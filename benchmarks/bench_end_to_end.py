@@ -1,9 +1,6 @@
 """Sec. VI-B — end-to-end contraction: paper-faithful pipeline vs greedy
 baseline, measured on the real executor (CPU), plus the projected
-single-chip TPU time from the F-surface model for the planner's output,
-and the epilogue-megakernel ablation (REPRO_MEGAKERNEL on/off on the
-lowered GEMM schedule: fused-chain counts, modeled HBM bytes saved, and
-the measured contract_all wall both ways).
+single-chip TPU time from the F-surface model for the planner's output.
 
 The paper's headline (304 s → 149.2 s on 107,520 Sunway nodes) is a
 planner+efficiency product; at our scale we report the same decomposition:
@@ -11,8 +8,6 @@ planner+efficiency product; at our scale we report the same decomposition:
 """
 
 from __future__ import annotations
-
-import os
 
 import numpy as np
 
@@ -46,91 +41,21 @@ def run(circuit: str = "syc-12") -> list[str]:
             repeat=2,
         )
         results[label] = complex(val)
-        # memory columns: planned live-set peak (lifetime buffer plan) and
-        # the fused-kernel transpose-bytes credit of the lowered schedule
+        # memory columns: planned live-set peak (lifetime buffer plan)
         mem = plan.memory_plan()
-        from repro.lowering.refiner import refine_tree_schedule
-
-        sched = refine_tree_schedule(tree, smask)
         rows.append(
             f"e2e_{label}_ms,{t*1e3:.1f},"
             f"overhead={report.slicing_overhead:.3f};"
             f"slices={report.num_sliced};"
             f"tpu_model_s={modeled_tree_time(tree, smask):.3e};"
             f"peak_bytes={mem.peak_bytes};"
-            f"peak_bytes_hoisted={mem.peak_bytes_hoisted};"
-            f"tb_elim={sched.transpose_bytes_eliminated():.3e}"
+            f"peak_bytes_hoisted={mem.peak_bytes_hoisted}"
         )
     assert abs(results["greedy_base"] - results["paper_faithful"]) < 1e-4, (
         "pipelines disagree on the amplitude!"
     )
-    rows.extend(megakernel_rows(circuit, plans["paper_faithful"], arrays))
     rows.extend(telemetry_rows())
     return rows
-
-
-def megakernel_rows(
-    circuit: str,
-    plan_tuple,
-    arrays,
-    trajectory_dir: str = "experiments/megakernel",
-) -> list[str]:
-    """Epilogue-megakernel ablation on the paper-faithful plan: the same
-    lowered GEMM schedule executed with the fusion-boundary pass off and
-    on (REPRO_MEGAKERNEL={0,1}), values asserted equal, chain statistics
-    from the ChainPlan, and the measured contract_all wall both ways —
-    appended to the trajectory history ``make_tables`` renders."""
-    tree, smask, report = plan_tuple
-    saved = os.environ.get("REPRO_MEGAKERNEL")
-    walls, vals = {}, {}
-    chain_summary = None
-    hbm_saved = {}
-    try:
-        for mega in ("0", "1"):
-            os.environ["REPRO_MEGAKERNEL"] = mega
-            plan = ContractionPlan(tree, smask, backend="gemm")
-            val, t = timer(
-                lambda: np.asarray(plan.contract_all(arrays, slice_batch=4)),
-                repeat=2,
-            )
-            walls[mega], vals[mega] = t, complex(val)
-            if mega == "1":
-                assert plan.chain_plan is not None, "fusion pass did not run"
-                chain_summary = plan.chain_plan.summary()
-                hbm_saved = chain_summary["hbm_bytes_saved"]
-    finally:
-        if saved is None:
-            os.environ.pop("REPRO_MEGAKERNEL", None)
-        else:
-            os.environ["REPRO_MEGAKERNEL"] = saved
-    assert abs(vals["0"] - vals["1"]) < 1e-4, (
-        "megakernel on/off disagree on the amplitude!"
-    )
-    record = {
-        "workload": circuit,
-        "num_sliced": report.num_sliced,
-        "fused_chains": chain_summary["multi_step_chains"],
-        "max_chain_len": chain_summary["max_chain_len"],
-        "chain_peak_bytes": chain_summary["max_live_bytes"],
-        "vmem_budget": chain_summary["vmem_budget"],
-        "hbm_bytes_saved": hbm_saved,
-        "wall_megakernel_off_s": walls["0"],
-        "wall_megakernel_on_s": walls["1"],
-        "speedup": walls["0"] / walls["1"] if walls["1"] else None,
-    }
-    append_trajectory([record], trajectory_dir)
-    return [
-        f"e2e_megakernel_off_ms,{walls['0']*1e3:.1f},"
-        f"chains=0;chain_saved=0",
-        f"e2e_megakernel_on_ms,{walls['1']*1e3:.1f},"
-        f"chains={chain_summary['multi_step_chains']};"
-        f"max_len={chain_summary['max_chain_len']};"
-        f"chain_peak={chain_summary['max_live_bytes']};"
-        + "chain_saved="
-        + ";".join(
-            f"{seg}:{int(v)}" for seg, v in sorted(hbm_saved.items())
-        ),
-    ]
 
 
 def precision_rows(
@@ -145,11 +70,7 @@ def precision_rows(
     two-phase time, modeled HBM traffic, slice count, bf16 step counts,
     the measured contract_all wall, and the measured Linear-XEB delta on
     the open-batch amplitudes — appended to the trajectory history
-    ``make_tables`` renders.
-
-    Pins ``REPRO_MEGAKERNEL=1`` / ``REPRO_FUSED_GEMM=1`` like the CI
-    gate: the ablation is about the precision dimension, not the other
-    lowering switches."""
+    ``make_tables`` renders."""
     from repro.core import plan_compiled, sample_bitstrings
     from repro.quantum.xeb import xeb_from_amplitudes
 
@@ -157,56 +78,44 @@ def precision_rows(
 
     tn, arrays = network_for(circuit)
     circ = CIRCUITS[circuit]()
-    saved = {
-        k: os.environ.get(k) for k in ("REPRO_MEGAKERNEL", "REPRO_FUSED_GEMM")
-    }
-    os.environ["REPRO_MEGAKERNEL"] = "1"
-    os.environ["REPRO_FUSED_GEMM"] = "1"
     stats, xebs = {}, {}
-    try:
-        for label, prec in (("fp32", "fp32"), ("auto", "auto")):
-            plan, report = plan_compiled(
-                tn, target_dim, backend="gemm", use_cache=False,
-                slicing_mode="peak", precision=prec,
-                fidelity_tol=fidelity_tol,
-            )
-            val, wall = timer(
-                lambda: np.asarray(plan.contract_all(arrays, slice_batch=8)),
-                repeat=2,
-            )
-            n_slices = 1 << plan.num_sliced
-            epi = sum(
-                plan.schedule.specs[k].modeled_time_s
-                for k in plan.epilogue_idx
-            ) * n_slices
-            stats[label] = {
-                "amp": complex(val),
-                "wall_s": wall,
-                "num_sliced": plan.num_sliced,
-                "modeled_time_s": report.modeled_time_hoisted_s,
-                "modeled_epilogue_s": epi,
-                "hbm_bytes": plan.schedule.hbm_traffic_bytes() * n_slices,
-                "peak_bytes": report.peak_bytes,
-                "precision_counts": plan.schedule.precision_counts(),
-                "predicted_amp_error": report.predicted_amp_error,
-            }
-            res = sample_bitstrings(
-                circ, num_samples=128,
-                open_qubits=tuple(range(circ.num_qubits - 4,
-                                        circ.num_qubits)),
-                target_dim=target_dim, seed=1, backend="gemm",
-                use_cache=False, slicing_mode="peak", slice_batch=4,
-                precision=prec, fidelity_tol=fidelity_tol,
-            )
-            xebs[label] = xeb_from_amplitudes(
-                circ.num_qubits, np.asarray(res.batch.amplitudes).ravel()
-            )
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+    for label, prec in (("fp32", "fp32"), ("auto", "auto")):
+        plan, report = plan_compiled(
+            tn, target_dim, backend="gemm", use_cache=False,
+            slicing_mode="peak", precision=prec,
+            fidelity_tol=fidelity_tol,
+        )
+        val, wall = timer(
+            lambda: np.asarray(plan.contract_all(arrays, slice_batch=8)),
+            repeat=2,
+        )
+        n_slices = 1 << plan.num_sliced
+        epi = sum(
+            plan.schedule.specs[k].modeled_time_s
+            for k in plan.epilogue_idx
+        ) * n_slices
+        stats[label] = {
+            "amp": complex(val),
+            "wall_s": wall,
+            "num_sliced": plan.num_sliced,
+            "modeled_time_s": report.modeled_time_hoisted_s,
+            "modeled_epilogue_s": epi,
+            "hbm_bytes": plan.schedule.hbm_traffic_bytes() * n_slices,
+            "peak_bytes": report.peak_bytes,
+            "precision_counts": plan.schedule.precision_counts(),
+            "predicted_amp_error": report.predicted_amp_error,
+        }
+        res = sample_bitstrings(
+            circ, num_samples=128,
+            open_qubits=tuple(range(circ.num_qubits - 4,
+                                    circ.num_qubits)),
+            target_dim=target_dim, seed=1, backend="gemm",
+            use_cache=False, slicing_mode="peak", slice_batch=4,
+            precision=prec, fidelity_tol=fidelity_tol,
+        )
+        xebs[label] = xeb_from_amplitudes(
+            circ.num_qubits, np.asarray(res.batch.amplitudes).ravel()
+        )
     f32, aut = stats["fp32"], stats["auto"]
     rel_err = abs(aut["amp"] - f32["amp"]) / abs(f32["amp"])
     assert rel_err <= fidelity_tol, (

@@ -266,8 +266,6 @@ def memory_rows(
                 "peak_bytes_peak": mem_p.peak_bytes,
                 "peak_bytes_hoisted_peak": mem_p.peak_bytes_hoisted,
                 "buffer_slots": mem_p.buffer_slots,
-                "transpose_bytes_eliminated":
-                    sched.transpose_bytes_eliminated(),
                 "transpose_bytes_paid": sched.transpose_bytes(),
             }
             if measured and i == 0:
@@ -315,7 +313,7 @@ def memory_rows(
                 f"memory_{name}_t{i}_peak_bytes,{mem_p.peak_bytes},"
                 f"width_peak={mem_w.peak_bytes};"
                 f"S={popcount(S_w)}->{popcount(S_p)};"
-                f"tb_elim={sched.transpose_bytes_eliminated():.3e}"
+                f"tb_paid={sched.transpose_bytes():.3e}"
             )
     append_trajectory(records, trajectory_dir)
     return rows

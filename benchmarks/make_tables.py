@@ -10,9 +10,7 @@ slicer vs width proxy + fused transpose credit) from the records the
 same benchmark's ``memory_rows`` appends under experiments/memory/, the §Co-optimizer table (one-shot
 pipeline vs anytime plan_search) from the records
 ``benchmarks.bench_slice_count.cooptimizer_rows`` appends under
-experiments/optimize/, the §Megakernel table (epilogue fused-chain
-ablation) from the records ``benchmarks.bench_end_to_end`` appends
-under experiments/megakernel/, and the §Observability table (tracer
+experiments/optimize/, and the §Observability table (tracer
 overhead + model-vs-measured calibration) from the records
 ``bench_end_to_end.telemetry_rows`` appends under experiments/obs/.
 
@@ -193,7 +191,7 @@ def print_memory_table(memory_dir="experiments/memory") -> None:
     print("\n### Lifetime-based memory planning "
           "(peak-aware slicer vs width proxy, fused transpose credit)\n")
     print("| workload | \\|S\\| width → peak | planned peak width → peak | "
-          "byte budget | transpose bytes eliminated | "
+          "byte budget | transpose bytes paid | "
           "wall width → peak | speedup |")
     print("|---|---|---|---|---|---|---|")
     for r in rows:
@@ -211,7 +209,7 @@ def print_memory_table(memory_dir="experiments/memory") -> None:
             f"| {fmt_bytes(r['peak_bytes_width'])} → "
             f"{fmt_bytes(r['peak_bytes_peak'])} "
             f"| {fmt_bytes(r.get('budget_bytes'))} "
-            f"| {fmt_bytes(r.get('transpose_bytes_eliminated'))} "
+            f"| {fmt_bytes(r.get('transpose_bytes_paid'))} "
             f"| {wall} | {speed} |"
         )
 
@@ -250,45 +248,6 @@ def print_optimize_table(optimize_dir="experiments/optimize") -> None:
             f"{fmt_bytes(r['budget_bytes'])} "
             f"| {fmt_s(r.get('wall_oneshot_s'))} → "
             f"{fmt_s(r.get('wall_search_s'))} |"
-        )
-
-
-def print_megakernel_table(megakernel_dir="experiments/megakernel") -> None:
-    """§Megakernel rows: the epilogue fused-chain ablation
-    (REPRO_MEGAKERNEL on/off on the lowered GEMM schedule), one row per
-    trajectory record."""
-    paths = sorted(glob.glob(os.path.join(megakernel_dir, "*.json")))
-    rows = []
-    for path in paths:
-        with open(path) as f:
-            rec = json.load(f)
-        if isinstance(rec, dict):
-            rows.extend(rec.get("records", []))
-    if not rows:
-        return
-    print("\n### Epilogue megakernel "
-          "(VMEM-resident fused GEMM chains, REPRO_MEGAKERNEL ablation)\n")
-    print("| workload | slices | fused chains | max len | chain peak | "
-          "HBM saved/exec (per segment) | wall off → on | speedup |")
-    print("|---|---|---|---|---|---|---|---|")
-    for r in rows:
-        if "fused_chains" not in r:
-            continue
-        saved = ", ".join(
-            f"{seg}:{fmt_bytes(v)}"
-            for seg, v in sorted(r.get("hbm_bytes_saved", {}).items())
-        ) or "-"
-        speed = r.get("speedup")
-        print(
-            f"| {r.get('workload', '-')} "
-            f"| {1 << r.get('num_sliced', 0)} "
-            f"| {r['fused_chains']} "
-            f"| {r.get('max_chain_len', '-')} "
-            f"| {fmt_bytes(r.get('chain_peak_bytes'))} "
-            f"| {saved} "
-            f"| {fmt_s(r.get('wall_megakernel_off_s'))} → "
-            f"{fmt_s(r.get('wall_megakernel_on_s'))} "
-            f"| {'-' if speed is None else f'{speed:.2f}×'} |"
         )
 
 
@@ -552,7 +511,6 @@ def main() -> None:
     print_hoisting_table()
     print_memory_table()
     print_optimize_table()
-    print_megakernel_table()
     print_obs_table()
     print_precision_table()
     print_distributed_table()

@@ -11,6 +11,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         bench_distributed_scaling,
         bench_end_to_end,

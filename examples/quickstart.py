@@ -14,6 +14,7 @@ import argparse
 import time
 
 from repro.core import sample_bitstrings, simulate_amplitude
+from repro.launch.compile_cache import enable_compile_cache
 from repro.quantum import statevector
 from repro.quantum.circuits import random_1d_circuit
 
@@ -27,6 +28,7 @@ def _timed(fn) -> float:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--backend", choices=("einsum", "gemm"), default=None,
                     help="execution backend (default: $REPRO_BACKEND or "

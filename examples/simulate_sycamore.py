@@ -31,11 +31,13 @@ from repro.core import (
     simulate_amplitude,
 )
 from repro.core.executor import ContractionPlan, simplify_network
+from repro.launch.compile_cache import enable_compile_cache
 from repro.quantum import xeb
 from repro.quantum.circuits import circuit_to_network, sycamore_like
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=4)
     ap.add_argument("--cols", type=int, default=4)

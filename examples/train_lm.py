@@ -9,10 +9,12 @@ import argparse
 import dataclasses
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import train
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")

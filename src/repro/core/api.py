@@ -66,18 +66,14 @@ class PlanReport:
     invariant_fraction: float = 0.0  # share of C(B) hoisted out of slices
     measured_overhead: float = 1.0  # executed-FLOPs overhead of the mode
     modeled_time_hoisted_s: float = 0.0  # Sec. V model under hoisting
-    # lifetime-based memory plan + fused-kernel metrics (PR 4)
+    # lifetime-based memory plan metrics (PR 4)
     peak_bytes: int = 0  # exact live-set peak, naive subtask
     peak_bytes_hoisted: int = 0  # live-set peak under two-phase execution
     buffer_slots: int = 0  # linear-scan slot count (naive subtask)
-    transpose_bytes_saved: float = 0.0  # HBM bytes fused kernels avoid/slice
     # anytime path–slice co-optimizer metrics (PR 5)
     optimize: str = "oneshot"  # planner mode: oneshot | anytime
     search_evals: int = 0  # candidate evaluations the search spent
     search_trace: list | None = None  # best-so-far improvements (dicts)
-    # epilogue megakernel metrics (PR 6)
-    fused_chains: int = 0  # multi-step VMEM-resident chains planned
-    chain_hbm_bytes_saved: float = 0.0  # modeled HBM bytes chains avoid/slice
     # observability (PR 7): metrics snapshot + per-span aggregates from
     # repro.obs.telemetry_summary(), populated only when tracing is on
     # (REPRO_TRACE=1 or the telemetry= toggle) — None otherwise
@@ -123,13 +119,6 @@ class PlanReport:
                 f"{k}={v}" for k, v in sorted(self.lowered_backends.items())
             )
             row += f" lowered[{nodes}] pad_waste={self.pad_waste*100:.1f}%"
-            if self.transpose_bytes_saved:
-                row += f" tb_saved={_fmt_bytes(self.transpose_bytes_saved)}"
-        if self.fused_chains:
-            row += (
-                f" chains={self.fused_chains}"
-                f" chain_saved={_fmt_bytes(self.chain_hbm_bytes_saved)}"
-            )
         if self.schedule_imbalance:
             row += (
                 f" sched[imb={self.schedule_imbalance:.2f}"
@@ -374,8 +363,6 @@ def _plan_compiled(
         PRECISION_MODES,
         default_precision,
     )
-    from ..lowering.refiner import default_fused, default_megakernel
-
     import jax.numpy as jnp
 
     backend = backend if backend is not None else default_backend()
@@ -402,8 +389,6 @@ def _plan_compiled(
     if not use_cache:
         ent = _build()
         return ent.plan, ent.report
-    # REPRO_FUSED_GEMM changes the refined schedule, so it is part of
-    # the key (like the backend itself)
     # search params only shape the plan under optimize="anytime" —
     # keep them out of the oneshot key so ignored knobs cannot
     # cause spurious cache misses
@@ -412,8 +397,6 @@ def _plan_compiled(
         if optimize == "anytime"
         else ()
     )
-    # REPRO_MEGAKERNEL changes the plan's chain dispatch the same way
-    # REPRO_FUSED_GEMM changes its schedule — both join the key
     # the resolved precision mode always joins the key; the fidelity
     # tolerance only matters off fp32, so fp32 plans at different
     # tolerances share one entry instead of fragmenting the cache
@@ -421,8 +404,7 @@ def _plan_compiled(
         tn,
         dtype,
         extra=(backend, target_dim, method, tune, merge, repeats, seed,
-               slicing_mode, default_fused(), default_megakernel(),
-               optimize, budget_bytes, search_key,
+               slicing_mode, optimize, budget_bytes, search_key,
                precision_mode,
                tol if precision_mode != "fp32" else None),
     )
@@ -535,9 +517,6 @@ def _plan_fresh(
         ) * (1 << plan.num_sliced)
         report.lowered_backends = plan.schedule.backend_counts()
         report.pad_waste = plan.schedule.pad_waste()
-        report.transpose_bytes_saved = (
-            plan.schedule.transpose_bytes_eliminated()
-        )
         report.precision_counts = plan.schedule.precision_counts()
         report.predicted_amp_error = plan.schedule.predicted_amp_error
         if plan._itemsize_of:
@@ -548,33 +527,6 @@ def _plan_fresh(
             report.peak_bytes = mem.peak_bytes
             report.peak_bytes_hoisted = mem.peak_bytes_hoisted
             report.buffer_slots = mem.buffer_slots
-    if plan.chain_plan is not None:
-        report.fused_chains = plan.chain_plan.num_multi
-        # per-slice saving in the mode that will execute: under hoisting
-        # the epilogue is what runs once per slice
-        seg = (
-            "epilogue"
-            if report.hoist and plan.can_hoist and plan.num_sliced
-            else "naive"
-        )
-        report.chain_hbm_bytes_saved = plan.chain_plan.hbm_bytes_saved(seg)
-        # cost-model correction: a chained step no longer pays the HBM
-        # round-trip of its interior output nor the unfused backends'
-        # transpose-copy traffic (kept disjoint in FusedChainSpec, so
-        # nothing is double-charged) — feed the per-segment savings back
-        # into the modeled times the planner reports
-        cp = plan.chain_plan
-        report.modeled_time_s = max(
-            0.0,
-            report.modeled_time_s
-            - cp.modeled_time_saved_s("naive") * (1 << plan.num_sliced),
-        )
-        report.modeled_time_hoisted_s = max(
-            0.0,
-            report.modeled_time_hoisted_s
-            - cp.modeled_time_saved_s("prologue")
-            - cp.modeled_time_saved_s("epilogue") * (1 << plan.num_sliced),
-        )
     report.plan_wall_s = time.perf_counter() - t0
     return plan, report
 

@@ -37,12 +37,14 @@ one, which is the paper's flagship sampling workload (Sec. VI: 1M
 correlated samples of Sycamore).  See :mod:`repro.sampling` for the
 sampling layer built on top.
 
-Two execution backends share the slice machinery: the default
-``einsum`` oracle path lowers every tree node to ``jnp.einsum``, while
-``backend="gemm"`` compiles the tree through :mod:`repro.lowering` into
-an explicit kernel schedule — each node normalized to
-transpose→reshape→GEMM form and refined onto Pallas ``tiled_matmul`` /
-``jnp.dot`` / ``jnp.einsum`` per the adaptive tile refiner.  The
+Two execution backends share the slice machinery and the lane-dense
+layout (:mod:`repro.lowering.layout`: every buffer flat in a static
+index order, so a TPU does not pad one-axis-per-index tensors 64×).
+The default ``einsum`` oracle path runs every tree node as one XLA
+``dot_general``; ``backend="gemm"`` compiles the tree through
+:mod:`repro.lowering` into an explicit kernel schedule — each node
+normalized to transpose→reshape→GEMM form and refined onto the Pallas
+``tiled_matmul`` or XLA's dot per the adaptive tile refiner.  The
 schedule is static per plan, so it runs identically under the per-slice
 path, the vmapped slice batch, and ``shard_map``.
 
@@ -253,7 +255,6 @@ class ContractionPlan:
         raw_out = node_inds[self.root]
         # canonicalize: output axes follow tn.open_inds declaration order
         want = tuple(ix for ix in tn.open_inds if ix in raw_out)
-        self.out_perm = tuple(raw_out.index(ix) for ix in want)
         self.out_inds = want if want else raw_out
 
         self.backend = backend if backend is not None else default_backend()
@@ -307,9 +308,9 @@ class ContractionPlan:
             self.prologue_leaves = part.prologue_leaves
             self.epilogue_leaves = part.epilogue_leaves
         # mixed-precision assignment: runs after the partition (epilogue
-        # steps weigh 2^|S| in the greedy order) and before the memory/
-        # chain planning (their byte accounting must see the storage
-        # precision the schedule will actually run at)
+        # steps weigh 2^|S| in the greedy order) and before the memory
+        # planning (its byte accounting must see the storage precision
+        # the schedule will actually run at)
         self._itemsize_of: dict[int, int] | None = None
         if self.schedule is not None and self.precision_mode != "fp32":
             from ..lowering.precision import (  # lazy: avoid cycle
@@ -333,41 +334,26 @@ class ContractionPlan:
                     self.dtype,
                     tree.emask,
                 )
-        # lifetime-based buffer plan (lazy; built eagerly below when the
-        # fusion-boundary pass needs the per-node buffer sizes)
+        # lifetime-based buffer plan (lazy)
         self._memory_plan = None
-        # fusion-boundary pass (epilogue megakernel): runs of adjacent
-        # schedule steps whose certified live set fits VMEM execute as
-        # single fused-chain calls.  Planned per execution segment so a
-        # chain can never cross the prologue/epilogue boundary; the
-        # REPRO_MEGAKERNEL switch is read here (plan construction) and
-        # joins the plan-cache fingerprint in the API layer.
-        self.chain_plan = None
-        self._chain_dispatch: dict[str, dict] = {}
-        if self.schedule is not None and self.steps:
-            from ..lowering.refiner import (  # lazy: avoid cycle
-                default_megakernel,
-                plan_chains,
-            )
+        # lane-dense layout: every buffer is stored flat in a static index
+        # order, and each step's operand orders and GEMM orientation are
+        # fixed here (see repro.lowering.layout)
+        from ..lowering.layout import dense_step  # lazy: avoid cycle
 
-            if default_megakernel():
-                mem = self.memory_plan()
-                segments = {"naive": tuple(range(len(self.steps)))}
-                if self.partition is not None:
-                    if self.prologue_idx:
-                        segments["prologue"] = self.prologue_idx
-                    segments["epilogue"] = self.epilogue_idx
-                step_nodes = tuple(
-                    (s.lhs, s.rhs, s.out) for s in self.steps
-                )
-                self.chain_plan = plan_chains(
-                    self.schedule, step_nodes, segments, mem.naive.nbytes,
-                    itemsize_of=self._itemsize_of,
-                )
-                self._chain_dispatch = {
-                    name: self.chain_plan.by_segment(name)
-                    for name in segments
-                }
+        self.store_order: dict[int, tuple] = {
+            i: node_inds[i] for i in range(tn.num_tensors)
+        }
+        self.dense_steps = []
+        for k, st in enumerate(self.steps):
+            spec = self.schedule.specs[k] if self.schedule else None
+            ds = dense_step(
+                self.store_order[st.lhs], self.store_order[st.rhs],
+                st.inds_out, tn.size_of,
+                canonical=spec is not None and spec.backend == "pallas",
+            )
+            self.dense_steps.append(ds)
+            self.store_order[st.out] = ds.out_order
         # memoized jitted executables (plan-lifetime — a cached plan
         # served twice skips retracing, not just re-planning)
         self._compiled: dict = {}
@@ -381,12 +367,6 @@ class ContractionPlan:
             maxsize=int(os.environ.get("REPRO_HOIST_CACHE_SIZE", "8")),
             max_bytes=int(hoist_bytes) if hoist_bytes else None,
         )
-        if self.chain_plan is not None:
-            _metrics.inc("plan.chains_fused", self.chain_plan.num_multi)
-            _metrics.inc(
-                "plan.chain_hbm_bytes_saved",
-                self.chain_plan.hbm_bytes_saved("naive"),
-            )
 
     # ------------------------------------------------------------------
     @property
@@ -484,63 +464,24 @@ class ContractionPlan:
         """Execute the given step positions over ``env`` (shared by the
         prologue, the epilogue, and the naive full-tree path).
 
-        Frees are driven by the lifetime-based memory plan's per-step
-        free schedule for ``segment`` — deterministic last-use drops (in
-        the epilogue this keeps the pinned hoisted buffers out of the
-        free lists; they are cross-slice captures whose storage is never
-        reclaimable inside one subtask).
+        ``env`` holds flat buffers in :attr:`store_order`; each step runs
+        through :func:`repro.lowering.gemm_form.contract_flat` on its
+        :class:`~repro.lowering.layout.DenseStep`.  Frees are driven by
+        the lifetime-based memory plan's per-step free schedule for
+        ``segment`` — deterministic last-use drops (in the epilogue this
+        keeps the pinned hoisted buffers out of the free lists; they are
+        cross-slice captures whose storage is never reclaimable inside
+        one subtask)."""
+        from ..lowering import gemm_form  # lazy: avoid cycle
 
-        Positions planned into a fused chain (``self.chain_plan``,
-        keyed by the chain's first position) dispatch as one
-        ``gemm_form.apply_chain`` call — this single site covers the
-        vmapped scan, ``contract_sharded``, and ``contract_resumable``,
-        which all funnel through here."""
         seg = self.memory_plan().segment_for(segment)
         frees = seg.frees if seg is not None else None
-        chains = self._chain_dispatch.get(segment, {})
-        ids = list(step_ids)
-        i = 0
-        while i < len(ids):
-            k = ids[i]
-            ch = chains.get(k)
-            if ch is not None:
-                # fused chain: one megakernel call covers the whole run;
-                # interior intermediates never enter env (they live in
-                # the kernel's VMEM scratch slots), so the planned frees
-                # for them are no-ops and everything else drops exactly
-                # where the lifetime plan says it dies.
-                from ..lowering import gemm_form  # lazy: avoid cycle
-
-                assert tuple(ids[i:i + ch.n_steps]) == ch.positions, (
-                    segment, ch.positions, ids[i:i + ch.n_steps]
-                )
-                env[ch.out_node] = gemm_form.apply_chain(
-                    ch,
-                    [self.schedule.specs[p] for p in ch.positions],
-                    [env[n] for n in ch.external_nodes],
-                )
-                interior = {n[2] for n in ch.nodes[:-1]}
-                for p in ch.positions:
-                    out = self.steps[p].out
-                    dead = (
-                        frees[out]
-                        if frees is not None
-                        else (self.steps[p].lhs, self.steps[p].rhs)
-                    )
-                    for u in dead:
-                        if u in env and u not in interior:
-                            del env[u]
-                i += ch.n_steps
-                continue
+        for k in step_ids:
             st = self.steps[k]
-            if self.schedule is None:
-                env[st.out] = jnp.einsum(st.expr, env[st.lhs], env[st.rhs])
-            else:
-                from ..lowering import gemm_form  # lazy: avoid cycle
-
-                env[st.out] = gemm_form.apply(
-                    self.schedule.specs[k], env[st.lhs], env[st.rhs]
-                )
+            env[st.out] = gemm_form.contract_flat(
+                self.schedule.specs[k] if self.schedule else None,
+                self.dense_steps[k], env[st.lhs], env[st.rhs],
+            )
             dead = (
                 frees[st.out]
                 if frees is not None
@@ -548,7 +489,6 @@ class ContractionPlan:
             )
             for u in dead:
                 del env[u]
-            i += 1
 
     def contract_slice(
         self, arrays: Sequence[jnp.ndarray], slice_id, hoisted=None
@@ -575,12 +515,20 @@ class ContractionPlan:
                 a = jax.lax.dynamic_index_in_dim(
                     a, svals[spos], axis=axis, keepdims=False
                 )
-            env[i] = a
+            env[i] = a.reshape(-1)
         self._run_steps(env, step_ids, segment)
-        out = env[self.root]
-        if self.out_perm and self.out_perm != tuple(range(out.ndim)):
-            out = jnp.transpose(out, self.out_perm)
-        return out
+        return self._output(env[self.root])
+
+    def _output(self, flat):
+        """The root buffer, from its storage order into ``out_inds``
+        order and shape (one axis per open index)."""
+        from ..lowering.layout import permute_flat  # lazy: avoid cycle
+
+        out = permute_flat(
+            flat, self.store_order[self.root], self.out_inds,
+            self.tn.size_of,
+        )
+        return out.reshape(self.out_shape())
 
     # ------------------------------------------------------------------
     def _prologue_outputs(self, arrays) -> list[jnp.ndarray]:
@@ -589,7 +537,8 @@ class ContractionPlan:
         ``hoisted_nodes`` order.  Invariant leaves carry no sliced index
         by construction, so no slice specs apply here."""
         env: dict[int, jnp.ndarray] = {
-            i: jnp.asarray(arrays[i]) for i in self.prologue_leaves
+            i: jnp.asarray(arrays[i]).reshape(-1)
+            for i in self.prologue_leaves
         }
         self._run_steps(env, self.prologue_idx, "prologue")
         return [env[v] for v in self.hoisted_nodes]

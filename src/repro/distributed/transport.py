@@ -69,10 +69,7 @@ def init_multi_host(
         return world()
     import jax
 
-    try:  # newer jax: plugin-selectable CPU collectives; gloo ships in-tree
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - config absent on old jax
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,
@@ -165,7 +162,6 @@ class CollectiveTransport(Transport):
     def _reducer(self):
         if self._reduce is None:
             import jax
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             axis = self.axis_name
@@ -174,12 +170,12 @@ class CollectiveTransport(Transport):
                 return jax.lax.psum(x, axis)
 
             self._reduce = jax.jit(
-                shard_map(
+                jax.shard_map(
                     psum_chunk,
                     mesh=self.mesh,
                     in_specs=P(),
                     out_specs=P(),
-                    check_rep=False,
+                    check_vma=False,
                 )
             )
         return self._reduce

@@ -4,7 +4,7 @@ Before this module existed the per-slice dispatch/hoist/mask/metrics
 logic was quadruplicated across ``contract_all`` (vmapped scan),
 ``contract_sharded`` (shard_map + psum), ``contract_resumable``
 (per-slice jit calls) and ``contract_multihost`` (scheduler-driven
-ranges) — every new capability (telemetry, megakernel, precision) had to
+ranges) — every new capability (telemetry, precision) had to
 be threaded through four paths.  A :class:`ContractionSession` is the
 single owner of that logic: a compiled
 :class:`~repro.core.executor.ContractionPlan` bound to concrete leaf
@@ -23,11 +23,11 @@ beyond it is shared here exactly once:
     (``jnp.where``, never a weight multiply: ``0 * NaN`` leaks),
   * :func:`padded_ids` — wrapped-around slice-id padding to a chunk
     multiple,
-  * :func:`record_execution` — the executed/padded/FLOPs/chain-call
-    work accounting,
+  * :func:`record_execution` — the executed/padded/FLOPs work
+    accounting,
   * jit memoization on the plan's ``_compiled`` dict (all sessions on a
     cached plan share traced programs),
-  * per-step free schedules and fused-chain dispatch (via
+  * per-step free schedules and lane-dense step dispatch (via
     ``plan.contract_slice`` → ``_run_steps`` — already single-sited).
 
 The four public drivers are thin strategy adapters over this class; the
@@ -81,7 +81,7 @@ def record_execution(plan, executed: int, padded: int, hoist: bool) -> None:
     ``padded`` counts masked lanes (wrapped-around ids whose contribution
     a validity select zeroes out).  The two are disjoint by contract —
     inflating ``exec.slices_executed`` with padded lanes historically
-    made multi-host FLOPs/chain accounting drift from the single-host
+    made multi-host FLOPs accounting drift from the single-host
     scan's on the same plan.  Prologue FLOPs are counted where the
     prologue actually runs (``contract_prologue`` — a hoist-cache hit
     executes nothing), so only the per-slice epilogue cost lands here
@@ -97,9 +97,6 @@ def record_execution(plan, executed: int, padded: int, hoist: bool) -> None:
         _metrics.inc(
             "exec.flops_executed", plan.executed_flops(executed, hoist=False)
         )
-    chains = plan._chain_dispatch.get("epilogue" if hoist else "naive")
-    if chains:
-        _metrics.inc("exec.chain_calls", len(chains) * executed)
 
 
 class ContractionSession:
@@ -212,8 +209,17 @@ class ContractionSession:
         (default all-true) marks the lanes that contribute.  One jitted
         program serves every batch size (jit re-specializes per shape
         and caches internally); the masking select and the vmapped
-        ``contract_slice`` dispatch — free schedules, fused chains,
-        precision — are the single shared implementation."""
+        ``contract_slice`` dispatch — free schedules, layouts, precision —
+        are the single shared implementation."""
+        ids = np.asarray(slice_ids, dtype=np.int32)
+        if valid is None:
+            valid = np.ones(ids.shape, dtype=bool)
+        return self._batch_fn()(
+            list(self.arrays), list(self.hoisted()),
+            jnp.asarray(ids), jnp.asarray(valid),
+        )
+
+    def _batch_fn(self):
         plan, hoist = self.plan, self.hoist
         ck = ("sess_batch", hoist)
         fn = plan._compiled.get(ck)
@@ -228,13 +234,16 @@ class ContractionSession:
                 return jnp.sum(mask_invalid(contrib, valid_), axis=0)
 
             fn = plan._compiled.setdefault(ck, fn)
-        ids = np.asarray(slice_ids, dtype=np.int32)
-        if valid is None:
-            valid = np.ones(ids.shape, dtype=bool)
-        return fn(
+        return fn
+
+    def compiled_slices(self, n: int):
+        """The compiled :meth:`run_slices` program for a batch of ``n``
+        ids, for reading its ``memory_analysis()`` and ``as_text()``."""
+        return self._batch_fn().lower(
             list(self.arrays), list(self.hoisted()),
-            jnp.asarray(ids), jnp.asarray(valid),
-        )
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.bool_),
+        ).compile()
 
     # ------------------------------------------------------------------
     # strategy: all slices, scan of vmapped batches (single host)
@@ -320,11 +329,57 @@ class ContractionSession:
     # ------------------------------------------------------------------
     # strategy: slice ids sharded over a mesh (shard_map + one psum)
     # ------------------------------------------------------------------
+    def _sharded_fn(self, mesh, axis_names: tuple, slice_batch: int):
+        """The jitted shard_map program of :meth:`run_sharded` (memoized
+        on the plan per mesh, axes, slice batch and hoist mode)."""
+        from jax.sharding import PartitionSpec as P
+
+        plan, hoist = self.plan, self.hoist
+        key = ("sharded", mesh, axis_names, slice_batch, hoist)
+        fn = plan._compiled.get(key)
+        if fn is not None:
+            return fn
+        spec = P(axis_names)
+
+        @jax.jit
+        def run(arrs, hbufs, ids_, valid_):
+            def worker(ids_local, valid_local):
+                # arrs/hbufs are closure captures: replicated devices
+                contract = lambda sid: plan.contract_slice(  # noqa: E731
+                    arrs, sid, hbufs if hoist else None
+                )
+                batched = jax.vmap(contract)
+                idb = ids_local.reshape(-1, slice_batch)
+                vb = valid_local.reshape(-1, slice_batch)
+
+                out_shape = jax.eval_shape(lambda: contract(jnp.int32(0)))
+
+                def body(acc, iv):
+                    sids, ok = iv
+                    contrib = mask_invalid(batched(sids), ok)
+                    return acc + jnp.sum(contrib, axis=0), None
+
+                acc0 = jnp.zeros(out_shape.shape, out_shape.dtype)
+                acc, _ = jax.lax.scan(body, acc0, (idb, vb))
+                return jax.lax.psum(acc, axis_names)
+
+            return jax.shard_map(
+                worker,
+                mesh=mesh,
+                in_specs=(spec, spec),
+                out_specs=P(),
+                check_vma=False,
+            )(ids_, valid_)
+
+        # setdefault so concurrent threads converge on one program
+        return plan._compiled.setdefault(key, run)
+
     def run_sharded(
         self, mesh, axis_names: tuple[str, ...] = ("data",),
-        slice_batch: int = 1,
+        slice_batch: int = 1, slice_ids=None,
     ) -> jnp.ndarray:
-        """Contract all slices with slice-parallelism over ``axis_names``.
+        """Contract all slices — or just ``slice_ids`` — with
+        slice-parallelism over ``axis_names`` (the paper's Sec. V-D).
 
         Every device scans its chunk of slice ids and contributes to one
         psum; each scan step runs ``slice_batch`` subtasks under ``vmap``.
@@ -332,63 +387,28 @@ class ContractionSession:
         so the one psum returns the complete amplitude batch on every
         device.  The hoisted prologue enters the worker as a replicated
         capture, broadcast once per (leaves, mesh) via the HoistCache."""
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
-
         plan, hoist = self.plan, self.hoist
         ndev = 1
         for ax in axis_names:
             ndev *= mesh.shape[ax]
-        n_slices = self.n_slices
+        subset = None if slice_ids is None else np.asarray(
+            slice_ids, dtype=np.int32
+        )
+        n_slices = self.n_slices if subset is None else len(subset)
         slice_batch = max(1, min(slice_batch, n_slices))
         # Ragged-batch contract: padding to a multiple of ndev*slice_batch
         # is what guarantees every device's local id chunk reshapes exactly
         # into (n_batches, slice_batch) — no divisibility assumption.
         ids, valid, total = padded_ids(n_slices, ndev * slice_batch)
+        if subset is not None:
+            ids = subset[ids]
 
         # invariant prologue: once per process, outside the slice loop
         hoisted = self.hoisted_replicated(mesh) if hoist else []
 
-        spec = P(axis_names)
         key = ("sharded", mesh, tuple(axis_names), slice_batch, hoist)
-        fn = plan._compiled.get(key)
-        cached = fn is not None
-        if fn is None:
-
-            @jax.jit
-            def run(arrs, hbufs, ids_, valid_):
-                def worker(ids_local, valid_local):
-                    # arrs/hbufs are closure captures: replicated devices
-                    contract = lambda sid: plan.contract_slice(  # noqa: E731
-                        arrs, sid, hbufs if hoist else None
-                    )
-                    batched = jax.vmap(contract)
-                    idb = ids_local.reshape(-1, slice_batch)
-                    vb = valid_local.reshape(-1, slice_batch)
-
-                    out_shape = jax.eval_shape(
-                        lambda: contract(jnp.int32(0))
-                    )
-
-                    def body(acc, iv):
-                        sids, ok = iv
-                        contrib = mask_invalid(batched(sids), ok)
-                        return acc + jnp.sum(contrib, axis=0), None
-
-                    acc0 = jnp.zeros(out_shape.shape, out_shape.dtype)
-                    acc, _ = jax.lax.scan(body, acc0, (idb, vb))
-                    return jax.lax.psum(acc, axis_names)
-
-                return shard_map(
-                    worker,
-                    mesh=mesh,
-                    in_specs=(spec, spec),
-                    out_specs=P(),
-                    check_rep=False,
-                )(ids_, valid_)
-
-            # setdefault so concurrent threads converge on one program
-            fn = plan._compiled.setdefault(key, run)
+        cached = key in plan._compiled
+        fn = self._sharded_fn(mesh, tuple(axis_names), slice_batch)
         with _trace.span(
             "exec.sharded", cat="exec", slices=n_slices, devices=ndev,
             hoist=hoist, cached=cached,

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU).
+"""Pallas TPU kernels (validated in interpret mode on CPU, compiled for
+a described v5e in ``tests/test_chip_compile.py``).
 
 contract_gemm    — tiled stem-contraction GEMM (the paper's hot-spot)
 flash_attention  — fused online-softmax attention for the LM fleet
@@ -12,19 +13,11 @@ into submodules.
 """
 
 from . import ops, ref  # noqa: F401
-from .contract_gemm import (  # noqa: F401
-    chain_reference,
-    fused_chain_matmul,
-    fused_transpose_matmul,
-    suffix_tile_split,
-    tiled_matmul,
-)
+from .contract_gemm import tiled_matmul  # noqa: F401
 from .flash_attention import flash_attention  # noqa: F401
 from .mamba2_ssd import ssd_intra_chunk  # noqa: F401
 from .ops import (  # noqa: F401
     attention,
-    fused_chain,
-    fused_matmul,
     matmul,
     ssd_scan,
 )

@@ -14,19 +14,17 @@ HBM→VMEM bandwidth.  This kernel:
   * leaves M as the outermost axis so slice-batched stems (executor vmap)
     stream through without re-fetching B.
 
-Validated against ref.matmul_ref in interpret mode (this container is
-CPU-only; TPU is the target).
+Validated against ref.matmul_ref in interpret mode on the CPU, and
+compiled for a described v5e in ``tests/test_chip_compile.py``.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, *, k_tiles: int):
@@ -34,8 +32,14 @@ def _matmul_kernel(a_ref, b_ref, o_ref, *, k_tiles: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    # f32 operands ask for the multi-pass fp32 MXU product: the default
+    # would round them to bf16; bf16 operands take the single pass
+    precision = (
+        jax.lax.Precision.HIGHEST if a_ref.dtype == jnp.float32 else None
+    )
     o_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+        precision=precision,
     )
 
 
@@ -72,455 +76,3 @@ def tiled_matmul(
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
     )(a, b)
-
-
-# ----------------------------------------------------------------------
-# fused transpose-GEMM (Sec. V): the layout permutation rides inside the
-# kernel instead of materializing transposed operand copies in HBM
-# ----------------------------------------------------------------------
-def suffix_tile_split(shape: tuple[int, ...], target: int) -> tuple[int, int, int]:
-    """Split a role group's dims into (grid prefix, tile suffix).
-
-    Returns ``(n_prefix, grid, tile)``: the longest suffix of ``shape``
-    whose product stays ``<= target`` becomes the in-kernel tile
-    (``tile`` = its product); the remaining prefix axes are enumerated by
-    the grid (``grid`` = their product).  Because the boundary sits on an
-    axis boundary, every tile is an exact rectangular block of the
-    operand's *native* layout — the fused kernel never pads.
-    """
-    tile = 1
-    j = len(shape)
-    while j > 0 and tile * shape[j - 1] <= target:
-        j -= 1
-        tile *= shape[j]
-    grid = 1
-    for d in shape[:j]:
-        grid *= d
-    return j, grid, tile
-
-
-def _coords(idx, dims: tuple[int, ...]) -> list:
-    """Row-major multi-index of flat ``idx`` over ``dims`` (traced-safe)."""
-    out = []
-    rem = idx
-    for d in reversed(dims):
-        out.append(rem % d)
-        rem = rem // d
-    out.reverse()
-    return out
-
-
-def _operand_index_map(role_of, bshape, pre_shape_1, pre_shape_2, which):
-    """index_map factory for one operand in its native layout.
-
-    ``role_of[p] = (kind, pos)`` classifies native axis ``p``; prefix
-    positions take their grid coordinate, suffix positions are covered by
-    a full-size block (block index 0).  ``which`` selects which two grid
-    axes this operand consumes (a: (m, k); b: (k, n); out: (m, n))."""
-
-    def index_map(b, i, j, kk):
-        g1 = {"a": i, "b": kk, "o": i}[which]
-        g2 = {"a": kk, "b": j, "o": j}[which]
-        bc = _coords(b, bshape)
-        c1 = _coords(g1, pre_shape_1)
-        c2 = _coords(g2, pre_shape_2)
-        out = []
-        for kind, pos in role_of:
-            if kind == "batch":
-                out.append(bc[pos])
-            elif kind == "first":
-                out.append(c1[pos] if pos < len(pre_shape_1) else 0)
-            else:  # "second"
-                out.append(c2[pos] if pos < len(pre_shape_2) else 0)
-        return tuple(out)
-
-    return index_map
-
-
-def _fused_kernel(
-    a_ref, b_ref, o_ref, *, perm_a, perm_b, tile_m, tile_n, tile_k, out_block
-):
-    @pl.when(pl.program_id(3) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    # the permutation happens here, on the VMEM-resident tile: the loaded
-    # blocks keep the operands' native axis order, so the HBM copies of
-    # a2/b2 that the reference path materializes never exist.
-    at = jnp.transpose(a_ref[...], perm_a).reshape(tile_m, tile_k)
-    bt = jnp.transpose(b_ref[...], perm_b).reshape(tile_k, tile_n)
-    o_ref[...] += jnp.dot(
-        at, bt, preferred_element_type=jnp.float32
-    ).reshape(out_block)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "perm_a", "perm_b", "nb", "nm", "nn", "nk", "bm", "bn", "bk",
-        "interpret",
-    ),
-)
-def fused_transpose_matmul(
-    a: jax.Array,
-    b: jax.Array,
-    *,
-    perm_a: tuple[int, ...],
-    perm_b: tuple[int, ...],
-    nb: int,
-    nm: int,
-    nn: int,
-    nk: int,
-    bm: int = 256,
-    bn: int = 256,
-    bk: int = 256,
-    interpret: bool = False,
-) -> jax.Array:
-    """Batched GEMM over operands in their *native* (contraction-tree)
-    layouts — the paper's Sec. V fused permute-GEMM, TPU-native.
-
-    ``perm_a`` orders ``a``'s native axes as (batch..., m..., k...) and
-    ``perm_b`` orders ``b``'s as (batch..., k..., n...) — exactly the
-    permutations the reference path materializes via
-    ``jnp.transpose(...).reshape(...)``.  Here they stay *virtual*: the
-    ``index_map`` of each BlockSpec walks the native layout so every grid
-    cell DMAs an axis-aligned native block into VMEM, and the kernel
-    permutes that tile in-register before the MXU dot.  Tiles are exact
-    axis-suffix blocks (see :func:`suffix_tile_split`), so — unlike the
-    pad-or-split reference — the fused kernel executes zero padding
-    FLOPs and moves ``2*(|A|+|B|)`` fewer bytes of HBM traffic.
-
-    ``bm/bn/bk`` are tile-size *targets*; the effective tile is the
-    largest axis-suffix product per role group that fits the target.
-    Returns the un-permuted natural output (batch..., m..., n...) with
-    one axis per role index, accumulated in fp32 (the kernel family's
-    bf16-compute / fp32-accumulate convention).
-    """
-    assert len(perm_a) == nb + nm + nk == a.ndim, (perm_a, nb, nm, nk, a.shape)
-    assert len(perm_b) == nb + nk + nn == b.ndim, (perm_b, nb, nk, nn, b.shape)
-    ax_ab, ax_am, ax_ak = perm_a[:nb], perm_a[nb:nb + nm], perm_a[nb + nm:]
-    ax_bb, ax_bk, ax_bn = perm_b[:nb], perm_b[nb:nb + nk], perm_b[nb + nk:]
-    batch_shape = tuple(a.shape[p] for p in ax_ab)
-    m_shape = tuple(a.shape[p] for p in ax_am)
-    k_shape = tuple(a.shape[p] for p in ax_ak)
-    n_shape = tuple(b.shape[p] for p in ax_bn)
-    assert tuple(b.shape[p] for p in ax_bb) == batch_shape
-    assert tuple(b.shape[p] for p in ax_bk) == k_shape
-
-    jm, grid_m, tile_m = suffix_tile_split(m_shape, bm)
-    jn, grid_n, tile_n = suffix_tile_split(n_shape, bn)
-    jk, grid_k, tile_k = suffix_tile_split(k_shape, bk)
-    B = math.prod(batch_shape)
-
-    # per-native-axis roles + block shapes for a, b, and the natural output
-    def spec_for(batch_axes, first_axes, first_shape, j_first,
-                 second_axes, second_shape, j_second, shape, which):
-        role = {}
-        for i, p in enumerate(batch_axes):
-            role[p] = ("batch", i)
-        for i, p in enumerate(first_axes):
-            role[p] = ("first", i)
-        for i, p in enumerate(second_axes):
-            role[p] = ("second", i)
-        role_of = tuple(role[p] for p in range(len(shape)))
-        block = []
-        for p in range(len(shape)):
-            kind, pos = role[p]
-            if kind == "batch":
-                block.append(1)
-            elif kind == "first":
-                block.append(1 if pos < j_first else first_shape[pos])
-            else:
-                block.append(1 if pos < j_second else second_shape[pos])
-        imap = _operand_index_map(
-            role_of, batch_shape, first_shape[:j_first],
-            second_shape[:j_second], which,
-        )
-        return pl.BlockSpec(tuple(block), imap), tuple(block)
-
-    a_spec, _ = spec_for(
-        ax_ab, ax_am, m_shape, jm, ax_ak, k_shape, jk, a.shape, "a"
-    )
-    b_spec, _ = spec_for(
-        ax_bb, ax_bk, k_shape, jk, ax_bn, n_shape, jn, b.shape, "b"
-    )
-    # natural output layout: (batch..., m..., n...) in role order
-    out_shape = batch_shape + m_shape + n_shape
-    o_batch = tuple(range(nb))
-    o_m = tuple(range(nb, nb + nm))
-    o_n = tuple(range(nb + nm, nb + nm + nn))
-    o_spec, o_block = spec_for(
-        o_batch, o_m, m_shape, jm, o_n, n_shape, jn, out_shape, "o"
-    )
-
-    # tile-local permutations: the loaded blocks keep native axis order,
-    # so the operands' own perms re-order them to role order exactly as
-    # the reference path's HBM transpose would.
-    return pl.pallas_call(
-        functools.partial(
-            _fused_kernel,
-            perm_a=perm_a,
-            perm_b=perm_b,
-            tile_m=tile_m,
-            tile_n=tile_n,
-            tile_k=tile_k,
-            out_block=o_block,
-        ),
-        grid=(B, grid_m, grid_n, grid_k),
-        in_specs=[a_spec, b_spec],
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
-        interpret=interpret,
-    )(a, b)
-
-
-# ----------------------------------------------------------------------
-# epilogue megakernel: a *run* of adjacent tree GEMMs executes as one
-# persistent kernel — chain intermediates live in VMEM scratch slots
-# assigned by the lifetime planner's linear scan, never touching HBM
-# ----------------------------------------------------------------------
-def _chain_step_math(a, b, form, *, unroll_batch: bool,
-                     precision: str = "fp32"):
-    """One chained step on VMEM-resident values, in tree-native
-    transpose-GEMM form.
-
-    ``a``/``b`` are either fp32 arrays (real chain) or ``(re, im)`` fp32
-    pairs (complex chain — the carry stays split through the whole chain;
-    per-step Karatsuba, 3 real GEMMs).  ``unroll_batch=True`` issues one
-    2-D MXU dot per batch cell — the exact dots (and accumulation order)
-    :func:`fused_transpose_matmul` executes per grid cell, which is what
-    makes the megakernel bitwise-reproducible against the unfused chain;
-    ``False`` uses one batched ``dot_general`` (the off-TPU reference
-    dataflow).  Returns the step output permuted to the executor's
-    ``inds_out`` order — the native layout of the next step's operand.
-
-    ``precision="bf16"`` rounds the GEMM inputs to bf16 (fp32
-    accumulation).  Incoming components are first widened to fp32 — an
-    exact no-op for bf16-stored carries — so the Karatsuba sums always
-    run in fp32 before the single rounding at the MXU boundary, matching
-    the unfused backends' cast placement exactly."""
-
-    def gemm(x, y):
-        xa = jnp.transpose(x, form.perm_a).reshape(form.B, form.M, form.K)
-        yb = jnp.transpose(y, form.perm_b).reshape(form.B, form.K, form.N)
-        if precision == "bf16":
-            xa = xa.astype(jnp.bfloat16)
-            yb = yb.astype(jnp.bfloat16)
-        if unroll_batch or form.B == 1:
-            out = jnp.stack(
-                [
-                    jnp.dot(
-                        xa[i], yb[i], preferred_element_type=jnp.float32
-                    )
-                    for i in range(form.B)
-                ]
-            )
-        else:
-            out = jax.lax.dot_general(
-                xa,
-                yb,
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-        out = out.reshape(form.batch_shape + form.m_shape + form.n_shape)
-        if form.out_perm != tuple(range(out.ndim)):
-            out = jnp.transpose(out, form.out_perm)
-        return out
-
-    if isinstance(a, tuple):
-        ar, ai = (c.astype(jnp.float32) for c in a)
-        br, bi = (c.astype(jnp.float32) for c in b)
-        p1 = gemm(ar, br)
-        p2 = gemm(ai, bi)
-        p3 = gemm(ar + ai, br + bi)
-        return (p1 - p2, p3 - p1 - p2)
-    return gemm(a.astype(jnp.float32), b.astype(jnp.float32))
-
-
-def _run_chain(read_ext, forms, carry_side, *, ncomp, unroll_batch,
-               store_carry=None, precisions=None):
-    """Shared chain dataflow: the kernel body and the off-TPU reference
-    both walk this exact sequence, so they agree step for step.
-    ``store_carry(t, comps)`` routes an interior carry through its VMEM
-    scratch slot (kernel) or passes it through (reference).
-
-    ``precisions[t]`` is step ``t``'s GEMM input precision.  An interior
-    carry is rounded to its *consumer's* precision before being stored
-    (kernel) or carried (reference) — the chain-interior intermediate
-    lives at the planned precision, and because the consumer would round
-    it identically at the MXU boundary anyway, kernel and reference stay
-    bitwise-identical regardless of the scratch slot's physical dtype."""
-    carry = None
-    for t, form in enumerate(forms):
-        prec = precisions[t] if precisions is not None else "fp32"
-        if t == 0:
-            a, b = read_ext(), read_ext()
-        else:
-            ext = read_ext()
-            a, b = (carry, ext) if carry_side[t] == "l" else (ext, carry)
-        val = _chain_step_math(
-            a, b, form, unroll_batch=unroll_batch, precision=prec
-        )
-        comps = val if ncomp == 2 else (val,)
-        if t + 1 < len(forms):
-            next_prec = (
-                precisions[t + 1] if precisions is not None else "fp32"
-            )
-            if next_prec == "bf16":
-                comps = tuple(c.astype(jnp.bfloat16) for c in comps)
-            if store_carry is not None:
-                comps = store_carry(t, comps)
-        carry = comps if ncomp == 2 else comps[0]
-    return carry if ncomp == 2 else (carry,)
-
-
-def _chain_kernel(*refs, forms, carry_side, slot_ids, ncomp, n_ext,
-                  precisions=None):
-    ext_refs = refs[:n_ext * ncomp]
-    out_refs = refs[n_ext * ncomp:n_ext * ncomp + ncomp]
-    scratch = refs[n_ext * ncomp + ncomp:]
-    cursor = [0]
-
-    def read_ext():
-        i = cursor[0]
-        cursor[0] += 1
-        vals = tuple(ext_refs[i * ncomp + c][...] for c in range(ncomp))
-        return vals if ncomp == 2 else vals[0]
-
-    def store_carry(t, comps):
-        # flat store into the planner-assigned slot, then read back in
-        # the carry's shape: the intermediate lives only in this VMEM
-        # scratch buffer — the HBM round-trip of the unfused path never
-        # happens.  Slot reuse across steps (ping-pong) is exactly the
-        # linear-scan assignment certified at plan time.  A bf16-rounded
-        # carry stored in a wider (shared) fp32 slot is held exactly.
-        sid = slot_ids[t]
-        stored = []
-        for c, v in enumerate(comps):
-            ref = scratch[sid * ncomp + c]
-            flat = v.astype(ref.dtype).reshape(-1)
-            ref[0:flat.size] = flat
-            stored.append(ref[0:flat.size].reshape(v.shape))
-        return tuple(stored)
-
-    outs = _run_chain(
-        read_ext, forms, carry_side, ncomp=ncomp, unroll_batch=True,
-        store_carry=store_carry, precisions=precisions,
-    )
-    for c in range(ncomp):
-        out_refs[c][...] = outs[c]
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "forms", "carry_side", "slot_ids", "slot_elems", "complex_mode",
-        "interpret", "precisions", "slot_prec",
-    ),
-)
-def fused_chain_matmul(
-    *operands: jax.Array,
-    forms: tuple,
-    carry_side: tuple[str, ...],
-    slot_ids: tuple[int, ...],
-    slot_elems: tuple[int, ...],
-    complex_mode: bool = False,
-    interpret: bool = False,
-    precisions: tuple[str, ...] | None = None,
-    slot_prec: tuple[str, ...] | None = None,
-):
-    """Persistent megakernel for a run of adjacent tree GEMMs.
-
-    ``forms`` are the chain's :class:`~repro.lowering.gemm_form.GemmForm`
-    steps in execution order; step ``t``'s carry operand is the previous
-    step's output (``carry_side[t]`` says which side, ``""`` for step 0).
-    ``operands`` are the chain's *external* inputs — step 0's pair, then
-    one non-carry operand per later step — each in its tree-native
-    layout.  In ``complex_mode`` every logical operand is passed as two
-    fp32 components ``(re, im)`` and the kernel returns the pair; the
-    carry stays component-split end to end, with each step running the
-    3-real-GEMM Karatsuba.
-
-    The whole chain executes as one grid-less ``pallas_call``: operands
-    are DMA'd to VMEM once, every intermediate lives in a VMEM scratch
-    slot (``slot_ids[t]`` = slot of step ``t``'s output, ``slot_elems`` =
-    per-slot capacity in logical elements — both straight from the
-    lifetime planner's linear-scan assignment, see
-    :func:`repro.lowering.memory.chain_segment_plan`), and only the final
-    output is written back — zero HBM round-trips between chained steps.
-    Returns a tuple of ``ncomp`` fp32 arrays in the executor's
-    ``inds_out`` order of the last step.
-
-    ``precisions[t]`` is step ``t``'s GEMM input precision ("fp32" /
-    "bf16"-input-fp32-accumulate); ``slot_prec`` gives each scratch
-    slot's physical dtype — "bf16" (half the VMEM bytes) when every
-    intermediate assigned to the slot is consumed at bf16.  Both default
-    to all-fp32.
-    """
-    ncomp = 2 if complex_mode else 1
-    n_ext = len(forms) + 1
-    assert len(operands) == n_ext * ncomp, (len(operands), n_ext, ncomp)
-    assert len(slot_ids) == len(forms) - 1, (slot_ids, len(forms))
-    if precisions is not None:
-        assert len(precisions) == len(forms), (precisions, len(forms))
-    slot_dtypes = tuple(
-        jnp.bfloat16
-        if slot_prec is not None and i < len(slot_prec)
-        and slot_prec[i] == "bf16"
-        else jnp.float32
-        for i in range(len(slot_elems))
-    )
-    f = forms[-1]
-    natural = f.batch_shape + f.m_shape + f.n_shape
-    oshape = tuple(natural[p] for p in f.out_perm)
-    out = pl.pallas_call(
-        functools.partial(
-            _chain_kernel,
-            forms=forms,
-            carry_side=carry_side,
-            slot_ids=slot_ids,
-            ncomp=ncomp,
-            n_ext=n_ext,
-            precisions=precisions,
-        ),
-        out_shape=tuple(
-            jax.ShapeDtypeStruct(oshape, jnp.float32) for _ in range(ncomp)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((e,), dt)
-            for e, dt in zip(slot_elems, slot_dtypes)
-            for _ in range(ncomp)
-        ],
-        interpret=interpret,
-    )(*operands)
-    return tuple(out)
-
-
-def chain_reference(
-    components,
-    *,
-    forms: tuple,
-    carry_side: tuple[str, ...],
-    complex_mode: bool = False,
-    precisions: tuple[str, ...] | None = None,
-):
-    """The megakernel's dataflow in plain jnp — same externals, same
-    per-step Karatsuba on split fp32 components, same step order, same
-    interior-carry precision rounding — used off-TPU where
-    interpret-mode Pallas would be pure-Python slow.  Batch cells run as
-    one batched ``dot_general`` (XLA fuses the whole chain into one
-    program); agreement with the kernel is to fp32 tolerance, and exact
-    when every step has ``B == 1``."""
-    ncomp = 2 if complex_mode else 1
-    cursor = [0]
-
-    def read_ext():
-        i = cursor[0]
-        cursor[0] += 1
-        vals = tuple(components[i * ncomp + c] for c in range(ncomp))
-        return vals if ncomp == 2 else vals[0]
-
-    return _run_chain(
-        read_ext, forms, carry_side, ncomp=ncomp, unroll_batch=False,
-        precisions=precisions,
-    )

@@ -19,12 +19,7 @@ import jax.numpy as jnp
 
 from ..obs import trace as _trace
 from . import ref
-from .contract_gemm import (
-    chain_reference,
-    fused_chain_matmul,
-    fused_transpose_matmul,
-    tiled_matmul,
-)
+from .contract_gemm import tiled_matmul
 from .flash_attention import flash_attention
 from .mamba2_ssd import ssd_intra_chunk
 
@@ -99,142 +94,6 @@ def _complex_matmul(
     p2 = matmul(ai, bi, **kw)
     p3 = matmul(ar + ai, br + bi, **kw)
     return (p1 - p2) + 1j * (p3 - p1 - p2)
-
-
-def fused_matmul(
-    a: jax.Array,
-    b: jax.Array,
-    *,
-    perm_a: tuple[int, ...],
-    perm_b: tuple[int, ...],
-    nb: int,
-    nm: int,
-    nn: int,
-    nk: int,
-    bm: int = 256,
-    bn: int = 256,
-    bk: int = 256,
-    interpret: bool | None = None,
-    precision: str = "fp32",
-) -> jax.Array:
-    """Fused transpose-GEMM over tree-native operand layouts, with complex
-    support (the same 3-real-GEMM Karatsuba as :func:`matmul` — real/imag
-    component extraction commutes with the in-kernel permutation, so the
-    components also stay in native layout; no transposed copy ever lands
-    in HBM).  Returns the natural (batch..., m..., n...) output, one axis
-    per role index.
-
-    ``precision="bf16"`` rounds each real component to bf16 before the
-    kernel (the in-kernel permutation commutes with the elementwise
-    cast); accumulation and output stay fp32.
-
-    Rank-0 operands / scalar outputs fall back to the materialized
-    permute + ``jnp.matmul`` reference — Pallas wants at least one output
-    axis, and the refiner never routes such nodes here anyway.
-    """
-    if interpret is None:
-        interpret = default_interpret()
-    if jnp.iscomplexobj(a) or jnp.iscomplexobj(b):
-        ar = jnp.real(a).astype(jnp.float32)
-        ai = jnp.imag(a).astype(jnp.float32)
-        br = jnp.real(b).astype(jnp.float32)
-        bi = jnp.imag(b).astype(jnp.float32)
-        kw = dict(perm_a=perm_a, perm_b=perm_b, nb=nb, nm=nm, nn=nn, nk=nk,
-                  bm=bm, bn=bn, bk=bk, interpret=interpret,
-                  precision=precision)
-        p1 = fused_matmul(ar, br, **kw)
-        p2 = fused_matmul(ai, bi, **kw)
-        p3 = fused_matmul(ar + ai, br + bi, **kw)
-        return (p1 - p2) + 1j * (p3 - p1 - p2)
-    if a.ndim == 0 or b.ndim == 0 or nb + nm + nn == 0:
-        import math
-
-        batch_shape = tuple(a.shape[p] for p in perm_a[:nb])
-        m_shape = tuple(a.shape[p] for p in perm_a[nb:nb + nm])
-        k_shape = tuple(a.shape[p] for p in perm_a[nb + nm:])
-        n_shape = tuple(b.shape[p] for p in perm_b[nb + nk:])
-        B, M = math.prod(batch_shape), math.prod(m_shape)
-        K, N = math.prod(k_shape), math.prod(n_shape)
-        a2 = jnp.transpose(a, perm_a).reshape(B, M, K)
-        b2 = jnp.transpose(b, perm_b).reshape(B, K, N)
-        return jnp.matmul(a2, b2).reshape(batch_shape + m_shape + n_shape)
-    if precision == "bf16":
-        a = a.astype(jnp.bfloat16)
-        b = b.astype(jnp.bfloat16)
-    with _trace.annotate("ops.fused_matmul"):
-        return fused_transpose_matmul(
-            a, b, perm_a=perm_a, perm_b=perm_b, nb=nb, nm=nm, nn=nn, nk=nk,
-            bm=bm, bn=bn, bk=bk, interpret=interpret,
-        )
-
-
-def fused_chain(
-    operands,
-    *,
-    forms: tuple,
-    carry_side: tuple[str, ...],
-    slot_ids: tuple[int, ...],
-    slot_elems: tuple[int, ...],
-    interpret: bool | None = None,
-    use_kernel: bool | None = None,
-    precisions: tuple[str, ...] | None = None,
-    slot_prec: tuple[str, ...] | None = None,
-):
-    """Execute a fused GEMM chain (see :class:`repro.lowering.refiner.
-    FusedChainSpec`): a run of adjacent tree contractions as one call,
-    intermediates VMEM-resident, with complex support.
-
-    Complex operands are split into fp32 ``(re, im)`` components *here*,
-    once, at the chain boundary — the carry stays component-split through
-    every step (per-step Karatsuba), so no complex intermediate is ever
-    materialized between chained steps.  On TPU the chain runs as the
-    persistent Pallas megakernel
-    (:func:`repro.kernels.contract_gemm.fused_chain_matmul`); off-TPU it
-    runs the same dataflow as one fused XLA program
-    (:func:`~repro.kernels.contract_gemm.chain_reference`) — interpret-
-    mode Pallas emulates kernels in Python per step, which would defeat
-    the fusion this path exists to measure.  ``use_kernel`` forces the
-    choice (the conformance suite exercises the kernel body explicitly
-    with ``use_kernel=True, interpret=True``).
-
-    ``precisions[t]`` is step ``t``'s GEMM input precision; interior
-    carries are rounded to their consumer's precision and held in VMEM
-    at the planned slot dtype (``slot_prec``) — kernel and reference
-    apply identical rounding, so they remain bitwise-comparable.
-    """
-    if interpret is None:
-        interpret = default_interpret()
-    if use_kernel is None:
-        use_kernel = not interpret
-    complex_mode = any(jnp.iscomplexobj(o) for o in operands)
-    comps = []
-    for o in operands:
-        o = jnp.asarray(o)
-        if complex_mode:
-            comps.append(jnp.real(o).astype(jnp.float32))
-            comps.append(jnp.imag(o).astype(jnp.float32))
-        else:
-            comps.append(o.astype(jnp.float32))
-    kw = dict(
-        forms=tuple(forms), carry_side=tuple(carry_side),
-        complex_mode=complex_mode,
-        precisions=tuple(precisions) if precisions is not None else None,
-    )
-    with _trace.annotate("ops.fused_chain"):
-        if use_kernel:
-            out = fused_chain_matmul(
-                *comps, slot_ids=tuple(slot_ids),
-                slot_elems=tuple(slot_elems), interpret=interpret,
-                slot_prec=tuple(slot_prec) if slot_prec is not None
-                else None,
-                **kw,
-            )
-        else:
-            out = chain_reference(comps, **kw)
-    if complex_mode:
-        re, im = out
-        return re + 1j * im
-    return out[0]
 
 
 def attention(

@@ -8,17 +8,11 @@ devices *before* any jax initialization.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5 explicit/auto axis types; older jax has neither
-    from jax.sharding import AxisType
 
-    def _axis_kwargs(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-
-except ImportError:
-
-    def _axis_kwargs(n: int) -> dict:
-        return {}
+def _axis_kwargs(n: int) -> dict:
+    return {"axis_types": (AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False):

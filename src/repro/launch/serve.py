@@ -69,6 +69,9 @@ def _burst(
 
 
 def main() -> None:
+    from .compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="serve amplitude/sampling traffic on the engine"
     )

@@ -8,6 +8,9 @@ port of that bridge between the planner and the kernels:
               transpose→reshape→GEMM→reshape form (batch/M/N/K index
               classification; open sampling indices ride as batch axes,
               sliced indices are fixed before lowering)
+  layout    — lane-dense storage: every buffer flat in a static index
+              order, permutations as transposes whose minor group is at
+              least one TPU lane tile wide, GEMM orientation per step
   refiner   — the Sec. V-B adaptive refiner for TPU: per-node backend
               choice (Pallas tiled_matmul / jnp.dot / jnp.einsum),
               MXU-128-snapped block shapes, pad-vs-split decisions, and
@@ -49,11 +52,11 @@ from .cache import (  # noqa: F401
     leaf_key,
     network_fingerprint,
 )
-from .gemm_form import GemmForm, apply, apply_chain, lower_step  # noqa: F401
+from .gemm_form import GemmForm, apply, contract_flat, lower_step  # noqa: F401
+from .layout import DenseStep, dense_step, permute_flat  # noqa: F401
 from .memory import (  # noqa: F401
     MemoryPlan,
     SegmentPlan,
-    chain_segment_plan,
     node_nbytes,
     peak_bytes,
     plan_memory,
@@ -69,17 +72,10 @@ from .precision import (  # noqa: F401
     tree_storage_itemsizes,
 )
 from .refiner import (  # noqa: F401
-    CHAIN_VMEM_BUDGET_BYTES,
-    ChainPlan,
-    FusedChainSpec,
     GemmSpec,
     LoweredSchedule,
-    default_fused,
-    default_megakernel,
     modeled_step_time,
     operand_transpose_bytes,
-    plan_chains,
-    plan_tree_chains,
     refine_schedule,
     refine_step,
     refine_tree_schedule,

@@ -158,103 +158,70 @@ def lower_step(
     )
 
 
-def apply(spec, a: jax.Array, b: jax.Array, *, interpret: bool | None = None):
-    """Execute one refined step (``spec`` is a refiner ``GemmSpec``).
+def contract_flat(spec, ds, a: jax.Array, b: jax.Array, *,
+                  interpret: bool | None = None) -> jax.Array:
+    """Execute one step on flat operands (the executor's storage form).
+
+    ``ds`` is the step's :class:`~repro.lowering.layout.DenseStep`:
+    each operand is permuted from its storage order into its GEMM order
+    through lane-dense transposes, the GEMM runs on 3-D ``(B, ·, ·)``
+    operands, and the flat result is in ``ds.out_order``.  ``spec`` (a
+    refiner ``GemmSpec``; ``None`` on the einsum backend) picks the
+    Pallas kernel for MXU-sized steps; every other step is one XLA
+    ``dot_general``.  fp32 steps ask for ``Precision.HIGHEST`` — on a
+    TPU the default f32 matmul rounds its inputs to bf16.
 
     Trace-safe: shapes and the backend choice are static, so this runs
     unchanged under ``jit``, the executor's slice-batch ``vmap``, and
-    ``shard_map``.
-    """
-    form: GemmForm = spec.form
-    if spec.backend == "einsum":
-        return jnp.einsum(form.expr, a, b)
+    ``shard_map``."""
+    from .layout import permute_flat  # lazy: avoid cycle
+
+    a3 = permute_flat(a, ds.a_order, ds.a_gemm, ds.size_of)
+    b3 = permute_flat(b, ds.b_order, ds.b_gemm, ds.size_of)
+    a3 = a3.reshape(ds.a_shape)
+    b3 = b3.reshape(ds.b_shape)
     real_bytes = real_component_bytes(jnp.result_type(a.dtype, b.dtype))
-    if spec.backend == "pallas_fused" and real_bytes <= 4:
+    precision = spec.precision if spec is not None else "fp32"
+    # 64-bit components would be silently truncated by the fp32 Pallas
+    # accumulator: they stay on XLA's dot
+    if spec is not None and spec.backend == "pallas" and real_bytes <= 4:
         from ..kernels import ops
 
-        # operands stay in their tree-native layouts: the kernel's
-        # index_maps apply perm_a/perm_b during tile loads, so the a2/b2
-        # HBM copies below never exist on this path.
-        out = ops.fused_matmul(
-            a, b,
-            perm_a=form.perm_a, perm_b=form.perm_b,
-            nb=len(form.batch_inds), nm=len(form.m_inds),
-            nn=len(form.n_inds), nk=len(form.k_inds),
-            bm=spec.bm, bn=spec.bn, bk=spec.bk,
+        mm = functools.partial(
+            ops.matmul, bm=spec.bm, bn=spec.bn, bk=spec.bk,
             interpret=interpret,
-            precision=getattr(spec, "precision", "fp32"),
+            min_kernel_dim=1,  # the refiner already gated tiny shapes
+            precision=precision,
         )
-    else:
-        a2 = jnp.transpose(a, form.perm_a).reshape(form.B, form.M, form.K)
-        b2 = jnp.transpose(b, form.perm_b).reshape(form.B, form.K, form.N)
-        if spec.backend == "dot" or real_bytes > 4:
-            # 64-bit components handed to a schedule refined for a
-            # narrower dtype would be silently truncated by the fp32
-            # Pallas accumulator — keep them on XLA's full-precision dot
-            # (this also catches a pallas_fused spec handed 64-bit
-            # arrays at trace time).
-            out = jnp.matmul(a2, b2)
-        elif spec.backend == "pallas":
-            from ..kernels import ops
-
-            mm = functools.partial(
-                ops.matmul,
-                bm=spec.bm,
-                bn=spec.bn,
-                bk=spec.bk,
-                interpret=interpret,
-                min_kernel_dim=1,  # the refiner already gated tiny shapes
-                precision=getattr(spec, "precision", "fp32"),
-            )
-            if form.B > 1:
-                out = jax.vmap(mm)(a2, b2)
-            else:
-                out = mm(a2[0], b2[0])[None]
+        if ds.a_shape[0] > 1:
+            out = jax.vmap(mm)(a3, b3)
         else:
-            raise ValueError(f"unknown lowering backend {spec.backend!r}")
-    out = out.reshape(form.batch_shape + form.m_shape + form.n_shape)
-    if form.out_perm != tuple(range(out.ndim)):
-        out = jnp.transpose(out, form.out_perm)
-    return out
-
-
-def apply_chain(
-    chain, specs, operands, *, interpret: bool | None = None,
-    use_kernel: bool | None = None,
-):
-    """Execute one fused chain (``chain`` is a refiner
-    :class:`~repro.lowering.refiner.FusedChainSpec`, ``specs`` the
-    GemmSpecs of its steps, ``operands`` the external buffers in
-    ``chain.external_nodes`` order) as a single megakernel call.
-
-    Trace-safe like :func:`apply` — the chain metadata is static — so the
-    same dispatch serves the vmapped slice scan, ``shard_map``, and the
-    resumable per-slice path.  64-bit components handed to a schedule
-    refined for a narrower dtype fall back to the sequential per-step
-    :func:`apply` (same trace-time guard as the single-step path: the
-    fp32 chain kernel would silently truncate them)."""
-    dt = jnp.result_type(*[o.dtype for o in operands])
-    if real_component_bytes(dt) > 4:
-        carry = apply(
-            specs[0], operands[0], operands[1], interpret=interpret
+            out = mm(a3[0], b3[0])[None]
+    else:
+        lhs, rhs = (b3, a3) if ds.swap else (a3, b3)
+        out = jax.lax.dot_general(
+            lhs, rhs, ds.dims, precision=jax.lax.Precision.HIGHEST
         )
-        for t in range(1, len(specs)):
-            ext = operands[t + 1]
-            a, b = (
-                (carry, ext) if chain.carry_side[t] == "l" else (ext, carry)
-            )
-            carry = apply(specs[t], a, b, interpret=interpret)
-        return carry
-    from ..kernels import ops
+    return out.reshape(-1)
 
-    return ops.fused_chain(
-        operands,
-        forms=tuple(s.form for s in specs),
-        carry_side=chain.carry_side,
-        slot_ids=chain.slot_ids,
-        slot_elems=chain.slot_elems,
-        interpret=interpret,
-        use_kernel=use_kernel,
-        precisions=tuple(getattr(s, "precision", "fp32") for s in specs),
-        slot_prec=getattr(chain, "slot_prec", None) or None,
+
+def apply(spec, a: jax.Array, b: jax.Array, *, interpret: bool | None = None):
+    """Execute one refined step (``spec`` is a refiner ``GemmSpec``) on
+    operands with one axis per index, in ``spec.form``'s orders; returns
+    the output in ``inds_out`` order.  Runs :func:`contract_flat`."""
+    from .layout import dense_step, permute_flat  # lazy: avoid cycle
+
+    form: GemmForm = spec.form
+    size = dict(zip(form.batch_inds, form.batch_shape))
+    size.update(zip(form.m_inds, form.m_shape))
+    size.update(zip(form.n_inds, form.n_shape))
+    size.update(zip(form.k_inds, form.k_shape))
+    ds = dense_step(
+        form.inds_a, form.inds_b, form.inds_out, size.__getitem__,
+        canonical=spec.backend == "pallas",
     )
+    out = contract_flat(
+        spec, ds, a.reshape(-1), b.reshape(-1), interpret=interpret
+    )
+    out = permute_flat(out, ds.out_order, form.inds_out, size.__getitem__)
+    return out.reshape(tuple(size[ix] for ix in form.inds_out))

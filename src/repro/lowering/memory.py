@@ -197,31 +197,6 @@ def _plan_segment(
     )
 
 
-def chain_segment_plan(
-    name: str,
-    entry,
-    steps,
-    outputs,
-    nbytes: dict[int, int],
-) -> SegmentPlan:
-    """Chain-local buffer plan for a fused-GEMM run (the epilogue
-    megakernel, :func:`repro.kernels.contract_gemm.fused_chain_matmul`).
-
-    Runs the same linear-scan allocator as :func:`plan_memory`'s
-    segments over just the chained steps, with every ``entry`` buffer
-    *pinned*: the megakernel DMAs whole operands into VMEM up front and
-    they stay resident for the duration of the chain, so only the
-    chain-interior intermediates compete for scratch slots.  The
-    returned :class:`SegmentPlan`'s ``peak_bytes`` is therefore the
-    certified VMEM live set of one chain execution (operands +
-    intermediates + output), and ``slot_of``/``slot_bytes`` are the
-    scratch-slot assignment the kernel allocates verbatim."""
-    return _plan_segment(
-        name, tuple(entry), tuple(entry), tuple(steps), tuple(outputs),
-        dict(nbytes),
-    )
-
-
 @dataclasses.dataclass(frozen=True)
 class MemoryPlan:
     """Lifetime-derived buffer plan for one ``(tree, S)`` pair.

@@ -53,7 +53,7 @@ BF16_UNIT_ROUNDOFF = 2.0 ** -9
 DEFAULT_FIDELITY_TOL = 0.05
 # backends that execute on the MXU with an fp32 accumulator — the only
 # ones that can take bf16 operands
-MXU_BACKENDS = ("pallas", "pallas_fused")
+MXU_BACKENDS = ("pallas",)
 
 
 def default_precision() -> str:
@@ -93,7 +93,6 @@ def assign_precision(
     epilogue_positions=None,
     n_slices: int = 1,
     min_kernel_dim: int = TPU_MXU,
-    fused: bool | None = None,
 ) -> LoweredSchedule:
     """Demote schedule steps to bf16 under the XEB error budget.
 
@@ -129,7 +128,7 @@ def assign_precision(
             continue
         spec16 = refine_step(
             spec.form, schedule.dtype, min_kernel_dim=min_kernel_dim,
-            fused=fused, precision="bf16",
+            precision="bf16",
         )
         if spec16.backend not in MXU_BACKENDS:
             continue
@@ -187,7 +186,6 @@ def tree_storage_itemsizes(
     itemsize: int = 8,
     mode: str | None = None,
     fidelity_tol: float | None = None,
-    fused: bool | None = None,
 ) -> dict[int, int] | None:
     """Planner-side storage-itemsize map for ``(tree, S)`` — what
     :func:`~repro.core.slicing.refine_slices_for_peak` needs to certify
@@ -203,7 +201,7 @@ def tree_storage_itemsizes(
     mode = default_precision() if mode is None else mode
     if mode == "fp32":
         return None
-    sched = refine_tree_schedule(tree, smask, dtype=dtype, fused=fused)
+    sched = refine_tree_schedule(tree, smask, dtype=dtype)
     order = tree.contract_order()
     epilogue = None
     n_slices = 1
@@ -217,7 +215,7 @@ def tree_storage_itemsizes(
         n_slices = 1 << popcount(smask)
     sched = assign_precision(
         sched, mode=mode, fidelity_tol=fidelity_tol,
-        epilogue_positions=epilogue, n_slices=n_slices, fused=fused,
+        epilogue_positions=epilogue, n_slices=n_slices,
     )
     if not sched.precision_counts().get("bf16"):
         return None

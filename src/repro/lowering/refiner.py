@@ -31,13 +31,11 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import os
 from typing import Callable, Hashable, Sequence
 
 import jax.numpy as jnp
 
 from ..core.merging import TPU_HBM_BW, TPU_MXU, TPU_PEAK_FLOPS
-from ..kernels.contract_gemm import suffix_tile_split
 from .gemm_form import GemmForm, lower_step, real_component_bytes
 
 # candidate Pallas block edges (multiples of the MXU tile)
@@ -56,16 +54,12 @@ NON_MXU_PEAK_FRACTION = 0.125
 class GemmSpec:
     """Refined, executable lowering of one contraction step.
 
-    For ``backend="pallas_fused"`` the block shapes are the *effective*
-    axis-suffix tiles (see ``kernels.contract_gemm.suffix_tile_split``),
-    which divide (B, M, N, K) exactly — no padding FLOPs, no materialized
-    operand transpose.  ``transpose_bytes`` is the HBM permute traffic
-    this spec pays (0 for fused/einsum — the fused saving is what
-    ``LoweredSchedule.transpose_bytes_eliminated`` totals up).
+    ``transpose_bytes`` is the HBM permute traffic this spec is charged
+    (0 on einsum nodes).
     """
 
     form: GemmForm
-    backend: str  # "pallas" | "pallas_fused" | "dot" | "einsum"
+    backend: str  # "pallas" | "dot" | "einsum"
     bm: int
     bn: int
     bk: int
@@ -73,30 +67,6 @@ class GemmSpec:
     pad_waste: float  # fraction of executed MXU FLOPs that are padding
     transpose_bytes: float = 0.0  # HBM bytes moved permuting the operands
     precision: str = "fp32"  # "fp32" | "bf16" (bf16-input/fp32-accumulate)
-
-
-def default_fused() -> bool:
-    """Whether the refiner may choose the fused transpose-GEMM backend:
-    the ``REPRO_FUSED_GEMM`` environment variable (CI runs the tier-1
-    gate under both values), defaulting to on.  ``REPRO_FUSED_GEMM=0``
-    is the off-switch back to the materialized permute + ``tiled_matmul``
-    reference path."""
-    v = os.environ.get("REPRO_FUSED_GEMM", "1")
-    if v not in ("0", "1"):
-        raise ValueError(f"REPRO_FUSED_GEMM={v!r} not in ('0', '1')")
-    return v == "1"
-
-
-def default_megakernel() -> bool:
-    """Whether the executor may fuse adjacent GEMMs into VMEM-resident
-    chains (the epilogue megakernel): the ``REPRO_MEGAKERNEL``
-    environment variable (CI runs the tier-1 gate under both values),
-    defaulting to on.  ``REPRO_MEGAKERNEL=0`` is the off-switch back to
-    one kernel dispatch per tree step."""
-    v = os.environ.get("REPRO_MEGAKERNEL", "1")
-    if v not in ("0", "1"):
-        raise ValueError(f"REPRO_MEGAKERNEL={v!r} not in ('0', '1')")
-    return v == "1"
 
 
 def precision_itemsize(dtype, precision: str = "fp32") -> int:
@@ -112,7 +82,7 @@ def operand_transpose_bytes(
 ) -> float:
     """HBM traffic of materializing the operand permutations: one read +
     one write per operand whose native layout is not already in GEMM
-    order — the ``2*(|A|+|B|)*bytes`` the fused kernel eliminates.
+    order — ``2*(|A|+|B|)*bytes``.
     Operands consumed at bf16 are permuted at their (halved) storage
     width."""
     itemsize = precision_itemsize(dtype, precision)
@@ -162,15 +132,12 @@ def modeled_step_time(
 ) -> tuple[float, float]:
     """(seconds, pad_waste) for one execution of this step.
 
-    Pallas is charged padded-tile FLOPs at full MXU peak; the fused
-    transpose-GEMM executes exact FLOPs (axis-suffix tiles never pad);
-    dot/einsum are charged exact FLOPs at the non-MXU effective peak.
-    All are capped by the HBM roofline on the operand + output traffic —
-    and the backends that materialize permuted operand copies
-    (``pallas``, ``dot``) additionally pay the ``2*(|A|+|B|)*bytes``
-    transpose bandwidth that the fused kernel (and XLA's fused einsum)
-    eliminates: a separate, non-overlappable HBM round-trip before the
-    GEMM proper.
+    Pallas is charged padded-tile FLOPs at full MXU peak; dot/einsum are
+    charged exact FLOPs at the non-MXU effective peak.  All are capped by
+    the HBM roofline on the operand + output traffic — and the backends
+    that materialize permuted operand copies (``pallas``, ``dot``)
+    additionally pay the ``2*(|A|+|B|)*bytes`` transpose bandwidth: a
+    separate, non-overlappable HBM round-trip before the GEMM proper.
 
     ``precision="bf16"`` (MXU backends only) doubles the systolic-array
     rate and halves the operand-side traffic — bf16 inputs, fp32
@@ -192,9 +159,6 @@ def modeled_step_time(
         )
         t_compute = padded / mxu_peak
         waste = 1.0 - flops / padded
-    elif backend == "pallas_fused":
-        t_compute = flops / mxu_peak
-        waste = 0.0
     else:
         t_compute = flops / (TPU_PEAK_FLOPS * NON_MXU_PEAK_FRACTION)
         waste = 0.0
@@ -209,16 +173,9 @@ def refine_step(
     dtype,
     *,
     min_kernel_dim: int = TPU_MXU,
-    fused: bool | None = None,
     precision: str = "fp32",
 ) -> GemmSpec:
     """Pick backend + block shapes for one normalized contraction step.
-
-    ``fused`` gates the fused transpose-GEMM candidates (default:
-    :func:`default_fused`, i.e. ``REPRO_FUSED_GEMM``).  A fused candidate
-    is admissible when its effective axis-suffix tiles are still
-    MXU-sized — its cost model pays no padding FLOPs and no operand
-    transpose bandwidth, so it wins whenever admissible.
 
     ``precision="bf16"`` refines the step under the bf16-input/
     fp32-accumulate model: the VMEM working-set check counts 2-byte
@@ -227,8 +184,6 @@ def refine_step(
     rate / half operand traffic.  Only MXU backends carry the precision —
     dot/einsum fallbacks always execute fp32.
     """
-    if fused is None:
-        fused = default_fused()
     real_bytes = real_component_bytes(dtype)
     if form.flops < EINSUM_FLOPS_FLOOR:
         t, w = modeled_step_time(form, dtype, "einsum", 1, 1, 1)
@@ -258,27 +213,6 @@ def refine_step(
                 if best is None or t < best.modeled_time_s:
                     best = GemmSpec(
                         form, "pallas", bm, bn, bk, t, w, tbytes, precision
-                    )
-                if not fused:
-                    continue
-                # fused candidate at the same targets: effective tiles are
-                # the axis-suffix products, admissible while MXU-sized
-                _, _, tm = suffix_tile_split(form.m_shape, bm)
-                _, _, tn = suffix_tile_split(form.n_shape, bn)
-                _, _, tk = suffix_tile_split(form.k_shape, bk)
-                if min(tm, tn, tk) < min_kernel_dim:
-                    continue
-                if ob * (tm * tk + tk * tn) + 4 * tm * tn > (
-                    VMEM_BUDGET_BYTES
-                ):
-                    continue
-                tf, wf = modeled_step_time(
-                    form, dtype, "pallas_fused", tm, tn, tk, precision
-                )
-                if tf < best.modeled_time_s:
-                    best = GemmSpec(
-                        form, "pallas_fused", tm, tn, tk, tf, wf, 0.0,
-                        precision,
                     )
     return best
 
@@ -341,18 +275,8 @@ class LoweredSchedule:
 
     def transpose_bytes(self) -> float:
         """HBM bytes this schedule spends materializing operand
-        permutations (per slice) — zero on fused/einsum nodes."""
+        permutations (per slice) — zero on einsum nodes."""
         return sum(s.transpose_bytes for s in self.specs)
-
-    def transpose_bytes_eliminated(self) -> float:
-        """HBM bytes of operand-transpose traffic the fused nodes avoid
-        (per slice): what the reference permute + ``tiled_matmul`` path
-        would have moved for every ``pallas_fused`` node."""
-        return sum(
-            operand_transpose_bytes(s.form, self.dtype)
-            for s in self.specs
-            if s.backend == "pallas_fused"
-        )
 
     def summary(self) -> dict:
         return {
@@ -361,7 +285,6 @@ class LoweredSchedule:
             "pad_waste": self.pad_waste(),
             "modeled_time_s": self.modeled_time_s,
             "transpose_bytes": self.transpose_bytes(),
-            "transpose_bytes_eliminated": self.transpose_bytes_eliminated(),
             "dtype": self.dtype,
             "precision_mode": self.precision_mode,
             "precision_counts": self.precision_counts(),
@@ -373,7 +296,7 @@ class LoweredSchedule:
         c = self.backend_counts()
         per = " ".join(
             f"{k}={c[k]}"
-            for k in ("pallas_fused", "pallas", "dot", "einsum")
+            for k in ("pallas", "dot", "einsum")
             if k in c
         )
         pc = self.precision_counts()
@@ -396,15 +319,12 @@ def refine_schedule(
     dtype=jnp.complex64,
     *,
     min_kernel_dim: int = TPU_MXU,
-    fused: bool | None = None,
 ) -> LoweredSchedule:
     """Lower + refine every ``(inds_a, inds_b, inds_out)`` step."""
-    if fused is None:
-        fused = default_fused()
     specs = [
         refine_step(
             lower_step(ia, ib, io, size_of), dtype,
-            min_kernel_dim=min_kernel_dim, fused=fused,
+            min_kernel_dim=min_kernel_dim,
         )
         for ia, ib, io in steps
     ]
@@ -417,7 +337,6 @@ def refine_tree_schedule(
     dtype=jnp.complex64,
     *,
     min_kernel_dim: int = TPU_MXU,
-    fused: bool | None = None,
 ) -> LoweredSchedule:
     """Refine the kernel schedule for every step of ``(tree, S)``
     directly from the contraction tree — planner-side usage (modeled
@@ -443,327 +362,7 @@ def refine_tree_schedule(
         node_inds[v] = out
     return refine_schedule(
         steps, tree.tn.size_of, dtype=dtype,
-        min_kernel_dim=min_kernel_dim, fused=fused,
-    )
-
-
-# ----------------------------------------------------------------------
-# fusion-boundary pass: greedy VMEM-resident chain growth along the
-# schedule (the epilogue megakernel's planning half)
-# ----------------------------------------------------------------------
-
-# live-set ceiling for one fused chain: whole operands + scratch slots +
-# output must be simultaneously VMEM-resident (vs ~16 MB/core), leaving
-# headroom for the final output's store buffering.  Deliberately larger
-# than the per-GEMM tile budget (VMEM_BUDGET_BYTES) — a chain replaces
-# several kernels' working sets with one residency certified by the
-# lifetime planner's linear scan.
-CHAIN_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
-# batch cells are unrolled into per-cell MXU dots inside the megakernel;
-# cap the unroll so open-batch sampling networks keep sane trace sizes
-CHAIN_MAX_BATCH = 256
-
-
-@dataclasses.dataclass(frozen=True)
-class FusedChainSpec:
-    """One planned VMEM-resident GEMM chain.
-
-    ``positions`` are consecutive entries of one execution segment's step
-    sequence (never crossing the prologue/epilogue boundary — chains are
-    planned per segment); step ``t``'s carry operand is step ``t-1``'s
-    output (``carry_side[t]`` ∈ {"l", "r"}, ``""`` at the head).
-    ``external_nodes`` are the env keys the executor gathers as kernel
-    operands (step 0's pair, then one non-carry operand per step);
-    ``slot_ids``/``slot_elems`` are the scratch-slot assignment of the
-    interior intermediates from the chain-local linear scan
-    (:func:`repro.lowering.memory.chain_segment_plan`), and
-    ``live_bytes`` is that scan's certified VMEM peak.
-
-    The saved-traffic accounting keeps the two eliminations disjoint so
-    nothing is double-charged: ``roundtrip_bytes_saved`` is the plain
-    HBM write+read of each interior intermediate, while
-    ``transpose_bytes_saved`` is only the *extra* permute-copy traffic
-    the unfused backends would have paid (``GemmSpec.transpose_bytes``,
-    already zero on fused/einsum steps) — a carry operand's transpose
-    bandwidth is therefore counted once, not once per elimination.
-    """
-
-    segment: str
-    positions: tuple[int, ...]
-    nodes: tuple[tuple[int, int, int], ...]  # (lhs, rhs, out) env keys
-    carry_side: tuple[str, ...]
-    external_nodes: tuple[int, ...]
-    out_node: int
-    live_bytes: int
-    slot_ids: tuple[int, ...]
-    slot_elems: tuple[int, ...]
-    roundtrip_bytes_saved: float
-    transpose_bytes_saved: float
-    # per-scratch-slot storage precision: "bf16" when every interior
-    # intermediate assigned to the slot is consumed at bf16 (the slot is
-    # then a bf16 VMEM buffer at half the bytes), "fp32" otherwise.
-    # Empty (the default) means all-fp32 — pre-precision plans.
-    slot_prec: tuple[str, ...] = ()
-
-    @property
-    def n_steps(self) -> int:
-        return len(self.positions)
-
-    @property
-    def hbm_bytes_saved(self) -> float:
-        """Modeled HBM bytes one execution of this chain avoids."""
-        return self.roundtrip_bytes_saved + self.transpose_bytes_saved
-
-
-@dataclasses.dataclass
-class ChainPlan:
-    """All fused chains planned for one ``(tree, S)`` schedule."""
-
-    chains: tuple[FusedChainSpec, ...]
-    vmem_budget: int
-
-    def by_segment(self, name: str) -> dict[int, FusedChainSpec]:
-        """start position → chain, for one segment's dispatch loop."""
-        return {
-            c.positions[0]: c for c in self.chains if c.segment == name
-        }
-
-    def segment_chains(self, name: str) -> list[FusedChainSpec]:
-        return [c for c in self.chains if c.segment == name]
-
-    @property
-    def num_chains(self) -> int:
-        return len(self.chains)
-
-    @property
-    def num_multi(self) -> int:
-        """Chains fusing ≥ 2 steps (all of them, per the planner's
-        ``min_len`` — kept explicit for reporting/regression gates)."""
-        return sum(1 for c in self.chains if c.n_steps >= 2)
-
-    def max_live_bytes(self) -> int:
-        return max((c.live_bytes for c in self.chains), default=0)
-
-    def hbm_bytes_saved(self, segment: str = "naive") -> float:
-        """Modeled HBM bytes saved per execution of ``segment`` (for the
-        epilogue that is once per slice)."""
-        return sum(
-            c.hbm_bytes_saved for c in self.chains if c.segment == segment
-        )
-
-    def modeled_time_saved_s(self, segment: str = "naive") -> float:
-        """Per-execution seconds of HBM traffic the chains eliminate —
-        the refiner cost-model correction for fused steps (their
-        round-trip and transpose charges no longer apply)."""
-        return self.hbm_bytes_saved(segment) / TPU_HBM_BW
-
-    def summary(self) -> dict:
-        return {
-            "chains": self.num_chains,
-            "multi_step_chains": self.num_multi,
-            "max_chain_len": max(
-                (c.n_steps for c in self.chains), default=0
-            ),
-            "max_live_bytes": self.max_live_bytes(),
-            "vmem_budget": self.vmem_budget,
-            "hbm_bytes_saved": {
-                seg: self.hbm_bytes_saved(seg)
-                for seg in sorted({c.segment for c in self.chains})
-            },
-        }
-
-
-def _chainable(spec: GemmSpec, real_bytes: int) -> bool:
-    """Whether one step may participate in a fused chain: fp32-component
-    dtypes only (the kernel accumulates in fp32), at least one axis per
-    operand/output (Pallas wants a real block; the refiner's degenerate
-    scalar nodes stay unfused), bounded batch unroll."""
-    f = spec.form
-    return (
-        real_bytes <= 4
-        and len(f.inds_a) >= 1
-        and len(f.inds_b) >= 1
-        and len(f.inds_out) >= 1
-        and f.B <= CHAIN_MAX_BATCH
-    )
-
-
-def _build_chain(
-    segment: str,
-    run: list[int],
-    step_nodes,
-    specs,
-    nbytes: dict[int, int],
-    itemsize: int,
-    itemsize_of: dict[int, int] | None = None,
-):
-    """Assemble the FusedChainSpec (or its certification plan) for one
-    candidate run of schedule positions.  Returns ``(spec, live_bytes)``.
-
-    ``itemsize_of`` maps env keys to their *storage* itemsize when the
-    precision planner stores some nodes as bf16 component pairs —
-    ``nbytes`` is then precision-aware, and the scratch-slot element
-    counts must divide by each node's own itemsize, not the schedule
-    dtype's."""
-    from .memory import chain_segment_plan  # lazy: avoid cycle
-
-    def isz(v: int) -> int:
-        return itemsize_of.get(v, itemsize) if itemsize_of else itemsize
-
-    nodes = tuple(step_nodes[p] for p in run)
-    carry_side = [""]
-    externals = [nodes[0][0], nodes[0][1]]
-    for t in range(1, len(nodes)):
-        prev_out = nodes[t - 1][2]
-        l, r, _ = nodes[t]
-        if l == prev_out:
-            carry_side.append("l")
-            externals.append(r)
-        else:
-            carry_side.append("r")
-            externals.append(l)
-    out_node = nodes[-1][2]
-    seg = chain_segment_plan(
-        f"chain:{segment}:{run[0]}", tuple(externals), nodes, (out_node,),
-        nbytes,
-    )
-    interior = [nodes[t][2] for t in range(len(nodes) - 1)]
-    used = sorted({seg.slot_of[v] for v in interior})
-    remap = {s: d for d, s in enumerate(used)}
-    slot_ids = tuple(remap[seg.slot_of[v]] for v in interior)
-    slot_bytes = [0] * len(used)
-    slot_elems = [0] * len(used)
-    slot_wide = [False] * len(used)
-    for t, v in enumerate(interior):
-        d = remap[seg.slot_of[v]]
-        slot_bytes[d] = max(slot_bytes[d], nbytes[v])
-        slot_elems[d] = max(slot_elems[d], nbytes[v] // isz(v))
-        # the consuming step (t+1 within the run) fixes the interior's
-        # storage precision; a slot is bf16 only if no occupant needs f32
-        if specs[run[t + 1]].precision != "bf16":
-            slot_wide[d] = True
-    roundtrip = sum(2.0 * nbytes[v] for v in interior)
-    transpose = sum(specs[p].transpose_bytes for p in run)
-    spec = FusedChainSpec(
-        segment=segment,
-        positions=tuple(run),
-        nodes=nodes,
-        carry_side=tuple(carry_side),
-        external_nodes=tuple(externals),
-        out_node=out_node,
-        live_bytes=seg.peak_bytes,
-        slot_ids=slot_ids,
-        slot_elems=tuple(slot_elems),
-        roundtrip_bytes_saved=roundtrip,
-        transpose_bytes_saved=transpose,
-        slot_prec=tuple(
-            "fp32" if wide else "bf16" for wide in slot_wide
-        ),
-    )
-    return spec, seg.peak_bytes
-
-
-def plan_chains(
-    schedule: LoweredSchedule,
-    step_nodes: Sequence[tuple[int, int, int]],
-    segments: dict[str, tuple[int, ...]],
-    nbytes: dict[int, int],
-    *,
-    vmem_budget: int = CHAIN_VMEM_BUDGET_BYTES,
-    min_len: int = 2,
-    itemsize_of: dict[int, int] | None = None,
-) -> ChainPlan:
-    """The fusion-boundary pass: greedily grow runs of adjacent steps
-    along each segment's execution order while the certified live set —
-    whole operands pinned, intermediates slot-assigned by the chain-local
-    linear scan — fits the VMEM budget.
-
-    ``step_nodes[p]`` are the ``(lhs, rhs, out)`` env keys of schedule
-    position ``p``; ``segments`` maps each execution segment to its
-    ordered positions, so a chain can never cross the prologue/epilogue
-    boundary, and a segment *output* (the root, or a hoisted frontier
-    buffer) can never be chain-interior — its consumer is outside the
-    segment, so adjacency fails there by construction.  ``nbytes`` is the
-    per-node buffer size from the memory plan (same dict for every
-    segment); under a mixed-precision plan it is dtype-true (bf16-stored
-    nodes at half bytes) and ``itemsize_of`` supplies each node's storage
-    itemsize so scratch slots are sized in elements correctly — the
-    CHAIN_VMEM_BUDGET_BYTES residency check thereby admits longer chains
-    when interiors are bf16."""
-    itemsize = int(jnp.dtype(schedule.dtype).itemsize)
-    real_bytes = real_component_bytes(schedule.dtype)
-    chains: list[FusedChainSpec] = []
-    for name, positions in segments.items():
-        i = 0
-        while i < len(positions):
-            p = positions[i]
-            if not _chainable(schedule.specs[p], real_bytes):
-                i += 1
-                continue
-            run = [p]
-            j = i
-            while j + 1 < len(positions):
-                q = positions[j + 1]
-                prev_out = step_nodes[run[-1]][2]
-                if (
-                    step_nodes[q][0] != prev_out
-                    and step_nodes[q][1] != prev_out
-                ):
-                    break
-                if not _chainable(schedule.specs[q], real_bytes):
-                    break
-                _, live = _build_chain(
-                    name, run + [q], step_nodes, schedule.specs, nbytes,
-                    itemsize, itemsize_of,
-                )
-                if live > vmem_budget:
-                    break
-                run.append(q)
-                j += 1
-            if len(run) >= min_len:
-                spec, _ = _build_chain(
-                    name, run, step_nodes, schedule.specs, nbytes,
-                    itemsize, itemsize_of,
-                )
-                chains.append(spec)
-            i = j + 1
-    return ChainPlan(chains=tuple(chains), vmem_budget=vmem_budget)
-
-
-def plan_tree_chains(
-    tree,
-    smask: int = 0,
-    dtype=jnp.complex64,
-    *,
-    hoist: bool = True,
-    fused: bool | None = None,
-    vmem_budget: int = CHAIN_VMEM_BUDGET_BYTES,
-) -> ChainPlan:
-    """Planner-side chain plan for ``(tree, S)`` — the same pass the
-    executor runs at plan construction, built directly from the tree
-    (pinned regressions, modeled benchmarks; no ContractionPlan
-    needed)."""
-    from .memory import node_nbytes  # lazy: avoid cycle
-
-    sched = refine_tree_schedule(tree, smask, dtype=dtype, fused=fused)
-    order = tree.contract_order()
-    step_nodes = tuple((*tree.children[v], v) for v in order)
-    itemsize = jnp.dtype(dtype).itemsize
-    nbytes = {
-        v: node_nbytes(tree, v, smask, itemsize) for v in tree.emask
-    }
-    segments: dict[str, tuple[int, ...]] = {
-        "naive": tuple(range(len(step_nodes)))
-    }
-    if hoist and smask and step_nodes:
-        from .partition import partition_tree  # lazy: avoid cycle
-
-        part = partition_tree(tree, smask)
-        pos = {v: k for k, v in enumerate(order)}
-        segments["prologue"] = tuple(pos[v] for v in part.invariant_nodes)
-        segments["epilogue"] = tuple(pos[v] for v in part.epilogue_nodes)
-    return plan_chains(
-        sched, step_nodes, segments, nbytes, vmem_budget=vmem_budget
+        min_kernel_dim=min_kernel_dim,
     )
 
 
@@ -773,7 +372,6 @@ def modeled_plan_time(
     dtype=jnp.complex64,
     *,
     part=None,
-    fused: bool | None = None,
     precision: str = "fp32",
     fidelity_tol: float | None = None,
 ) -> float:
@@ -789,14 +387,13 @@ def modeled_plan_time(
     would actually run under (see :mod:`repro.lowering.precision`)."""
     from ..core.tensor_network import popcount  # lazy: avoid cycle
 
-    sched = refine_tree_schedule(tree, smask, dtype=dtype, fused=fused)
+    sched = refine_tree_schedule(tree, smask, dtype=dtype)
     if not smask:
         if precision != "fp32":
             from .precision import assign_precision  # lazy: avoid cycle
 
             sched = assign_precision(
                 sched, mode=precision, fidelity_tol=fidelity_tol,
-                fused=fused,
             )
         return sched.modeled_time_s
     if part is None:
@@ -814,7 +411,7 @@ def modeled_plan_time(
         )
         sched = assign_precision(
             sched, mode=precision, fidelity_tol=fidelity_tol,
-            epilogue_positions=epilogue, n_slices=n_slices, fused=fused,
+            epilogue_positions=epilogue, n_slices=n_slices,
         )
     prologue_t = sum(
         spec.modeled_time_s

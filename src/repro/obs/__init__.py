@@ -11,7 +11,7 @@ claims — Sec. VI).  Three parts:
     profiles), JSONL export readable by Perfetto.
   * :mod:`repro.obs.metrics` — process-global named counters / gauges /
     histograms (plan-cache and HoistCache hits/misses/evicted bytes,
-    slices executed, chains fused, executed FLOPs, ragged-padding
+    slices executed, executed FLOPs, ragged-padding
     waste; the multi-host scheduler adds per-host queue depth gauges
     ``sched.queue_depth.h<h>``, the ``sched.steals`` counter, the
     ``sched.steal_latency_s`` histogram — drain-to-claim latency of

@@ -8,10 +8,10 @@ ever *checked* those models against real hardware.  :func:`calibrate_plan`
 executes a plan's steps eagerly, one at a time, with a
 ``block_until_ready`` fence around each, and joins the measured walls
 with the modeled per-slice times into a per-backend-class table
-(``pallas`` / ``pallas_fused`` / ``chain`` / ``dot`` / ``einsum``; under
-mixed precision, non-fp32 steps split into their own rows, e.g.
-``pallas[bf16]`` / ``chain[mixed]`` — bf16 runs against a different MXU
-roofline, so its measured/modeled ratio is a separate signal).
+(``pallas`` / ``dot`` / ``einsum``; under mixed precision, non-fp32
+steps split into their own rows, e.g. ``pallas[bf16]`` — bf16 runs
+against a different MXU roofline, so its measured/modeled ratio is a
+separate signal).
 
 The measured/modeled ratio per class is the feedback signal the
 ROADMAP's adaptive refiner and work-stealing scheduler need: a class
@@ -33,21 +33,21 @@ import time
 
 @dataclasses.dataclass
 class CalibrationRow:
-    """One executed step (or fused chain) of the plan."""
+    """One executed step of the plan."""
 
-    node: int  # tree node id of the step output (chain: its out node)
-    backend: str  # pallas | pallas_fused | dot | einsum | chain
+    node: int  # tree node id of the step output
+    backend: str  # pallas | dot | einsum
     measured_s: float  # min-over-repeat eager wall, block_until_ready
     modeled_s: float  # refiner / cost-model per-slice seconds
     flops: float  # modeled real-multiply FLOPs of the step (per slice)
-    precision: str = "fp32"  # operand precision (chain: "mixed" if split)
+    precision: str = "fp32"  # operand precision
 
     @property
     def cls(self) -> str:
         """Calibration class: the backend, qualified by precision when
-        the step does not run at full fp32 (``pallas[bf16]``,
-        ``chain[mixed]``, …) — bf16 steps hit a different roofline, so
-        folding them into the fp32 rows would skew both ratios."""
+        the step does not run at full fp32 (``pallas[bf16]``, …) — bf16
+        steps hit a different roofline, so folding them into the fp32
+        rows would skew both ratios."""
         if self.precision == "fp32":
             return self.backend
         return f"{self.backend}[{self.precision}]"
@@ -128,16 +128,15 @@ def calibrate_plan(plan, arrays, slice_id: int = 0, repeat: int = 2):
     """Execute one slice of ``plan`` step-by-step (eagerly, fenced) and
     join each step's measured wall with its modeled per-slice time.
 
-    Honors the plan's fused-chain dispatch (``_chain_dispatch["naive"]``)
-    so chain steps are measured as the single ``apply_chain`` call they
-    execute as, and classed ``"chain"`` with the chain's modeled time
-    (sum of member specs minus the modeled HBM traffic saving).  Returns
-    a :class:`CalibrationReport`.
+    Each step runs the executor's own lane-dense dispatch
+    (:func:`repro.lowering.gemm_form.contract_flat`) on flat buffers.
+    Returns a :class:`CalibrationReport`.
     """
     import jax.numpy as jnp
     from jax import lax
 
-    from ..core.merging import TPU_HBM_BW, modeled_node_time
+    from ..core.merging import modeled_node_time
+    from ..lowering import gemm_form
     from ..obs import trace
 
     # slice the leaves for the concrete slice assignment
@@ -147,67 +146,24 @@ def calibrate_plan(plan, arrays, slice_id: int = 0, repeat: int = 2):
         a = jnp.asarray(arrays[i])
         for axis, spos in plan.leaf_specs[i]:
             a = lax.index_in_dim(a, svals[spos], axis=axis, keepdims=False)
-        env[i] = a
+        env[i] = a.reshape(-1)
 
-    chains = plan._chain_dispatch.get("naive", {})
     n_sub = 1 << plan.num_sliced
     rows: list[CalibrationRow] = []
-    k = 0
-    while k < len(plan.steps):
-        ch = chains.get(k)
-        if ch is not None:
-            from ..lowering import gemm_form
-
-            specs = [plan.schedule.specs[p] for p in ch.positions]
-            operands = [env[n] for n in ch.external_nodes]
-            with trace.span("calib.node", cat="calib", node=ch.out_node):
-                measured, out = _time_call(
-                    lambda: gemm_form.apply_chain(ch, specs, operands),
-                    repeat,
-                )
-            env[ch.out_node] = out
-            modeled = (
-                sum(s.modeled_time_s for s in specs)
-                - ch.hbm_bytes_saved / TPU_HBM_BW
-            )
-            flops = sum(s.form.flops for s in specs)
-            precs = {getattr(s, "precision", "fp32") for s in specs}
-            rows.append(
-                CalibrationRow(
-                    node=ch.out_node,
-                    backend="chain",
-                    measured_s=measured,
-                    modeled_s=max(modeled, 0.0),
-                    flops=flops,
-                    precision=(
-                        precs.pop() if len(precs) == 1 else "mixed"
-                    ),
-                )
-            )
-            k += ch.n_steps
-            continue
-        st = plan.steps[k]
+    for k, st in enumerate(plan.steps):
         a, b = env[st.lhs], env[st.rhs]
-        if plan.schedule is None:
-            expr = st.expr
-            with trace.span("calib.node", cat="calib", node=st.out):
-                measured, out = _time_call(
-                    lambda: jnp.einsum(expr, a, b), repeat
-                )
+        spec = plan.schedule.specs[k] if plan.schedule is not None else None
+        ds = plan.dense_steps[k]
+        with trace.span("calib.node", cat="calib", node=st.out):
+            measured, out = _time_call(
+                lambda: gemm_form.contract_flat(spec, ds, a, b), repeat
+            )
+        if spec is None:
             modeled = (
                 modeled_node_time(plan.tree, st.out, plan.smask) / n_sub
             )
-            cls = "einsum"
-            flops = 0.0
-            prec = "fp32"
+            cls, flops, prec = "einsum", 0.0, "fp32"
         else:
-            from ..lowering import gemm_form
-
-            spec = plan.schedule.specs[k]
-            with trace.span("calib.node", cat="calib", node=st.out):
-                measured, out = _time_call(
-                    lambda: gemm_form.apply(spec, a, b), repeat
-                )
             modeled = spec.modeled_time_s
             cls = spec.backend
             flops = spec.form.flops
@@ -223,7 +179,6 @@ def calibrate_plan(plan, arrays, slice_id: int = 0, repeat: int = 2):
                 precision=prec,
             )
         )
-        k += 1
 
     mem = plan.memory_plan()
     return CalibrationReport(

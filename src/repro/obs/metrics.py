@@ -2,8 +2,7 @@
 
 The numeric side of the observability layer (spans answer *where time
 went*; metrics answer *how much work happened*): plan-cache and
-HoistCache hits/misses/evicted bytes, slices executed, fused-chain
-dispatches, executed FLOPs, ragged-padding waste, search accept/reject
+HoistCache hits/misses/evicted bytes, slices executed, executed FLOPs, ragged-padding waste, search accept/reject
 counts, serving queue/compute latencies.  The registry is thread-safe,
 snapshot-able as one plain dict (:func:`snapshot`) and reset-able for
 tests (:func:`reset`).

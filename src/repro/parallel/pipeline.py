@@ -37,8 +37,6 @@ def pipeline_forward(
     Returns the full (n_micro, mb, ...) output (valid on every device —
     the last stage's results are broadcast with a psum at the end).
     """
-    from jax.experimental.shard_map import shard_map
-
     n_stages = mesh.shape[axis]
     L = jax.tree.leaves(stacked_params)[0].shape[0]
     assert L % n_stages == 0, (L, n_stages)
@@ -76,12 +74,12 @@ def pipeline_forward(
         # broadcast the last stage's outputs to every pipeline rank
         return jax.lax.psum(out, axis)
 
-    return shard_map(
+    return jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(param_specs, x_spec),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stacked_params, x)
 
 

@@ -63,8 +63,6 @@ def compressed_psum(
     XLA psum on int8 would overflow — we widen to bf16 on the wire, still
     2× smaller than f32), then de-scaled by the max scale.
     """
-    from jax.experimental.shard_map import shard_map
-
     def body(g, r):
         q, s, r2 = compress_with_feedback(g, r)
         # wire format: int8 payload + per-tensor scale; psum over pods
@@ -81,10 +79,10 @@ def compressed_psum(
         return summed, r2
 
     spec = jax.sharding.PartitionSpec()
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec),
         out_specs=(spec, spec),
-        check_rep=False,
+        check_vma=False,
     )(grads, residuals)

@@ -2,6 +2,7 @@ import os
 import random
 
 import pytest
+from hypothesis import HealthCheck, settings
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -13,66 +14,14 @@ def subprocess_kwargs() -> dict:
     env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src")
     return {"env": env, "cwd": REPO_ROOT}
 
-try:
-    from hypothesis import HealthCheck, settings
 
-    settings.register_profile(
-        "ci",
-        max_examples=25,
-        deadline=None,
-        suppress_health_check=[HealthCheck.too_slow],
-    )
-    settings.load_profile("ci")
-except ModuleNotFoundError:
-    # hypothesis is optional: property-based tests skip cleanly when it is
-    # absent, while plain tests in the same modules keep running.  We install
-    # a shim into sys.modules *before* test modules are collected (conftest
-    # imports first), providing the exact names the test-suite uses:
-    # given / settings / strategies-as-st / HealthCheck.
-    import sys
-    import types
-
-    def _strategy(*_a, **_k):
-        return None
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.__getattr__ = lambda name: _strategy  # PEP 562: any strategy name
-
-    def given(*_a, **_k):
-        def deco(fn):
-            def wrapper(*args, **kwargs):
-                pytest.skip("hypothesis not installed")
-
-            wrapper.__name__ = fn.__name__
-            wrapper.__doc__ = fn.__doc__
-            return wrapper
-
-        return deco
-
-    class settings:  # noqa: N801 - mirrors hypothesis.settings
-        def __init__(self, *_a, **_k):
-            pass
-
-        def __call__(self, fn):
-            return fn
-
-        @staticmethod
-        def register_profile(*_a, **_k):
-            pass
-
-        @staticmethod
-        def load_profile(*_a, **_k):
-            pass
-
-    HealthCheck = types.SimpleNamespace(too_slow=None, filter_too_much=None)
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = given
-    _hyp.settings = settings
-    _hyp.strategies = _st
-    _hyp.HealthCheck = HealthCheck
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
+settings.register_profile(
+    "ci",
+    max_examples=25,
+    deadline=None,
+    suppress_health_check=[HealthCheck.too_slow],
+)
+settings.load_profile("ci")
 
 
 def random_closed_network(n_tensors: int, degree: int, seed: int):

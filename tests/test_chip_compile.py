@@ -1,0 +1,164 @@
+"""Compile the main path for a described TPU v5e, without a chip.
+
+The TPU compiler is installed next to JAX, and it compiles for a chip
+that is described rather than attached.  These tests compile the Pallas
+GEMM kernel at the tile shapes the refiner emits and whole slice
+programs of small gemm and einsum plans, and assert that the kernel is
+really in the program (``tpu_custom_call``) and that the compiled
+footprint stays near the lifetime planner's certified peak.  Nothing
+runs, so they say nothing about results or times.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+
+from __future__ import annotations
+
+import itertools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from repro.core.api import plan_compiled
+from repro.core.executor import simplify_network
+from repro.engine.session import ContractionSession
+from repro.kernels import ops, tiled_matmul
+from repro.lowering.refiner import BLOCK_CANDIDATES, VMEM_BUDGET_BYTES
+from repro.quantum.circuits import circuit_to_network, sycamore_like
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means no v5e here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def compile_for_chip(monkeypatch):
+    """Kernels compile for the chip (not the interpreter), and the
+    persistent cache is off: an entry compiled for a described chip
+    cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                sharding=sharding)
+
+
+# the (bm, bn, bk) tiles the refiner may emit at fp32
+REFINER_TILES = [
+    t for t in itertools.product(BLOCK_CANDIDATES, repeat=3)
+    if 4 * (t[0] * t[2] + t[2] * t[1]) + 4 * t[0] * t[1] <= VMEM_BUDGET_BYTES
+]
+
+
+@pytest.mark.parametrize("bm,bn,bk", REFINER_TILES)
+def test_tiled_matmul_compiles_at_refiner_tiles(one_chip, bm, bn, bk):
+    a = _sds((2 * bm, 2 * bk), jnp.float32, one_chip)
+    b = _sds((2 * bk, 2 * bn), jnp.float32, one_chip)
+    fn = jax.jit(lambda x, y: tiled_matmul(x, y, bm=bm, bn=bn, bk=bk))
+    text = fn.lower(a, b).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+def test_complex_karatsuba_matmul_compiles(one_chip, precision):
+    a = _sds((256, 384), jnp.complex64, one_chip)
+    b = _sds((384, 128), jnp.complex64, one_chip)
+    fn = jax.jit(lambda x, y: ops.matmul(x, y, precision=precision))
+    text = fn.lower(a, b).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3  # three real GEMMs
+
+
+def _slice_program(plan, arrays, sharding, batch):
+    sess = ContractionSession(plan, arrays)
+    hshapes = (
+        jax.eval_shape(plan._prologue_outputs, list(arrays))
+        if sess.hoist else []
+    )
+    args = (
+        [_sds(np.shape(a), np.asarray(a).dtype, sharding) for a in arrays],
+        [_sds(h.shape, h.dtype, sharding) for h in hshapes],
+        _sds((batch,), jnp.int32, sharding),
+        _sds((batch,), jnp.bool_, sharding),
+    )
+    return sess._batch_fn().lower(*args).compile()
+
+
+@pytest.fixture(scope="module")
+def syc12():
+    circ = sycamore_like(4, 5, 12)
+    tn, arrays = circuit_to_network(circ, bitstring="0" * circ.num_qubits)
+    return simplify_network(tn, arrays)
+
+
+@pytest.mark.parametrize("backend", ["gemm", "einsum"])
+def test_slice_program_compiles_near_certified_peak(one_chip, syc12, backend):
+    tn, arrays = syc12
+    plan, _ = plan_compiled(
+        tn, 18, dtype=arrays[0].dtype, backend=backend,
+        slicing_mode="peak", use_cache=False,
+    )
+    compiled = _slice_program(plan, arrays, one_chip, batch=2)
+    text = compiled.as_text()
+    if backend == "gemm":
+        assert plan.schedule.backend_counts().get("pallas")
+        assert "tpu_custom_call" in text
+    else:
+        assert "tpu_custom_call" not in text
+    ma = compiled.memory_analysis()
+    certified = 2 * plan.memory_plan().peak_bytes
+    # one axis per index would pad every minor dim of 2 to 128 lanes
+    assert ma.temp_size_in_bytes <= 4 * certified
+
+
+def test_sharded_program_compiles_for_four_chips(topo, syc12):
+    from jax.sharding import Mesh
+
+    tn, arrays = syc12
+    plan, _ = plan_compiled(
+        tn, 18, dtype=arrays[0].dtype, backend="gemm",
+        slicing_mode="peak", use_cache=False,
+    )
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("data",))
+    rep = NamedSharding(mesh, PartitionSpec())
+    shard = NamedSharding(mesh, PartitionSpec("data"))
+    sess = ContractionSession(plan, arrays)
+    hshapes = (
+        jax.eval_shape(plan._prologue_outputs, list(arrays))
+        if sess.hoist else []
+    )
+    args = (
+        [_sds(np.shape(a), np.asarray(a).dtype, rep) for a in arrays],
+        [_sds(h.shape, h.dtype, rep) for h in hshapes],
+        _sds((8,), jnp.int32, shard),
+        _sds((8,), jnp.bool_, shard),
+    )
+    compiled = sess._sharded_fn(mesh, ("data",), 1).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text

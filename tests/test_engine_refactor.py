@@ -14,7 +14,7 @@ program rather than sharing the adapter's — the comparison is between
 two independently compiled executables, which is what makes equality
 meaningful.
 
-Legs: {REPRO_MEGAKERNEL 0/1} x {hoist off/on} x {fp32/bf16} on the
+Legs: {width/peak slicing} x {hoist off/on} x {fp32/bf16} on the
 lowered GEMM backend, plus an einsum leg and the unsliced dense path.
 The pinned circuit is the 12-qubit syc-12 family the benchmarks use,
 planned at a width that forces slicing with a slice count that is NOT a
@@ -25,7 +25,6 @@ a refactor of the padding/masking logic would diverge first.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -48,38 +47,29 @@ SLICE_BATCH = 3  # must not divide the slice count (ragged final batch)
 
 
 @functools.lru_cache(maxsize=None)
-def _leg(mega: str, backend: str, precision: str):
-    """Plan the pinned syc-12 circuit under one env leg (uncached — each
-    leg gets its own plan object so no jitted programs leak between
-    legs)."""
-    old = os.environ.get("REPRO_MEGAKERNEL")
-    os.environ["REPRO_MEGAKERNEL"] = mega
-    try:
-        circuit = sycamore_like(ROWS, COLS, CYCLES, seed=SEED)
-        tn, arrays = circuit_to_network(
-            circuit, bitstring="0" * circuit.num_qubits
-        )
-        tn, arrays = simplify_network(tn, arrays)
-        plan, _ = plan_compiled(
-            tn, TARGET_DIM, backend=backend, precision=precision,
-            use_cache=False,
-        )
-    finally:
-        if old is None:
-            os.environ.pop("REPRO_MEGAKERNEL", None)
-        else:
-            os.environ["REPRO_MEGAKERNEL"] = old
+def _leg(slicing: str, backend: str, precision: str):
+    """Plan the pinned syc-12 circuit under one leg (uncached — each leg
+    gets its own plan object so no jitted programs leak between legs)."""
+    circuit = sycamore_like(ROWS, COLS, CYCLES, seed=SEED)
+    tn, arrays = circuit_to_network(
+        circuit, bitstring="0" * circuit.num_qubits
+    )
+    tn, arrays = simplify_network(tn, arrays)
+    plan, _ = plan_compiled(
+        tn, TARGET_DIM, backend=backend, precision=precision,
+        slicing_mode=slicing, use_cache=False,
+    )
     assert plan.num_sliced > 0  # the leg must exercise real slicing
     assert (1 << plan.num_sliced) % SLICE_BATCH != 0
     return plan, tuple(arrays)
 
 
 LEGS = [
-    ("0", "gemm", "fp32"),
-    ("1", "gemm", "fp32"),
-    ("0", "gemm", "bf16"),
-    ("1", "gemm", "bf16"),
-    ("0", "einsum", "fp32"),
+    ("width", "gemm", "fp32"),
+    ("peak", "gemm", "fp32"),
+    ("width", "gemm", "bf16"),
+    ("peak", "gemm", "bf16"),
+    ("width", "einsum", "fp32"),
 ]
 
 
@@ -146,7 +136,6 @@ def legacy_contract_all(plan, arrays, slice_batch=8, hoist=None):
 def legacy_contract_sharded(
     plan, arrays, mesh, axis_names=("data",), slice_batch=1, hoist=None
 ):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.executor import default_hoist
@@ -197,12 +186,12 @@ def legacy_contract_sharded(
                 acc, _ = jax.lax.scan(body, acc0, (idb, vb))
                 return jax.lax.psum(acc, axis_names)
 
-            return shard_map(
+            return jax.shard_map(
                 worker,
                 mesh=mesh,
                 in_specs=(spec, spec),
                 out_specs=P(),
-                check_rep=False,
+                check_vma=False,
             )(ids_, valid_)
 
         fn = plan._compiled.setdefault(key, run)
@@ -273,10 +262,10 @@ def legacy_mh_batch(plan, arrays, sb, hoist):
 # ----------------------------------------------------------------------
 # adapter vs frozen legacy: bitwise
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("mega,backend,precision", LEGS)
+@pytest.mark.parametrize("slicing,backend,precision", LEGS)
 @pytest.mark.parametrize("hoist", [False, True])
-def test_contract_all_bitwise(mega, backend, precision, hoist):
-    plan, arrays = _leg(mega, backend, precision)
+def test_contract_all_bitwise(slicing, backend, precision, hoist):
+    plan, arrays = _leg(slicing, backend, precision)
     ref = legacy_contract_all(
         plan, list(arrays), slice_batch=SLICE_BATCH, hoist=hoist
     )
@@ -286,12 +275,12 @@ def test_contract_all_bitwise(mega, backend, precision, hoist):
     assert np.array_equal(np.asarray(new), np.asarray(ref))
 
 
-@pytest.mark.parametrize("mega,backend,precision", LEGS)
+@pytest.mark.parametrize("slicing,backend,precision", LEGS)
 @pytest.mark.parametrize("hoist", [False, True])
-def test_contract_sharded_bitwise(mega, backend, precision, hoist):
+def test_contract_sharded_bitwise(slicing, backend, precision, hoist):
     from jax.sharding import Mesh
 
-    plan, arrays = _leg(mega, backend, precision)
+    plan, arrays = _leg(slicing, backend, precision)
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     ref = legacy_contract_sharded(
         plan, list(arrays), mesh, slice_batch=SLICE_BATCH, hoist=hoist
@@ -302,10 +291,10 @@ def test_contract_sharded_bitwise(mega, backend, precision, hoist):
     assert np.array_equal(np.asarray(new), np.asarray(ref))
 
 
-@pytest.mark.parametrize("mega,backend,precision", LEGS[:2] + LEGS[3:])
+@pytest.mark.parametrize("slicing,backend,precision", LEGS[:2] + LEGS[3:])
 @pytest.mark.parametrize("hoist", [False, True])
-def test_contract_resumable_bitwise(mega, backend, precision, hoist):
-    plan, arrays = _leg(mega, backend, precision)
+def test_contract_resumable_bitwise(slicing, backend, precision, hoist):
+    plan, arrays = _leg(slicing, backend, precision)
     ref, ref_state = legacy_contract_resumable(
         plan, list(arrays), chunk=SLICE_BATCH, hoist=hoist
     )
@@ -323,7 +312,7 @@ def test_run_slices_matches_legacy_mh_batch(hoist):
     final wrapped/masked one).  contract_multihost's surrounding
     scheduler/transport/claims logic is unchanged by the refactor, so
     per-range identity is driver identity."""
-    plan, arrays = _leg("1", "gemm", "fp32")
+    plan, arrays = _leg("peak", "gemm", "fp32")
     sess = ContractionSession(plan, list(arrays), hoist=hoist)
     legacy = legacy_mh_batch(plan, list(arrays), SLICE_BATCH, sess.hoist)
     n = sess.n_slices
@@ -339,7 +328,7 @@ def test_run_slices_matches_legacy_mh_batch(hoist):
 def test_multihost_world1_matches_contract_all():
     from repro.distributed.multihost import contract_multihost
 
-    plan, arrays = _leg("1", "gemm", "fp32")
+    plan, arrays = _leg("peak", "gemm", "fp32")
     res = contract_multihost(plan, list(arrays), slice_batch=SLICE_BATCH)
     assert res.complete
     ref = plan.contract_all(list(arrays), slice_batch=SLICE_BATCH)
@@ -365,7 +354,7 @@ def test_dense_path_bitwise():
 def test_session_shares_program_across_drivers():
     """All sessions over one plan converge on ONE traced batch program
     (the _compiled memoization the serving engine relies on)."""
-    plan, arrays = _leg("1", "gemm", "fp32")
+    plan, arrays = _leg("peak", "gemm", "fp32")
     s1 = ContractionSession(plan, list(arrays), hoist=True)
     s2 = ContractionSession(plan, list(arrays), hoist=True)
     s1.run_slices(np.arange(SLICE_BATCH, dtype=np.int32))

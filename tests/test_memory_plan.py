@@ -1,11 +1,11 @@
-"""Lifetime-based memory planning + fused transpose-GEMM kernels.
+"""Lifetime-based memory planning + the lane-dense GEMM step.
 
 Covers: linear-scan live-set peaks vs a brute-force executor simulation
 on random trees (naive and prologue/epilogue segments), slot-assignment
-validity, fused-kernel equivalence with the einsum oracle and *bitwise*
-agreement with the permute + ``tiled_matmul`` reference at matched tile
-blocking (complex Karatsuba included), refiner selection + the
-``REPRO_FUSED_GEMM`` off-switch, the peak-aware slicer contract
+validity, lane-dense step equivalence with the einsum oracle and
+*bitwise* agreement with the permute + ``tiled_matmul`` reference at
+matched tile blocking (complex Karatsuba included), refiner selection
+of the Pallas kernel for MXU-sized steps, the peak-aware slicer contract
 (|S_peak| <= |S_width|, explicit byte budgets honored), the
 device-identity prologue cache key, hoisted-buffer donation, and the
 pinned syc-12 peak-bytes regression gate."""
@@ -34,9 +34,9 @@ from repro.lowering import gemm_form, lower_step, refine_schedule, refine_step
 from repro.lowering.cache import leaf_key
 from repro.lowering.memory import node_nbytes, peak_bytes, plan_memory
 from repro.lowering.partition import partition_tree
-from repro.lowering.refiner import GemmSpec, default_fused
+from repro.lowering.layout import dense_step
+from repro.lowering.refiner import GemmSpec
 from repro.kernels import ops
-from repro.kernels.contract_gemm import suffix_tile_split
 from repro.quantum.circuits import circuit_to_network, random_1d_circuit
 
 RNG = np.random.default_rng(0)
@@ -218,7 +218,7 @@ def _random_form(rng, nb, nm, nn, nk, sizes_from=(1, 6)):
     return form, sa, sb
 
 
-def _fused_vs_einsum(seed, nb, nm, nn, nk, complex_, sizes_from=(1, 6)):
+def _dense_vs_einsum(seed, nb, nm, nn, nk, complex_, sizes_from=(1, 6)):
     rng = np.random.default_rng(seed)
     form, sa, sb = _random_form(rng, nb, nm, nn, nk, sizes_from)
     dtype = np.complex64 if complex_ else np.float32
@@ -228,7 +228,7 @@ def _fused_vs_einsum(seed, nb, nm, nn, nk, complex_, sizes_from=(1, 6)):
         a = a + 1j * rng.normal(size=sa)
         b = b + 1j * rng.normal(size=sb)
     a, b = a.astype(dtype), b.astype(dtype)
-    spec = GemmSpec(form, "pallas_fused", 4, 4, 4, 0.0, 0.0)
+    spec = GemmSpec(form, "pallas", 4, 4, 4, 0.0, 0.0)
     got = np.asarray(gemm_form.apply(spec, jnp.asarray(a), jnp.asarray(b)))
     want = np.einsum(form.expr, a, b)
     scale = max(1.0, np.abs(want).max())
@@ -241,9 +241,9 @@ def _fused_vs_einsum(seed, nb, nm, nn, nk, complex_, sizes_from=(1, 6)):
     [(0, 1, 1, 1), (1, 2, 2, 2), (2, 1, 2, 0), (0, 2, 1, 2), (1, 0, 2, 1),
      (0, 0, 0, 2)],
 )
-def test_fused_matches_einsum_fixed(nb, nm, nn, nk, complex_):
+def test_dense_step_matches_einsum_fixed(nb, nm, nn, nk, complex_):
     for seed in (0, 1):
-        _fused_vs_einsum(seed, nb, nm, nn, nk, complex_)
+        _dense_vs_einsum(seed, nb, nm, nn, nk, complex_)
 
 
 @given(
@@ -255,11 +255,11 @@ def test_fused_matches_einsum_fixed(nb, nm, nn, nk, complex_):
     complex_=st.booleans(),
 )
 @settings(max_examples=30)
-def test_fused_property(seed, nb, nm, nn, nk, complex_):
+def test_dense_step_property(seed, nb, nm, nn, nk, complex_):
     """Random pairwise contractions (random role counts, sizes 1..5,
-    shuffled axis orders, complex Karatsuba + real) — fused
-    transpose-GEMM == einsum."""
-    _fused_vs_einsum(seed, nb, nm, nn, nk, complex_)
+    shuffled axis orders, complex Karatsuba + real) — the lane-dense
+    Pallas step == einsum."""
+    _dense_vs_einsum(seed, nb, nm, nn, nk, complex_)
 
 
 @pytest.mark.parametrize("complex_", [False, True])
@@ -267,13 +267,12 @@ def test_fused_property(seed, nb, nm, nn, nk, complex_):
     "nb,nm,nn,nk,tile",
     [(0, 3, 3, 3, 4), (1, 2, 2, 2, 4), (0, 4, 3, 4, 8), (2, 2, 2, 3, 2)],
 )
-def test_fused_bitwise_vs_tiled_matmul(nb, nm, nn, nk, tile, complex_):
-    """Bit-agreement with the permute + ``tiled_matmul`` reference at
-    matched tile blocking: power-of-two role dims so the fused
-    axis-suffix tiles divide exactly, reference run with identical
-    (bm, bn, bk) — same tile values, same K accumulation order, so the
-    results must be *bitwise* identical (complex via the same Karatsuba
-    on both sides)."""
+def test_dense_step_bitwise_vs_tiled_matmul(nb, nm, nn, nk, tile, complex_):
+    """Bit-agreement with a plain numpy permute + ``tiled_matmul`` at
+    matched tile blocking: the lane-dense route only moves data, so the
+    kernel sees the same tile values in the same K order and the results
+    must be *bitwise* identical (complex via the same Karatsuba on both
+    sides)."""
     rng = np.random.default_rng(7 * nb + nm + nn + nk + tile)
     form, sa, sb = _random_form(rng, nb, nm, nn, nk, sizes_from=(2, 3))
     dtype = np.complex64 if complex_ else np.float32
@@ -283,45 +282,42 @@ def test_fused_bitwise_vs_tiled_matmul(nb, nm, nn, nk, tile, complex_):
         a = a + 1j * rng.normal(size=sa)
         b = b + 1j * rng.normal(size=sb)
     a, b = a.astype(dtype), b.astype(dtype)
-    # effective axis-suffix tiles at this target
-    _, _, tm = suffix_tile_split(form.m_shape, tile)
-    _, _, tn_ = suffix_tile_split(form.n_shape, tile)
-    _, _, tk = suffix_tile_split(form.k_shape, tile)
-    fused = np.asarray(
-        ops.fused_matmul(
-            jnp.asarray(a), jnp.asarray(b),
-            perm_a=form.perm_a, perm_b=form.perm_b,
-            nb=len(form.batch_inds), nm=len(form.m_inds),
-            nn=len(form.n_inds), nk=len(form.k_inds),
-            bm=tile, bn=tile, bk=tile, interpret=True,
+    spec = GemmSpec(form, "pallas", tile, tile, tile, 0.0, 0.0)
+    size = dict(zip(form.inds_a, sa))
+    size.update(zip(form.inds_b, sb))
+    ds = dense_step(
+        form.inds_a, form.inds_b, form.inds_out, size.__getitem__,
+        canonical=True,
+    )
+    got = np.asarray(
+        gemm_form.contract_flat(
+            spec, ds, jnp.asarray(a).reshape(-1), jnp.asarray(b).reshape(-1)
         )
-    ).reshape(form.B, form.M, form.N)
-    a2 = jnp.transpose(jnp.asarray(a), form.perm_a).reshape(
-        form.B, form.M, form.K
     )
-    b2 = jnp.transpose(jnp.asarray(b), form.perm_b).reshape(
-        form.B, form.K, form.N
-    )
+    a2 = a.transpose([form.inds_a.index(i) for i in ds.a_gemm])
+    b2 = b.transpose([form.inds_b.index(i) for i in ds.b_gemm])
+    a2 = jnp.asarray(a2.reshape(ds.a_shape))
+    b2 = jnp.asarray(b2.reshape(ds.b_shape))
     ref = np.stack([
         np.asarray(
             ops.matmul(
-                a2[i], b2[i], bm=tm, bn=tn_, bk=tk,
+                a2[i], b2[i], bm=tile, bn=tile, bk=tile,
                 min_kernel_dim=1, interpret=True,
             )
         )
         for i in range(form.B)
-    ])
-    assert fused.dtype == ref.dtype
-    assert np.array_equal(fused, ref), (form.expr, tm, tn_, tk)
+    ]).reshape(-1)
+    assert got.dtype == ref.dtype
+    assert np.array_equal(got, ref), (form.expr, tile)
 
 
-def test_fused_apply_under_vmap():
-    """The fused step must run inside the executor's slice-batch vmap."""
+def test_dense_step_under_vmap():
+    """The Pallas step must run inside the executor's slice-batch vmap."""
     rng = np.random.default_rng(3)
     form, sa, sb = _random_form(rng, 1, 2, 2, 2, sizes_from=(2, 3))
     a = rng.normal(size=sa).astype(np.float32)
     b = rng.normal(size=sb).astype(np.float32)
-    spec = GemmSpec(form, "pallas_fused", 4, 4, 4, 0.0, 0.0)
+    spec = GemmSpec(form, "pallas", 4, 4, 4, 0.0, 0.0)
     va = jnp.stack([jnp.asarray(a), 2.0 * jnp.asarray(a)])
     vb = jnp.stack([jnp.asarray(b), jnp.asarray(b)])
     got = jax.vmap(lambda x, y: gemm_form.apply(spec, x, y))(va, vb)
@@ -332,8 +328,8 @@ def test_fused_apply_under_vmap():
     )
 
 
-def test_fused_spec_adapts_to_64bit_arrays():
-    """A fused spec handed complex128 arrays at trace time must route to
+def test_pallas_spec_adapts_to_64bit_arrays():
+    """A Pallas spec handed complex128 arrays at trace time must route to
     the full-precision dot, not truncate through the fp32 kernel."""
     jax.config.update("jax_enable_x64", True)
     try:
@@ -345,7 +341,7 @@ def test_fused_spec_adapts_to_64bit_arrays():
         b = (rng.normal(size=sb) + 1j * rng.normal(size=sb)).astype(
             np.complex128
         )
-        spec = GemmSpec(form, "pallas_fused", 4, 4, 4, 0.0, 0.0)
+        spec = GemmSpec(form, "pallas", 4, 4, 4, 0.0, 0.0)
         got = np.asarray(
             gemm_form.apply(spec, jnp.asarray(a), jnp.asarray(b))
         )
@@ -359,7 +355,7 @@ def test_fused_spec_adapts_to_64bit_arrays():
 
 
 def _big_pow2_form(rng):
-    """An MXU-sized all-power-of-two form the refiner can fuse."""
+    """An MXU-sized all-power-of-two form."""
     ms = [f"m{i}" for i in range(8)]
     ns = [f"n{i}" for i in range(8)]
     ks = [f"k{i}" for i in range(8)]
@@ -374,48 +370,34 @@ def _big_pow2_form(rng):
     return lower_step(inds_a, inds_b, inds_out, sizes.__getitem__)
 
 
-def test_refiner_picks_fused_and_credits_transpose():
+def test_refiner_picks_pallas_for_mxu_sized_steps():
     form = _big_pow2_form(np.random.default_rng(0))
-    spec = refine_step(form, np.complex64, fused=True)
-    ref = refine_step(form, np.complex64, fused=False)
-    assert spec.backend == "pallas_fused"
-    assert ref.backend == "pallas"
-    # the fused cost model credits the eliminated 2*(|A|+|B|)*bytes of
-    # transpose bandwidth (plus zero padding), so it must model faster
-    assert spec.modeled_time_s < ref.modeled_time_s
-    assert spec.pad_waste == 0.0
-    assert spec.transpose_bytes == 0.0
-    assert ref.transpose_bytes > 0.0
-    # effective tiles divide exactly
+    spec = refine_step(form, np.complex64)
+    assert spec.backend == "pallas"
+    assert spec.transpose_bytes > 0.0
     assert form.M % spec.bm == 0
     assert form.N % spec.bn == 0
     assert form.K % spec.bk == 0
-    # schedule-level accounting
     sched = refine_schedule(
         [(form.inds_a, form.inds_b, form.inds_out)],
         {**{ix: 2 for ix in form.inds_a}, **{ix: 2 for ix in form.inds_b}}
         .__getitem__,
         dtype=np.complex64,
-        fused=True,
     )
-    assert sched.backend_counts() == {"pallas_fused": 1}
-    assert sched.transpose_bytes_eliminated() == pytest.approx(
-        2.0 * 8 * (form.B * form.M * form.K + form.B * form.K * form.N)
-    )
-    assert "pallas_fused=1" in sched.summary_row()
+    assert sched.backend_counts() == {"pallas": 1}
+    assert "pallas=1" in sched.summary_row()
 
 
-def test_fused_env_gate(monkeypatch):
-    form = _big_pow2_form(np.random.default_rng(1))
-    monkeypatch.setenv("REPRO_FUSED_GEMM", "0")
-    assert default_fused() is False
-    assert refine_step(form, np.complex64).backend == "pallas"
-    monkeypatch.setenv("REPRO_FUSED_GEMM", "1")
-    assert default_fused() is True
-    assert refine_step(form, np.complex64).backend == "pallas_fused"
-    monkeypatch.setenv("REPRO_FUSED_GEMM", "maybe")
-    with pytest.raises(ValueError):
-        default_fused()
+def test_refiner_keeps_sub_tile_steps_off_pallas():
+    """A step with a sub-MXU dimension stays on XLA's dot."""
+    ms = [f"m{i}" for i in range(8)]
+    ks = [f"k{i}" for i in range(8)]
+    sizes = {ix: 2 for ix in ms + ks + ["n0"]}
+    form = lower_step(
+        tuple(ms + ks), tuple(ks + ["n0"]), tuple(ms + ["n0"]),
+        sizes.__getitem__,
+    )
+    assert refine_step(form, np.complex64).backend == "dot"
 
 
 # ----------------------------------------------------------------------
@@ -591,19 +573,3 @@ def test_syc12_peak_regression():
     assert mem.peak_bytes <= pinned["peak_bytes"]
     assert mem.peak_bytes_hoisted <= pinned["peak_bytes_hoisted"]
 
-    # fusion-boundary pass: the chain planner must keep finding at least
-    # the pinned number of multi-step VMEM chains on this plan, every
-    # chain's certified live set must respect both the pinned fused peak
-    # and the hard VMEM budget, and the modeled epilogue HBM savings
-    # (round-trips + transpose traffic, counted disjointly) must not
-    # regress below the pinned floor.
-    from repro.lowering import CHAIN_VMEM_BUDGET_BYTES, plan_tree_chains
-
-    cp = plan_tree_chains(tree, S)
-    assert cp.num_multi >= pinned["fused_chains"]
-    assert cp.max_live_bytes() <= pinned["chain_peak_bytes"]
-    assert cp.max_live_bytes() <= CHAIN_VMEM_BUDGET_BYTES
-    assert (
-        cp.hbm_bytes_saved("epilogue")
-        >= pinned["chain_hbm_bytes_saved_epilogue"]
-    )

@@ -450,12 +450,8 @@ def test_calibrate_plan_joins_model_and_measured(backend):
         assert np.isfinite(agg["ratio"]) and agg["ratio"] > 0.0
     if backend == "einsum":
         assert set(by_class) == {"einsum"}
-    # steps covered exactly once (chains count n_steps each)
-    chains = plan._chain_dispatch.get("naive", {})
-    expect_rows = len(plan.steps) - sum(
-        ch.n_steps - 1 for ch in chains.values()
-    )
-    assert len(cal.rows) == expect_rows
+    # every step covered exactly once
+    assert len(cal.rows) == len(plan.steps)
     table = cal.table()
     assert "meas/model" in table and table.count("\n") >= 2
     json.dumps(cal.summary())

@@ -13,19 +13,10 @@ Gates the PR-9 stack:
      never larger, measured amplitude error within tolerance;
   4. plan-cache fingerprints: the resolved precision mode always joins
      the key, the tolerance only off fp32;
-  5. bf16 kernel parity: the chain megakernel is bitwise against its
-     off-TPU reference at matched precisions, and the per-op bf16 paths
-     stay within the bf16 forward-error envelope of fp32.
-
-The heavyweight fixtures pin ``REPRO_MEGAKERNEL=1`` / ``REPRO_FUSED_
-GEMM=1`` while *planning*: the syc-12 contraction is ~50x slower
-unfused on CPU, and the gate's modeled numbers are only meaningful on
-the schedule the refiner actually targets.  Execution-mode coverage
-(hoist on/off, shard_map) still varies per test.
+  5. bf16 kernel parity: the lane-dense Pallas step is bitwise against
+     a plain permute + kernel reference at bf16, and the per-op bf16
+     paths stay within the bf16 forward-error envelope of fp32.
 """
-
-import contextlib
-import os
 
 import numpy as np
 import pytest
@@ -56,25 +47,6 @@ SYC_TD = 18  # pinned syc-12 planner config (matches bench_end_to_end)
 GATE_TOL = 0.05  # the "realistic" XEB budget the gate certifies at
 
 
-@contextlib.contextmanager
-def _pinned_lowering_env():
-    """Fix the lowering switches the heavy fixtures assume (see module
-    docstring) without disturbing the CI matrix env for other tests."""
-    saved = {
-        k: os.environ.get(k) for k in ("REPRO_MEGAKERNEL", "REPRO_FUSED_GEMM")
-    }
-    os.environ["REPRO_MEGAKERNEL"] = "1"
-    os.environ["REPRO_FUSED_GEMM"] = "1"
-    try:
-        yield
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
 @pytest.fixture(scope="module")
 def syc():
     circ = sycamore_like(4, 5, 12, seed=0)
@@ -93,12 +65,11 @@ def syc_oracle(syc):
 def syc_fp32(syc):
     """(plan, report, amplitude) of the pinned fp32 baseline."""
     _, tn, arrays = syc
-    with _pinned_lowering_env():
-        plan, report = plan_compiled(
-            tn, SYC_TD, backend="gemm", use_cache=False,
-            slicing_mode="peak", precision="fp32",
-        )
-        amp = complex(np.asarray(plan.contract_all(arrays, slice_batch=8)))
+    plan, report = plan_compiled(
+        tn, SYC_TD, backend="gemm", use_cache=False,
+        slicing_mode="peak", precision="fp32",
+    )
+    amp = complex(np.asarray(plan.contract_all(arrays, slice_batch=8)))
     return plan, report, amp
 
 
@@ -106,12 +77,11 @@ def syc_fp32(syc):
 def syc_auto(syc):
     """(plan, report, amplitude) of the auto plan at the gate budget."""
     _, tn, arrays = syc
-    with _pinned_lowering_env():
-        plan, report = plan_compiled(
-            tn, SYC_TD, backend="gemm", use_cache=False,
-            slicing_mode="peak", precision="auto", fidelity_tol=GATE_TOL,
-        )
-        amp = complex(np.asarray(plan.contract_all(arrays, slice_batch=8)))
+    plan, report = plan_compiled(
+        tn, SYC_TD, backend="gemm", use_cache=False,
+        slicing_mode="peak", precision="auto", fidelity_tol=GATE_TOL,
+    )
+    amp = complex(np.asarray(plan.contract_all(arrays, slice_batch=8)))
     return plan, report, amp
 
 
@@ -130,8 +100,7 @@ def test_default_precision_env(monkeypatch):
 
 def test_error_model_monotone_in_k_and_depth(syc):
     _, tn, _ = syc
-    with _pinned_lowering_env():
-        sched = refine_tree_schedule(_tree_of(syc), 0)
+    sched = refine_tree_schedule(_tree_of(syc), 0)
     forms = [s.form for s in sched.specs]
     by_k = sorted(forms, key=lambda f: f.K)
     errs = [node_amp_error(f) for f in by_k]
@@ -152,30 +121,28 @@ def _tree_of(syc_fixture):
 def test_assignment_monotone_and_certified(syc):
     """bf16 sets are nested as the tolerance grows (strict-prefix
     admission) and every assignment self-certifies within its budget."""
-    with _pinned_lowering_env():
-        tree = _tree_of(syc)
-        sched = refine_tree_schedule(tree, 0)
-        prev: set[int] = set()
-        for tol in (0.0, 1e-3, 5e-3, 0.02, 0.05, 0.5):
-            out = assign_precision(sched, mode="auto", fidelity_tol=tol)
-            cur = {
-                i for i, s in enumerate(out.specs) if s.precision == "bf16"
-            }
-            assert prev <= cur, f"tol={tol} dropped a prior demotion"
-            assert predicted_fidelity_loss(out.predicted_amp_error) <= tol
-            prev = cur
-        assert assign_precision(sched, mode="auto", fidelity_tol=0.0).specs \
-            == sched.specs
-        forced = assign_precision(sched, mode="bf16", fidelity_tol=1e9)
-        assert set(
-            i for i, s in enumerate(forced.specs) if s.precision == "bf16"
-        ) >= prev
+    tree = _tree_of(syc)
+    sched = refine_tree_schedule(tree, 0)
+    prev: set[int] = set()
+    for tol in (0.0, 1e-3, 5e-3, 0.02, 0.05, 0.5):
+        out = assign_precision(sched, mode="auto", fidelity_tol=tol)
+        cur = {
+            i for i, s in enumerate(out.specs) if s.precision == "bf16"
+        }
+        assert prev <= cur, f"tol={tol} dropped a prior demotion"
+        assert predicted_fidelity_loss(out.predicted_amp_error) <= tol
+        prev = cur
+    assert assign_precision(sched, mode="auto", fidelity_tol=0.0).specs \
+        == sched.specs
+    forced = assign_precision(sched, mode="bf16", fidelity_tol=1e9)
+    assert set(
+        i for i, s in enumerate(forced.specs) if s.precision == "bf16"
+    ) >= prev
 
 
 def test_storage_itemsizes_halve_only_bf16_consumers(syc):
-    with _pinned_lowering_env():
-        tree = _tree_of(syc)
-        iso = tree_storage_itemsizes(tree, 0, mode="bf16", fidelity_tol=1e9)
+    tree = _tree_of(syc)
+    iso = tree_storage_itemsizes(tree, 0, mode="bf16", fidelity_tol=1e9)
     assert iso  # the pinned syc-12 schedule has MXU steps to demote
     assert set(iso.values()) <= {4, 8}  # halved or full, nothing else
     assert 4 in iso.values()  # some node is actually stored bf16
@@ -188,12 +155,11 @@ def test_storage_itemsizes_halve_only_bf16_consumers(syc):
 def test_tol_zero_bitwise_fp32(syc, syc_fp32):
     _, tn, arrays = syc
     plan32, _, amp32 = syc_fp32
-    with _pinned_lowering_env():
-        p0, r0 = plan_compiled(
-            tn, SYC_TD, backend="gemm", use_cache=False,
-            slicing_mode="peak", precision="auto", fidelity_tol=0.0,
-        )
-        amp0 = complex(np.asarray(p0.contract_all(arrays, slice_batch=8)))
+    p0, r0 = plan_compiled(
+        tn, SYC_TD, backend="gemm", use_cache=False,
+        slicing_mode="peak", precision="auto", fidelity_tol=0.0,
+    )
+    amp0 = complex(np.asarray(p0.contract_all(arrays, slice_batch=8)))
     assert p0.smask == plan32.smask
     assert p0.schedule.specs == plan32.schedule.specs
     assert (r0.precision_counts or {}).get("bf16", 0) == 0
@@ -271,12 +237,11 @@ def test_sampling_xeb_within_tolerance_shard_map(syc):
         seed=1, backend="gemm", use_cache=False, slice_batch=4,
         slicing_mode="peak",
     )
-    with _pinned_lowering_env():
-        base = sample_bitstrings(circ, precision="fp32", **kw)
-        mixed = sample_bitstrings(
-            circ, mesh=mesh, axis_names=("data",),
-            precision="auto", fidelity_tol=GATE_TOL, **kw,
-        )
+    base = sample_bitstrings(circ, precision="fp32", **kw)
+    mixed = sample_bitstrings(
+        circ, mesh=mesh, axis_names=("data",),
+        precision="auto", fidelity_tol=GATE_TOL, **kw,
+    )
     a32 = np.asarray(base.batch.amplitudes)
     a16 = np.asarray(mixed.batch.amplitudes)
     scale = np.abs(a32).max()
@@ -339,11 +304,10 @@ def test_peak_mode_slices_never_larger(syc, td):
     from repro.optimize import oneshot_plan
 
     _, tn, _ = syc
-    with _pinned_lowering_env():
-        s32 = oneshot_plan(tn, td, seed=0, slicing_mode="peak",
-                           precision="fp32")
-        s16 = oneshot_plan(tn, td, seed=0, slicing_mode="peak",
-                           precision="auto", fidelity_tol=GATE_TOL)
+    s32 = oneshot_plan(tn, td, seed=0, slicing_mode="peak",
+                       precision="fp32")
+    s16 = oneshot_plan(tn, td, seed=0, slicing_mode="peak",
+                       precision="auto", fidelity_tol=GATE_TOL)
     assert popcount(s16.smask) <= popcount(s32.smask)
     # prune-only second pass: the bf16 mask is a subset of the fp32 one
     assert s16.smask & ~s32.smask == 0
@@ -400,42 +364,46 @@ def node_amp_error_bound(k: int) -> float:
 
 
 @pytest.mark.parametrize("case", [0, 2, 3])
-def test_chain_kernel_bitwise_vs_reference_bf16(case):
-    """The chain megakernel and its off-TPU reference agree *bitwise* at
-    matched per-step precisions — the same contract the fp32 suite pins,
-    extended to mixed schedules."""
-    from test_megakernel import (
-        CHAIN_CASES,
-        _chain_operands,
-        _chain_slots,
-        _einsum_chain,
-        _random_chain,
-    )
-
+def test_pallas_step_bitwise_vs_reference_bf16(case):
+    """The lane-dense Pallas step at bf16 is *bitwise* the kernel run on
+    a plain numpy permutation of the same operands — layout moves data,
+    never values — and stays inside the bf16 envelope of einsum."""
     from repro.kernels import ops
+    from repro.lowering import dense_step, gemm_form, lower_step
+    from repro.lowering.refiner import GemmSpec
 
-    seed, n_steps, cplx, batch = CHAIN_CASES[case]
-    rng = np.random.default_rng(seed)
-    forms, carry_side, externals, sizes = _random_chain(
-        rng, n_steps, with_batch=batch
-    )
-    slot_ids, slot_elems = _chain_slots(forms, carry_side)
-    arrs = _chain_operands(rng, externals, sizes, complex_mode=cplx)
-    want = np.asarray(_einsum_chain(forms, carry_side, arrs))
-
-    for precisions in (
-        ("bf16",) * n_steps,
-        tuple("bf16" if t % 2 else "fp32" for t in range(n_steps)),
-    ):
-        kw = dict(
-            forms=forms, carry_side=carry_side,
-            slot_ids=slot_ids, slot_elems=slot_elems,
-            precisions=precisions,
-        )
-        got_kernel = np.asarray(ops.fused_chain(
-            arrs, use_kernel=True, interpret=True, **kw
-        ))
-        got_ref = np.asarray(ops.fused_chain(arrs, use_kernel=False, **kw))
-        assert np.array_equal(got_kernel, got_ref), precisions
-        scale = np.abs(want).max()
-        assert np.abs(got_kernel - want).max() <= 0.05 * scale
+    rng = np.random.default_rng(case)
+    labels = [f"i{j}" for j in range(9)]
+    sizes = {ix: 2 for ix in labels}
+    perm = list(rng.permutation(labels))
+    inds_a = tuple(perm[:6])
+    inds_b = tuple(perm[3:])
+    inds_out = tuple(perm[:3]) + tuple(perm[6:])
+    cplx = case != 0
+    form = lower_step(inds_a, inds_b, inds_out, sizes.__getitem__)
+    a = rng.standard_normal((2,) * 6)
+    b = rng.standard_normal((2,) * 6)
+    if cplx:
+        a = a + 1j * rng.standard_normal((2,) * 6)
+        b = b + 1j * rng.standard_normal((2,) * 6)
+    dt = np.complex64 if cplx else np.float32
+    a, b = a.astype(dt), b.astype(dt)
+    spec = GemmSpec(form, "pallas", 4, 4, 4, 0.0, 0.0, precision="bf16")
+    ds = dense_step(inds_a, inds_b, inds_out, sizes.__getitem__,
+                    canonical=True)
+    got = np.asarray(gemm_form.contract_flat(
+        spec, ds, jnp.asarray(a).reshape(-1), jnp.asarray(b).reshape(-1)
+    ))
+    a2 = a.transpose([inds_a.index(i) for i in ds.a_gemm])[None]
+    b2 = b.transpose([inds_b.index(i) for i in ds.b_gemm])[None]
+    ref = np.asarray(ops.matmul(
+        jnp.asarray(a2.reshape(ds.a_shape)[0]),
+        jnp.asarray(b2.reshape(ds.b_shape)[0]),
+        bm=4, bn=4, bk=4, min_kernel_dim=1, interpret=True,
+        precision="bf16",
+    )).reshape(-1)
+    assert np.array_equal(got, ref)
+    want = np.einsum(form.expr, a, b).transpose(
+        [inds_out.index(i) for i in ds.out_order]
+    ).reshape(-1)
+    assert np.abs(got - want).max() <= 0.05 * np.abs(want).max()
