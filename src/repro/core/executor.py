@@ -48,6 +48,16 @@ normalized to transpose→reshape→GEMM form and refined onto the Pallas
 schedule is static per plan, so it runs identically under the per-slice
 path, the vmapped slice batch, and ``shard_map``.
 
+Every program names its parts with ``jax.named_scope``, so each device
+op's ``op_name`` metadata says what it does: ``step<k>.<backend>`` around
+step ``k`` of :attr:`ContractionPlan.steps` (``backend`` the refiner's
+``pallas``, ``dot`` or ``einsum``), with ``permute`` and ``gemm`` inside
+it (:func:`repro.lowering.gemm_form.contract_flat`); ``leaves`` (the
+slicing of the leaf arrays), ``output`` (the root into ``out_inds``
+order), ``prologue`` (the hoisted program) and ``batch_sum`` (the engine's
+masked sum over a slice batch).  Scopes change only metadata: the
+optimized program is the same without them.
+
 Distribution across devices lives in :mod:`repro.core.distributed`.
 """
 
@@ -478,10 +488,12 @@ class ContractionPlan:
         frees = seg.frees if seg is not None else None
         for k in step_ids:
             st = self.steps[k]
-            env[st.out] = gemm_form.contract_flat(
-                self.schedule.specs[k] if self.schedule else None,
-                self.dense_steps[k], env[st.lhs], env[st.rhs],
-            )
+            spec = self.schedule.specs[k] if self.schedule else None
+            backend = spec.backend if spec is not None else "einsum"
+            with jax.named_scope(f"step{k}.{backend}"):
+                env[st.out] = gemm_form.contract_flat(
+                    spec, self.dense_steps[k], env[st.lhs], env[st.rhs],
+                )
             dead = (
                 frees[st.out]
                 if frees is not None
@@ -498,7 +510,6 @@ class ContractionPlan:
         ``hoisted`` (from :meth:`contract_prologue`) seeds the environment
         with the materialized slice-invariant buffers, so only the
         epilogue steps run; ``None`` executes the full tree (naive)."""
-        svals = self.slice_values(slice_id)
         env: dict[int, jnp.ndarray] = {}
         if hoisted is None:
             leaf_ids: Sequence[int] = range(len(arrays))
@@ -509,13 +520,15 @@ class ContractionPlan:
             leaf_ids = self.epilogue_leaves
             step_ids = self.epilogue_idx
             segment = "epilogue"
-        for i in leaf_ids:
-            a = jnp.asarray(arrays[i])
-            for axis, spos in self.leaf_specs[i]:
-                a = jax.lax.dynamic_index_in_dim(
-                    a, svals[spos], axis=axis, keepdims=False
-                )
-            env[i] = a.reshape(-1)
+        with jax.named_scope("leaves"):
+            svals = self.slice_values(slice_id)
+            for i in leaf_ids:
+                a = jnp.asarray(arrays[i])
+                for axis, spos in self.leaf_specs[i]:
+                    a = jax.lax.dynamic_index_in_dim(
+                        a, svals[spos], axis=axis, keepdims=False
+                    )
+                env[i] = a.reshape(-1)
         self._run_steps(env, step_ids, segment)
         return self._output(env[self.root])
 
@@ -524,11 +537,12 @@ class ContractionPlan:
         order and shape (one axis per open index)."""
         from ..lowering.layout import permute_flat  # lazy: avoid cycle
 
-        out = permute_flat(
-            flat, self.store_order[self.root], self.out_inds,
-            self.tn.size_of,
-        )
-        return out.reshape(self.out_shape())
+        with jax.named_scope("output"):
+            out = permute_flat(
+                flat, self.store_order[self.root], self.out_inds,
+                self.tn.size_of,
+            )
+            return out.reshape(self.out_shape())
 
     # ------------------------------------------------------------------
     def _prologue_outputs(self, arrays) -> list[jnp.ndarray]:
@@ -536,12 +550,13 @@ class ContractionPlan:
         arrays and return the hoisted frontier buffers in
         ``hoisted_nodes`` order.  Invariant leaves carry no sliced index
         by construction, so no slice specs apply here."""
-        env: dict[int, jnp.ndarray] = {
-            i: jnp.asarray(arrays[i]).reshape(-1)
-            for i in self.prologue_leaves
-        }
-        self._run_steps(env, self.prologue_idx, "prologue")
-        return [env[v] for v in self.hoisted_nodes]
+        with jax.named_scope("prologue"):
+            env: dict[int, jnp.ndarray] = {
+                i: jnp.asarray(arrays[i]).reshape(-1)
+                for i in self.prologue_leaves
+            }
+            self._run_steps(env, self.prologue_idx, "prologue")
+            return [env[v] for v in self.hoisted_nodes]
 
     def contract_prologue(self, arrays, use_cache: bool = True):
         """Materialize the slice-invariant prologue once.

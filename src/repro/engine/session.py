@@ -210,14 +210,24 @@ class ContractionSession:
         program serves every batch size (jit re-specializes per shape
         and caches internally); the masking select and the vmapped
         ``contract_slice`` dispatch — free schedules, layouts, precision —
-        are the single shared implementation."""
+        are the single shared implementation.
+
+        Traced as ``engine.run_slices``, with two children:
+        ``engine.ids_put`` (the ids and mask onto the device) and
+        ``engine.launch`` (the jitted call up to its return, not to the
+        device's finish)."""
         ids = np.asarray(slice_ids, dtype=np.int32)
         if valid is None:
             valid = np.ones(ids.shape, dtype=bool)
-        return self._batch_fn()(
-            list(self.arrays), list(self.hoisted()),
-            jnp.asarray(ids), jnp.asarray(valid),
-        )
+        fn, hbufs = self._batch_fn(), list(self.hoisted())
+        with _trace.span(
+            "engine.run_slices", cat="engine", ids=int(ids.size),
+            first_id=int(ids[0]) if ids.size else None,
+        ):
+            with _trace.span("engine.ids_put", cat="engine"):
+                ids_d, valid_d = jnp.asarray(ids), jnp.asarray(valid)
+            with _trace.span("engine.launch", cat="engine"):
+                return fn(list(self.arrays), hbufs, ids_d, valid_d)
 
     def _batch_fn(self):
         plan, hoist = self.plan, self.hoist
@@ -231,7 +241,8 @@ class ContractionSession:
                     arrs, sid, hbufs if hoist else None
                 )
                 contrib = jax.vmap(contract)(ids_)
-                return jnp.sum(mask_invalid(contrib, valid_), axis=0)
+                with jax.named_scope("batch_sum"):
+                    return jnp.sum(mask_invalid(contrib, valid_), axis=0)
 
             fn = plan._compiled.setdefault(ck, fn)
         return fn
@@ -356,8 +367,10 @@ class ContractionSession:
 
                 def body(acc, iv):
                     sids, ok = iv
-                    contrib = mask_invalid(batched(sids), ok)
-                    return acc + jnp.sum(contrib, axis=0), None
+                    contrib = batched(sids)
+                    with jax.named_scope("batch_sum"):
+                        contrib = mask_invalid(contrib, ok)
+                        return acc + jnp.sum(contrib, axis=0), None
 
                 acc0 = jnp.zeros(out_shape.shape, out_shape.dtype)
                 acc, _ = jax.lax.scan(body, acc0, (idb, vb))
@@ -413,10 +426,10 @@ class ContractionSession:
             "exec.sharded", cat="exec", slices=n_slices, devices=ndev,
             hoist=hoist, cached=cached,
         ):
-            out = fn(
-                list(self.arrays), list(hoisted),
-                jnp.asarray(ids), jnp.asarray(valid),
-            )
+            with _trace.span("engine.ids_put", cat="engine"):
+                ids_d, valid_d = jnp.asarray(ids), jnp.asarray(valid)
+            with _trace.span("engine.launch", cat="engine"):
+                out = fn(list(self.arrays), list(hoisted), ids_d, valid_d)
             _trace.sync(out)
         record_execution(plan, n_slices, total - n_slices, hoist)
         return out

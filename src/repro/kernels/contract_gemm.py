@@ -75,4 +75,5 @@ def tiled_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="tiled_matmul",  # a stable kernel name for profiles
     )(a, b)

@@ -17,7 +17,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..obs import trace as _trace
 from . import ref
 from .contract_gemm import tiled_matmul
 from .flash_attention import flash_attention
@@ -73,10 +72,7 @@ def matmul(
     if precision == "bf16":
         ap = ap.astype(jnp.bfloat16)
         bp = bp.astype(jnp.bfloat16)
-    # host-side XLA-profile annotation only (repro.obs.trace.annotate is
-    # a no-op unless REPRO_TRACE=1, and never touches the traced graph)
-    with _trace.annotate("ops.matmul"):
-        out = tiled_matmul(ap, bp, bm=bm, bn=bn, bk=bk, interpret=interpret)
+    out = tiled_matmul(ap, bp, bm=bm, bn=bn, bk=bk, interpret=interpret)
     return out[:m, :n]
 
 

@@ -171,38 +171,44 @@ def contract_flat(spec, ds, a: jax.Array, b: jax.Array, *,
     ``dot_general``.  fp32 steps ask for ``Precision.HIGHEST`` — on a
     TPU the default f32 matmul rounds its inputs to bf16.
 
+    The operand permutes run under ``jax.named_scope("permute")`` and
+    the GEMM (with the Karatsuba sums and differences) under ``"gemm"``,
+    so a profile's device ops name their part of the step.
+
     Trace-safe: shapes and the backend choice are static, so this runs
     unchanged under ``jit``, the executor's slice-batch ``vmap``, and
     ``shard_map``."""
     from .layout import permute_flat  # lazy: avoid cycle
 
-    a3 = permute_flat(a, ds.a_order, ds.a_gemm, ds.size_of)
-    b3 = permute_flat(b, ds.b_order, ds.b_gemm, ds.size_of)
-    a3 = a3.reshape(ds.a_shape)
-    b3 = b3.reshape(ds.b_shape)
+    with jax.named_scope("permute"):
+        a3 = permute_flat(a, ds.a_order, ds.a_gemm, ds.size_of)
+        b3 = permute_flat(b, ds.b_order, ds.b_gemm, ds.size_of)
+        a3 = a3.reshape(ds.a_shape)
+        b3 = b3.reshape(ds.b_shape)
     real_bytes = real_component_bytes(jnp.result_type(a.dtype, b.dtype))
     precision = spec.precision if spec is not None else "fp32"
-    # 64-bit components would be silently truncated by the fp32 Pallas
-    # accumulator: they stay on XLA's dot
-    if spec is not None and spec.backend == "pallas" and real_bytes <= 4:
-        from ..kernels import ops
+    with jax.named_scope("gemm"):
+        # 64-bit components would be silently truncated by the fp32
+        # Pallas accumulator: they stay on XLA's dot
+        if spec is not None and spec.backend == "pallas" and real_bytes <= 4:
+            from ..kernels import ops
 
-        mm = functools.partial(
-            ops.matmul, bm=spec.bm, bn=spec.bn, bk=spec.bk,
-            interpret=interpret,
-            min_kernel_dim=1,  # the refiner already gated tiny shapes
-            precision=precision,
-        )
-        if ds.a_shape[0] > 1:
-            out = jax.vmap(mm)(a3, b3)
+            mm = functools.partial(
+                ops.matmul, bm=spec.bm, bn=spec.bn, bk=spec.bk,
+                interpret=interpret,
+                min_kernel_dim=1,  # the refiner already gated tiny shapes
+                precision=precision,
+            )
+            if ds.a_shape[0] > 1:
+                out = jax.vmap(mm)(a3, b3)
+            else:
+                out = mm(a3[0], b3[0])[None]
         else:
-            out = mm(a3[0], b3[0])[None]
-    else:
-        lhs, rhs = (b3, a3) if ds.swap else (a3, b3)
-        out = jax.lax.dot_general(
-            lhs, rhs, ds.dims, precision=jax.lax.Precision.HIGHEST
-        )
-    return out.reshape(-1)
+            lhs, rhs = (b3, a3) if ds.swap else (a3, b3)
+            out = jax.lax.dot_general(
+                lhs, rhs, ds.dims, precision=jax.lax.Precision.HIGHEST
+            )
+        return out.reshape(-1)
 
 
 def apply(spec, a: jax.Array, b: jax.Array, *, interpret: bool | None = None):

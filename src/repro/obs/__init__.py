@@ -5,10 +5,11 @@ The measurement substrate for every perf claim the reproduction makes
 claims — Sec. VI).  Three parts:
 
   * :mod:`repro.obs.trace` — low-overhead span tracer: context-manager /
-    decorator spans on a thread-local stack, monotonic wall clocks,
-    optional ``jax.block_until_ready`` sync points at phase boundaries,
-    ``jax.profiler.TraceAnnotation`` passthrough (spans show up in XLA
-    profiles), JSONL export readable by Perfetto.
+    decorator spans on a thread-local stack, monotonic wall clocks on
+    the profiler's time axis, optional ``jax.block_until_ready`` sync
+    points at phase boundaries, ``jax.profiler.TraceAnnotation``
+    passthrough (spans show up in XLA profiles, where the records
+    line up with them), JSONL export readable by Perfetto.
   * :mod:`repro.obs.metrics` — process-global named counters / gauges /
     histograms (plan-cache and HoistCache hits/misses/evicted bytes,
     slices executed, executed FLOPs, ragged-padding
@@ -25,9 +26,13 @@ claims — Sec. VI).  Three parts:
     need (ROADMAP).
 
 Everything is gated by ``REPRO_TRACE={0,1}`` (default off).  The off
-path is no-op stubs at the Python orchestration layer — nothing is ever
-inserted into jitted programs, so plan fingerprints and compiled
-artifacts are bitwise-unchanged whether tracing is on or off.
+path is no-op stubs at the Python orchestration layer — the flag never
+reaches a jitted program, so plan fingerprints and compiled artifacts
+are bitwise-unchanged whether tracing is on or off.  Inside the
+programs, ``jax.named_scope``s name each contraction step and its parts
+(always on: they change only op metadata, see
+:mod:`repro.core.executor`), which is how a device profile's ops are
+attributed.
 """
 
 from __future__ import annotations
@@ -35,7 +40,6 @@ from __future__ import annotations
 from . import calibrate, log, metrics, trace  # noqa: F401
 from .calibrate import CalibrationReport, calibrate_plan  # noqa: F401
 from .trace import (  # noqa: F401
-    annotate,
     dump_trace,
     enabled,
     enabled_scope,

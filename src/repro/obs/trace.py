@@ -1,4 +1,4 @@
-"""Span tracer: thread-local span stacks, monotonic wall, JSONL export.
+"""Span tracer: thread-local span stacks, profiler-clock wall, JSONL export.
 
 Design constraints (the acceptance contract of the observability PR):
 
@@ -6,15 +6,21 @@ Design constraints (the acceptance contract of the observability PR):
     :func:`span` return a shared no-op context manager and makes
     :func:`sync` / :func:`instant` early-return on one boolean check.
     Instrumentation lives at the Python orchestration layer only —
-    nothing is inserted into jit-traced code — so compiled artifacts and
+    the flag never reaches jit-traced code — so compiled artifacts and
     plan fingerprints are bitwise-identical with tracing on or off.
   * **Well-formed span trees.**  Spans nest on a thread-local stack:
     every record carries its parent's id, and per thread the intervals
     are properly nested (children inside parents, siblings
     non-overlapping) because enter/exit order is stack order.
-  * **XLA profile passthrough.**  An active span also enters
+  * **XLA profile passthrough, one clock.**  An active span also enters
     ``jax.profiler.TraceAnnotation(name)``, so the same names show up on
     the host timeline of an XLA profile when one is being captured.
+    Records are stamped on the clock the profiler stamps its host
+    events with (the Unix-epoch clock of ``time.time_ns``; an xplane's
+    event times are offsets from its ``profile_start_time`` on that
+    clock), through one offset from ``time.perf_counter`` taken at
+    import, so durations stay monotonic and a record lands where its
+    annotation does.
   * **Sync points.**  Wall times at phase boundaries are only meaningful
     once dispatched work retires; :func:`sync` is
     ``jax.block_until_ready`` gated on the tracing flag, so enabling
@@ -42,6 +48,15 @@ import time
 #: must not grow memory without bound); drops are counted in
 #: ``metrics`` under ``trace.dropped_spans``.
 MAX_SPANS = 200_000
+
+
+#: profiler clock minus ``time.perf_counter``, in seconds, taken once
+_CLOCK_OFFSET_S = (time.time_ns() - time.perf_counter_ns()) * 1e-9
+
+
+def _now() -> float:
+    """Seconds on the profiler's clock, advancing as ``perf_counter``."""
+    return time.perf_counter() + _CLOCK_OFFSET_S
 
 
 def _env_enabled() -> bool:
@@ -101,7 +116,7 @@ class SpanRecord:
     parent_id: int  # 0 = top-level span of its thread
     name: str
     cat: str
-    t_start: float  # time.perf_counter seconds
+    t_start: float  # seconds on the profiler's clock (see _now)
     t_end: float
     thread: int
     pid: int
@@ -186,11 +201,11 @@ class _Span:
         if _jax:
             self._ann = _jax.profiler.TraceAnnotation(self.name)
             self._ann.__enter__()
-        self.t0 = time.perf_counter()
+        self.t0 = _now()
         return self
 
     def __exit__(self, *exc):
-        t1 = time.perf_counter()
+        t1 = _now()
         if self._ann is not None:
             self._ann.__exit__(*exc)
         st = _stack()
@@ -249,25 +264,6 @@ def traced(name: str | None = None, cat: str = "fn"):
     return deco
 
 
-def annotate(name: str):
-    """XLA-profile-only annotation (``jax.profiler.TraceAnnotation``):
-    used inside kernel dispatch where a wall-clock span would time
-    tracing, not execution.  No-op when tracing is off."""
-    global _jax
-    if not _enabled:
-        return _NOOP
-    if _jax is None:
-        try:
-            import jax
-
-            _jax = jax
-        except Exception:  # pragma: no cover
-            _jax = False
-    if not _jax:
-        return _NOOP
-    return _jax.profiler.TraceAnnotation(name)
-
-
 def sync(x):
     """Phase-boundary sync point: ``jax.block_until_ready`` when tracing
     is on (span walls then measure retired work, not dispatch), identity
@@ -291,7 +287,7 @@ def instant(name: str, cat: str = "instant", **attrs) -> None:
     """Zero-duration event (structured log records ride on these)."""
     if not _enabled:
         return
-    t = time.perf_counter()
+    t = _now()
     st = _stack()
     rec = SpanRecord(
         span_id=next(_ids),

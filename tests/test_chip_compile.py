@@ -15,7 +15,9 @@ imports this file.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -134,6 +136,43 @@ def test_slice_program_compiles_near_certified_peak(one_chip, syc12, backend):
     certified = 2 * plan.memory_plan().peak_bytes
     # one axis per index would pad every minor dim of 2 to 128 lanes
     assert ma.temp_size_in_bytes <= 4 * certified
+
+
+def _code(hlo_text: str) -> list[str]:
+    """HLO text without metadata and the stack-frame tables it uses."""
+    text = re.sub(r", metadata=\{[^}]*\}", "", hlo_text)
+    return [
+        line for line in text.splitlines()
+        if not re.match(r"(\d+ |FileNames|FunctionNames|FileLocations|"
+                        r"StackFrames)", line)
+    ]
+
+
+def test_step_scopes_change_metadata_only(one_chip, syc12, monkeypatch):
+    """The slice program's named scopes leave the chip's program as it
+    is, and put every Pallas kernel, named ``tiled_matmul``, in the
+    ``gemm`` scope of a ``pallas`` step."""
+    tn, arrays = syc12
+
+    def compiled_text():
+        plan, _ = plan_compiled(
+            tn, 18, dtype=arrays[0].dtype, backend="gemm",
+            slicing_mode="peak", use_cache=False,
+        )
+        return _slice_program(plan, arrays, one_chip, batch=2).as_text()
+
+    scoped = compiled_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compiled_text()
+    assert _code(scoped) == _code(bare)
+    kernels = [line for line in scoped.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels
+    for line in kernels:
+        assert re.match(r"\s*(ROOT )?%tiled_matmul(\.\d+)? = ", line), line
+        assert re.search(r'op_name="[^"]*\bstep\d+\.pallas\)?/gemm/',
+                         line), line
 
 
 def test_sharded_program_compiles_for_four_chips(topo, syc12):
