@@ -13,8 +13,9 @@ port of that bridge between the planner and the kernels:
               least one TPU lane tile wide, GEMM orientation per step
   refiner   — the Sec. V-B adaptive refiner for TPU: per-node backend
               choice (Pallas tiled_matmul / jnp.dot / jnp.einsum),
-              MXU-128-snapped block shapes, pad-vs-split decisions, and
-              the per-node cost model fed back into PlanReport
+              MXU-128-snapped block shapes priced by grid steps and
+              operand re-reads, and the per-node cost model fed back
+              into PlanReport
   cache     — compiled-plan LRU keyed by a canonical network
               fingerprint (structure + dtype + open indices + planner
               params), so repeated requests for the same circuit family
@@ -40,7 +41,8 @@ Sunway→TPU mapping of the refiner, for the record: SWTT 8×8 fused-GEMM
 kernel quantization → MXU 128×128 tile quantization; LDM residency →
 VMEM residency budget; DMA-bandwidth roofline → HBM roofline;
 fp16-compute/fp32-accumulate → bf16/fp32 ``preferred_element_type``;
-the permute-or-pad index rewrite → per-node pad-vs-split block choice.
+the permute-or-pad index rewrite → per-node block choice by grid steps,
+with no padding past the MXU tile.
 """
 
 from .cache import (  # noqa: F401

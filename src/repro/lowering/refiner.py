@@ -14,14 +14,21 @@ GemmForm` of every contraction step:
   2. **block shapes** — (bm, bn, bk) snapped to multiples of the 128-wide
      MXU tile, chosen per node from a candidate ladder under the VMEM
      residency budget;
-  3. **pad-vs-split** — for each candidate the model charges the padded
-     FLOPs ``ceil(M/bm)·ceil(N/bn)·ceil(K/bk)`` tiles actually execute;
-     picking a smaller block *splits* the GEMM into more, fuller tiles
-     while a larger block *pads* — the candidate with the lower modeled
-     time wins (the Sunway refiner's permute-or-pad choice).
+  3. **grid steps vs padding** — the Pallas kernel's time is its grid
+     steps ``B·⌈M/bm⌉·⌈N/bn⌉·⌈K/bk⌉`` (× Karatsuba's 3) times a per-step
+     price: the larger of the tile's MXU time and the DMA of its A and B
+     tiles (so the re-reads of A across N and of B across M are
+     charged), plus a fixed pipeline cost per step; the output block is
+     written once per ``(i, j)``.  Padded tiles count as whole tiles.
+     No candidate may pad a dimension past its 128-wide MXU tile: on the
+     chip fp32 ``HIGHEST`` takes several MXU passes, which the model does
+     not price yet, so a padded row costs more than the model shows.
+     Among the rest the lowest modeled time wins, ties going to fewer
+     grid steps.
 
-The same per-node cost model (tile quantization capped by the HBM
-roofline, complex traffic counted as Karatsuba's 3 real GEMMs) is summed
+The same per-node cost model (grid steps for Pallas, exact FLOPs capped
+by the HBM roofline for dot/einsum, complex traffic counted as
+Karatsuba's 3 real GEMMs or the naive 4) is summed
 into ``LoweredSchedule.modeled_time_s``, which the API layer feeds back
 into ``PlanReport.modeled_time_s`` so planner metrics reflect the
 schedule that will actually execute.
@@ -30,6 +37,7 @@ schedule that will actually execute.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from typing import Callable, Hashable, Sequence
 
@@ -48,6 +56,13 @@ EINSUM_FLOPS_FLOOR = 2.0 ** 16
 # effective peak for non-MXU lowerings (XLA dot_general / einsum on
 # sub-tile shapes): mostly VPU + permute work, modeled at peak/8
 NON_MXU_PEAK_FRACTION = 0.125
+# fixed cost of one Pallas grid step beyond its tile DMA and MXU work:
+# the pipeline's per-step overhead, the MXU weight loads and the output
+# block's read-modify-write.  On a v5e the fp32 ``tiled_matmul`` at
+# 128×128×128 took 0.445 µs per grid step across eleven stem GEMMs from
+# K = 256 to K = 65536; its A and B tiles (128 KiB) take 0.160 µs at
+# 819 GB/s, so 0.285 µs is left for the step itself.
+PALLAS_STEP_S = 0.285e-6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,6 +136,20 @@ def step_traffic_bytes(
     )
 
 
+def pallas_grid_steps(form: GemmForm, dtype, bm: int, bn: int,
+                      bk: int) -> int:
+    """Grid steps of one execution of this step on the Pallas kernel:
+    ``B·⌈M/bm⌉·⌈N/bn⌉·⌈K/bk⌉`` per real GEMM, times Karatsuba's 3 for
+    complex operands."""
+    return (
+        form.B
+        * math.ceil(form.M / bm)
+        * math.ceil(form.N / bn)
+        * math.ceil(form.K / bk)
+        * _real_gemm_count(dtype, "pallas")
+    )
+
+
 def modeled_step_time(
     form: GemmForm,
     dtype,
@@ -132,12 +161,16 @@ def modeled_step_time(
 ) -> tuple[float, float]:
     """(seconds, pad_waste) for one execution of this step.
 
-    Pallas is charged padded-tile FLOPs at full MXU peak; dot/einsum are
-    charged exact FLOPs at the non-MXU effective peak.  All are capped by
-    the HBM roofline on the operand + output traffic — and the backends
-    that materialize permuted operand copies (``pallas``, ``dot``)
-    additionally pay the ``2*(|A|+|B|)*bytes`` transpose bandwidth: a
-    separate, non-overlappable HBM round-trip before the GEMM proper.
+    Pallas is charged per grid step (:func:`pallas_grid_steps`): the
+    larger of the tile's MXU time at full peak and the DMA of its
+    ``bm×bk`` A and ``bk×bn`` B tiles, plus :data:`PALLAS_STEP_S`; the
+    fp32 output block is written once per ``(i, j)``.  Padded tiles
+    count whole.  dot/einsum are charged exact FLOPs at the non-MXU
+    effective peak, capped by the HBM roofline on the operand + output
+    traffic.  The backends that materialize permuted operand copies
+    (``pallas``, ``dot``) additionally pay the ``2*(|A|+|B|)*bytes``
+    transpose bandwidth: a separate, non-overlappable HBM round-trip
+    before the GEMM proper.
 
     ``precision="bf16"`` (MXU backends only) doubles the systolic-array
     rate and halves the operand-side traffic — bf16 inputs, fp32
@@ -145,24 +178,24 @@ def modeled_step_time(
     """
     n_real = _real_gemm_count(dtype, backend)
     flops = form.flops * n_real
-    traffic = step_traffic_bytes(form, dtype, precision)
-    t_mem = traffic / TPU_HBM_BW
-    mxu_peak = TPU_PEAK_FLOPS * (2.0 if precision == "bf16" else 1.0)
     if backend == "pallas":
-        padded = (
-            2.0
-            * form.B
-            * _ceil_to(form.M, bm)
-            * _ceil_to(form.N, bn)
-            * _ceil_to(form.K, bk)
-            * n_real
+        mxu_peak = TPU_PEAK_FLOPS * (2.0 if precision == "bf16" else 1.0)
+        ob = 2 if precision == "bf16" else real_component_bytes(dtype)
+        steps = pallas_grid_steps(form, dtype, bm, bn, bk)
+        t_tile = max(
+            2.0 * bm * bn * bk / mxu_peak,
+            ob * (bm * bk + bk * bn) / TPU_HBM_BW,
         )
-        t_compute = padded / mxu_peak
-        waste = 1.0 - flops / padded
+        out_blocks = steps // math.ceil(form.K / bk)
+        t = steps * (t_tile + PALLAS_STEP_S) + (
+            out_blocks * 4.0 * bm * bn / TPU_HBM_BW
+        )
+        waste = 1.0 - flops / (2.0 * steps * bm * bn * bk)
     else:
         t_compute = flops / (TPU_PEAK_FLOPS * NON_MXU_PEAK_FRACTION)
+        t_mem = step_traffic_bytes(form, dtype, precision) / TPU_HBM_BW
+        t = max(t_compute, t_mem)
         waste = 0.0
-    t = max(t_compute, t_mem)
     if backend in ("pallas", "dot"):
         t += operand_transpose_bytes(form, dtype, precision) / TPU_HBM_BW
     return t, waste
@@ -198,23 +231,22 @@ def refine_step(
     # per-component operand bytes at the requested precision; the fp32
     # accumulator/output tile is always 4-byte
     ob = 2 if precision == "bf16" else real_bytes
-    best: GemmSpec | None = None
     tbytes = operand_transpose_bytes(form, dtype, precision)
-    for bm in BLOCK_CANDIDATES:
-        for bn in BLOCK_CANDIDATES:
-            for bk in BLOCK_CANDIDATES:
-                if ob * (bm * bk + bk * bn) + 4 * bm * bn > (
-                    VMEM_BUDGET_BYTES
-                ):
-                    continue  # working set must stay VMEM-resident
-                t, w = modeled_step_time(
-                    form, dtype, "pallas", bm, bn, bk, precision
-                )
-                if best is None or t < best.modeled_time_s:
-                    best = GemmSpec(
-                        form, "pallas", bm, bn, bk, t, w, tbytes, precision
-                    )
-    return best
+    candidates = []
+    for bm, bn, bk in itertools.product(BLOCK_CANDIDATES, repeat=3):
+        if ob * (bm * bk + bk * bn) + 4 * bm * bn > VMEM_BUDGET_BYTES:
+            continue  # working set must stay VMEM-resident
+        if any(
+            _ceil_to(d, b) != _ceil_to(d, TPU_MXU)
+            for d, b in ((form.M, bm), (form.N, bn), (form.K, bk))
+        ):
+            continue  # pads past the MXU tile
+        t, w = modeled_step_time(form, dtype, "pallas", bm, bn, bk, precision)
+        steps = pallas_grid_steps(form, dtype, bm, bn, bk)
+        candidates.append(((t, steps), GemmSpec(
+            form, "pallas", bm, bn, bk, t, w, tbytes, precision
+        )))
+    return min(candidates, key=lambda c: c[0])[1]
 
 
 @dataclasses.dataclass
@@ -278,6 +310,24 @@ class LoweredSchedule:
         permutations (per slice) — zero on einsum nodes."""
         return sum(s.transpose_bytes for s in self.specs)
 
+    def pallas_grid_steps(self) -> int:
+        """Pallas grid steps per slice, over every Pallas step (with
+        Karatsuba's 3 real GEMMs for complex steps)."""
+        return sum(
+            pallas_grid_steps(s.form, self.dtype, s.bm, s.bn, s.bk)
+            for s in self.specs
+            if s.backend == "pallas"
+        )
+
+    def pallas_tiles(self) -> dict[str, int]:
+        """Pallas steps by block shape: ``{"bm×bn×bk": count}``."""
+        counts: dict[str, int] = {}
+        for s in self.specs:
+            if s.backend == "pallas":
+                key = f"{s.bm}×{s.bn}×{s.bk}"
+                counts[key] = counts.get(key, 0) + 1
+        return counts
+
     def summary(self) -> dict:
         return {
             "nodes": len(self.specs),
@@ -285,6 +335,8 @@ class LoweredSchedule:
             "pad_waste": self.pad_waste(),
             "modeled_time_s": self.modeled_time_s,
             "transpose_bytes": self.transpose_bytes(),
+            "pallas_grid_steps": self.pallas_grid_steps(),
+            "pallas_tiles": self.pallas_tiles(),
             "dtype": self.dtype,
             "precision_mode": self.precision_mode,
             "precision_counts": self.precision_counts(),
@@ -306,9 +358,16 @@ class LoweredSchedule:
             if pc.get("bf16")
             else ""
         )
+        tiles = ",".join(
+            f"{k}:{v}" for k, v in sorted(self.pallas_tiles().items())
+        )
+        grid = (
+            f"grid_steps={self.pallas_grid_steps()} tiles={tiles} "
+            if tiles else ""
+        )
         return (
             f"lowered[{self.dtype}]: {len(self.specs)} nodes ({per}) "
-            f"pad_waste={self.pad_waste()*100:.1f}% "
+            f"pad_waste={self.pad_waste()*100:.1f}% {grid}"
             f"t_model={self.modeled_time_s:.3e}s/slice{prec}"
         )
 

@@ -29,6 +29,7 @@ from repro.core.api import plan_compiled
 from repro.core.executor import simplify_network
 from repro.engine.session import ContractionSession
 from repro.kernels import ops, tiled_matmul
+from repro.lowering import lower_step, refine_step
 from repro.lowering.refiner import BLOCK_CANDIDATES, VMEM_BUDGET_BYTES
 from repro.quantum.circuits import circuit_to_network, sycamore_like
 
@@ -94,6 +95,27 @@ def test_complex_karatsuba_matmul_compiles(one_chip, precision):
     fn = jax.jit(lambda x, y: ops.matmul(x, y, precision=precision))
     text = fn.lower(a, b).compile().as_text()
     assert text.count("tpu_custom_call") >= 3  # three real GEMMs
+
+
+# (M, N, K) of the two costliest stem GEMMs of the benchmark's plans
+@pytest.mark.parametrize("m,n,k", [(16384, 2048, 8192), (8192, 8192, 8192)])
+def test_karatsuba_matmul_compiles_at_real_size_and_chosen_tile(
+    one_chip, m, n, k
+):
+    """The kernel at the tile the refiner chooses for the cells' largest
+    steps, at their full size: Mosaic must accept the VMEM it needs."""
+    form = lower_step(("m", "k"), ("k", "n"), ("m", "n"),
+                      dict(m=m, n=n, k=k).__getitem__)
+    spec = refine_step(form, jnp.complex64)
+    assert spec.backend == "pallas"
+    a = _sds((m, k), jnp.complex64, one_chip)
+    b = _sds((k, n), jnp.complex64, one_chip)
+    fn = jax.jit(lambda x, y: ops.matmul(x, y, bm=spec.bm, bn=spec.bn,
+                                         bk=spec.bk))
+    text = fn.lower(a, b).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 3  # three real GEMMs
 
 
 def _slice_program(plan, arrays, sharding, batch):

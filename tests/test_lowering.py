@@ -2,6 +2,8 @@
 end-to-end backend agreement, schedule execution under shard_map, and the
 compiled-plan cache contract."""
 
+import collections
+import itertools
 import subprocess
 import sys
 
@@ -26,7 +28,7 @@ from repro.lowering import (
     refine_schedule,
     refine_step,
 )
-from repro.lowering import gemm_form
+from repro.lowering import gemm_form, refiner
 from repro.lowering.cache import PLAN_CACHE, PlanCache, network_fingerprint
 from repro.quantum import statevector
 from repro.quantum.circuits import circuit_to_network, random_1d_circuit
@@ -168,6 +170,87 @@ def test_refiner_routes_64bit_off_pallas():
     assert refine_step(form, np.float32).backend == "pallas"
     assert refine_step(form, np.float64).backend == "dot"
     assert refine_step(form, np.complex128).backend == "dot"
+
+
+# (M, N, K) of the Pallas steps of the benchmark's 30-qubit plans
+STEM_SHAPES = [
+    (32768, 2048, 256), (1024, 2048, 65536), (512, 32768, 4096),
+    (32768, 4096, 512), (16384, 2048, 8192), (32768, 4096, 256),
+    (32768, 512, 4096), (4096, 4096, 4096), (65536, 512, 256),
+    (32768, 2048, 1024), (8192, 8192, 8192),
+]
+
+
+def _mnk_form(m, n, k):
+    sizes = dict(m=m, n=n, k=k)
+    return lower_step(("m", "k"), ("k", "n"), ("m", "n"), sizes.__getitem__)
+
+
+def _admissible_tiles(form, dtype):
+    """Tiles within the VMEM budget that pad no dimension past 128."""
+    ob = gemm_form.real_component_bytes(dtype)
+    for t in itertools.product(refiner.BLOCK_CANDIDATES, repeat=3):
+        bm, bn, bk = t
+        if ob * (bm * bk + bk * bn) + 4 * bm * bn > (
+            refiner.VMEM_BUDGET_BYTES
+        ):
+            continue
+        if all(-(-d // b) * b == -(-d // 128) * 128
+               for d, b in zip((form.M, form.N, form.K), t)):
+            yield t
+
+
+@pytest.mark.parametrize("dtype", [np.complex64, np.float32])
+@pytest.mark.parametrize("m,n,k", STEM_SHAPES)
+def test_refiner_picks_fewest_grid_steps_on_stem_shapes(m, n, k, dtype):
+    form = _mnk_form(m, n, k)
+    spec = refine_step(form, dtype)
+    assert spec.backend == "pallas"
+    assert spec.pad_waste == 0.0
+    assert (spec.bm, spec.bn, spec.bk) == (512, 512, min(k, 512))
+    steps = refiner.pallas_grid_steps(form, dtype, spec.bm, spec.bn, spec.bk)
+    assert steps == min(
+        refiner.pallas_grid_steps(form, dtype, *t)
+        for t in _admissible_tiles(form, dtype)
+    )
+
+
+def test_refiner_does_not_pad_ragged_m_for_fewer_grid_steps():
+    """M = 5 x 128: a 512-row tile would pad M to 1024 for fewer grid
+    steps; padding outweighs them."""
+    form = _mnk_form(640, 4096, 4096)
+    spec = refine_step(form, np.complex64)
+    assert spec.backend == "pallas"
+    assert -(-640 // spec.bm) * spec.bm == 640
+    assert spec.pad_waste == 0.0
+    assert (spec.bn, spec.bk) == (512, 512)
+
+
+def test_pallas_grid_steps_counts_every_pallas_step():
+    sizes = dict(b=2, m=640, k=1024, n=512, p=4096, q=8)
+    sched = refine_schedule(
+        [
+            (("b", "m", "k"), ("b", "k", "n"), ("b", "m", "n")),
+            (("n", "p"), ("p", "q", "m"), ("n", "q", "m")),
+        ],
+        sizes.__getitem__,
+        dtype=np.complex64,
+    )
+    assert sched.backend_counts() == {"pallas": 2}
+    want = 0
+    for s in sched.specs:
+        f = s.form
+        want += (f.B * -(-f.M // s.bm) * -(-f.N // s.bn)
+                 * -(-f.K // s.bk) * 3)  # Karatsuba's 3 real GEMMs
+    assert sched.pallas_grid_steps() == want > 0
+    tiles = sched.pallas_tiles()
+    assert tiles == dict(collections.Counter(
+        f"{s.bm}×{s.bn}×{s.bk}" for s in sched.specs
+    ))
+    summary = sched.summary()
+    assert summary["pallas_grid_steps"] == want
+    assert summary["pallas_tiles"] == tiles
+    assert f"grid_steps={want}" in sched.summary_row()
 
 
 @given(
