@@ -184,7 +184,6 @@ def telemetry_rows(
     from repro.obs import trace
 
     import jax
-    import jax.numpy as jnp
 
     rows, records = [], []
     prev = trace.enabled()
@@ -205,7 +204,9 @@ def telemetry_rows(
             else:
                 path = "resumable[0:16)"
                 out_shape = jax.eval_shape(
-                    lambda: plan.contract_slice(list(arrays), jnp.int32(0))
+                    lambda: plan.contract_slice(
+                        list(arrays), plan.slice_bits(0)
+                    )
                 )
 
                 def run_once():

@@ -6,7 +6,10 @@ program:
 
   * each of the ``2^|S|`` subtasks fixes the sliced indices to one bit
     assignment (``lax.index_in_dim`` on the leaf arrays — shape-stable, so
-    a single jitted function serves every subtask),
+    a single jitted function serves every subtask); the host hands the
+    program each slice id as its row of bits
+    (:meth:`ContractionPlan.slice_bits`), so an id may be wider than the
+    device's 32-bit integers,
   * subtasks are batched with ``vmap`` (beyond-paper: batching slices
     recovers GEMM efficiency lost to narrow stems — the M dimension grows
     by the slice-batch factor); a ragged final batch is padded with
@@ -80,6 +83,10 @@ from .tensor_network import TensorNetwork, bits
 _LETTERS = string.ascii_letters
 
 BACKENDS = ("einsum", "gemm")
+
+#: Slice ids are int64 on the host (:meth:`ContractionPlan.slice_bits`),
+#: so a plan slices at most this many indices.
+MAX_SLICE_BITS = 62
 
 
 def default_backend() -> str:
@@ -463,12 +470,24 @@ class ContractionPlan:
         return self._memory_plan
 
     # ------------------------------------------------------------------
-    def slice_values(self, slice_id):
-        """bit-decompose a (traced) slice id into per-index 0/1 values."""
-        ar = jnp.arange(self.num_sliced, dtype=jnp.int32)
-        return (
-            jnp.right_shift(jnp.asarray(slice_id, jnp.int32), ar) & 1
-        ).astype(jnp.int32)
+    def slice_bits(self, slice_ids) -> np.ndarray:
+        """Slice ids as the device takes them: bit ``j`` of each id,
+        ``(id >> j) & 1``, in column ``j``, one int32 column per sliced
+        index in :attr:`sliced_bits` order.
+
+        ``slice_ids`` is an int or a sequence of ints (Python or NumPy)
+        below ``2**MAX_SLICE_BITS``; the result has shape
+        ``np.shape(slice_ids) + (num_sliced,)``.  Ids stay int64 on the
+        host and never reach the device whole, so they may be wider than
+        its 32-bit integers."""
+        if self.num_sliced > MAX_SLICE_BITS:
+            raise ValueError(
+                f"{self.num_sliced} sliced indices; slice ids hold at most "
+                f"{MAX_SLICE_BITS} bits"
+            )
+        ids = np.asarray(slice_ids, dtype=np.int64)
+        shifts = np.arange(self.num_sliced, dtype=np.int64)
+        return ((ids[..., None] >> shifts) & 1).astype(np.int32)
 
     def _run_steps(self, env: dict, step_ids, segment: str = "naive") -> None:
         """Execute the given step positions over ``env`` (shared by the
@@ -503,9 +522,10 @@ class ContractionPlan:
                 del env[u]
 
     def contract_slice(
-        self, arrays: Sequence[jnp.ndarray], slice_id, hoisted=None
+        self, arrays: Sequence[jnp.ndarray], slice_bits, hoisted=None
     ):
-        """Contract one subtask (slice assignment = bits of slice_id).
+        """Contract one subtask: ``slice_bits`` is its row of
+        :meth:`slice_bits`, the value of each sliced index.
 
         ``hoisted`` (from :meth:`contract_prologue`) seeds the environment
         with the materialized slice-invariant buffers, so only the
@@ -521,12 +541,11 @@ class ContractionPlan:
             step_ids = self.epilogue_idx
             segment = "epilogue"
         with jax.named_scope("leaves"):
-            svals = self.slice_values(slice_id)
             for i in leaf_ids:
                 a = jnp.asarray(arrays[i])
                 for axis, spos in self.leaf_specs[i]:
                     a = jax.lax.dynamic_index_in_dim(
-                        a, svals[spos], axis=axis, keepdims=False
+                        a, slice_bits[spos], axis=axis, keepdims=False
                     )
                 env[i] = a.reshape(-1)
         self._run_steps(env, step_ids, segment)

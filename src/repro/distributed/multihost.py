@@ -217,7 +217,7 @@ def contract_multihost(
                     f"[{rng.start},{rng.end})"
                 )
             ids = (
-                np.arange(rng.start, rng.start + sb, dtype=np.int32)
+                np.arange(rng.start, rng.start + sb, dtype=np.int64)
                 % n_slices
             )
             valid = np.arange(rng.start, rng.start + sb) < rng.end

@@ -63,13 +63,13 @@ def padded_ids(
 ) -> tuple[np.ndarray, np.ndarray, int]:
     """Slice ids padded (by wrap-around) to a multiple of ``multiple``.
 
-    Returns ``(ids, valid, total)``: int32 ids of length ``total`` (the
+    Returns ``(ids, valid, total)``: int64 ids of length ``total`` (the
     ceiling multiple), a boolean validity vector marking the real ids,
     and ``total`` itself.  Padding with *wrapped* ids keeps every lane a
     legal slice id (shape-stable indexing); the validity mask is what
     keeps the duplicates out of the sum."""
     total = -(-n_slices // multiple) * multiple
-    ids = np.arange(total, dtype=np.int32) % n_slices
+    ids = np.arange(total, dtype=np.int64) % n_slices
     valid = np.arange(total) < n_slices
     return ids, valid, total
 
@@ -135,6 +135,7 @@ class ContractionSession:
         h = default_hoist() if hoist is None else bool(hoist)
         self.hoist = bool(h and plan.can_hoist)
         self._hoisted: list | None = None
+        _metrics.set_gauge("engine.sliced_bits", plan.num_sliced)
 
     # ------------------------------------------------------------------
     @property
@@ -171,7 +172,7 @@ class ContractionSession:
                 key,
                 jax.eval_shape(
                     lambda: plan.contract_slice(
-                        list(self.arrays), jnp.int32(0)
+                        list(self.arrays), plan.slice_bits(0)
                     )
                 ),
             )
@@ -192,12 +193,15 @@ class ContractionSession:
         fn = plan._compiled.get(ck) or plan._compiled.setdefault(
             ck,
             jax.jit(
-                lambda arrs, hbufs, sid: plan.contract_slice(
-                    arrs, sid, hbufs if hoist else None
+                lambda arrs, hbufs, sbits: plan.contract_slice(
+                    arrs, sbits, hbufs if hoist else None
                 )
             ),
         )
-        return fn(list(self.arrays), list(self.hoisted()), jnp.int32(slice_id))
+        return fn(
+            list(self.arrays), list(self.hoisted()),
+            jnp.asarray(plan.slice_bits(slice_id)),
+        )
 
     # ------------------------------------------------------------------
     # THE primitive: one jitted masked-vmap batch over explicit ids
@@ -205,18 +209,21 @@ class ContractionSession:
     def run_slices(self, slice_ids, valid=None) -> jnp.ndarray:
         """Execute a batch of slice ids and return the masked partial sum.
 
-        ``slice_ids`` may contain wrapped-around padding ids; ``valid``
-        (default all-true) marks the lanes that contribute.  One jitted
-        program serves every batch size (jit re-specializes per shape
-        and caches internally); the masking select and the vmapped
-        ``contract_slice`` dispatch — free schedules, layouts, precision —
-        are the single shared implementation.
+        ``slice_ids`` (ints below ``2**MAX_SLICE_BITS``) may contain
+        wrapped-around padding ids; ``valid`` (default all-true) marks
+        the lanes that contribute.  The device program takes the ids as
+        a ``(batch, |S|)`` array of their bits (``plan.slice_bits``).
+        One jitted program serves every batch size (jit re-specializes
+        per shape and caches internally); the masking select and the
+        vmapped ``contract_slice`` dispatch — free schedules, layouts,
+        precision — are the single shared implementation.
 
         Traced as ``engine.run_slices``, with two children:
-        ``engine.ids_put`` (the ids and mask onto the device) and
+        ``engine.ids_put`` (the ids' bits and the mask onto the device;
+        attribute ``bits`` = |S|) and
         ``engine.launch`` (the jitted call up to its return, not to the
         device's finish)."""
-        ids = np.asarray(slice_ids, dtype=np.int32)
+        ids = np.asarray(slice_ids, dtype=np.int64)
         if valid is None:
             valid = np.ones(ids.shape, dtype=bool)
         fn, hbufs = self._batch_fn(), list(self.hoisted())
@@ -224,10 +231,18 @@ class ContractionSession:
             "engine.run_slices", cat="engine", ids=int(ids.size),
             first_id=int(ids[0]) if ids.size else None,
         ):
-            with _trace.span("engine.ids_put", cat="engine"):
-                ids_d, valid_d = jnp.asarray(ids), jnp.asarray(valid)
+            bits_d, valid_d = self._put_ids(ids, valid)
             with _trace.span("engine.launch", cat="engine"):
-                return fn(list(self.arrays), hbufs, ids_d, valid_d)
+                return fn(list(self.arrays), hbufs, bits_d, valid_d)
+
+    def _put_ids(self, ids: np.ndarray, valid):
+        """The ids' bits and the validity mask on the device."""
+        with _trace.span(
+            "engine.ids_put", cat="engine", bits=self.plan.num_sliced
+        ):
+            return (
+                jnp.asarray(self.plan.slice_bits(ids)), jnp.asarray(valid)
+            )
 
     def _batch_fn(self):
         plan, hoist = self.plan, self.hoist
@@ -236,11 +251,11 @@ class ContractionSession:
         if fn is None:
 
             @jax.jit
-            def fn(arrs, hbufs, ids_, valid_):
-                contract = lambda sid: plan.contract_slice(  # noqa: E731
-                    arrs, sid, hbufs if hoist else None
+            def fn(arrs, hbufs, bits_, valid_):
+                contract = lambda sbits: plan.contract_slice(  # noqa: E731
+                    arrs, sbits, hbufs if hoist else None
                 )
-                contrib = jax.vmap(contract)(ids_)
+                contrib = jax.vmap(contract)(bits_)
                 with jax.named_scope("batch_sum"):
                     return jnp.sum(mask_invalid(contrib, valid_), axis=0)
 
@@ -252,7 +267,7 @@ class ContractionSession:
         ids, for reading its ``memory_analysis()`` and ``as_text()``."""
         return self._batch_fn().lower(
             list(self.arrays), list(self.hoisted()),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n, self.plan.num_sliced), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.bool_),
         ).compile()
 
@@ -279,7 +294,8 @@ class ContractionSession:
             # setdefault: concurrent serving threads race to publish, but
             # all end up calling the one surviving jitted fn (single trace)
             fn = plan._compiled.get(key) or plan._compiled.setdefault(
-                key, jax.jit(lambda a: plan.contract_slice(a, 0))
+                key,
+                jax.jit(lambda a: plan.contract_slice(a, plan.slice_bits(0))),
             )
             with _trace.span(
                 "exec.contract_all", cat="exec", slices=1, hoist=False
@@ -294,18 +310,44 @@ class ContractionSession:
         slice_batch = max(1, min(slice_batch, n_slices))
         n_batches = -(-n_slices // slice_batch)
         flat_ids, flat_valid, total = padded_ids(n_slices, slice_batch)
-        padded = total != n_slices
+        fn = self._all_fn(slice_batch)
+        id_bits = plan.slice_bits(flat_ids).reshape(
+            n_batches, slice_batch, plan.num_sliced
+        )
+        w = flat_valid.reshape(n_batches, slice_batch)
+        with _trace.span(
+            "exec.contract_all",
+            cat="exec",
+            slices=n_slices,
+            slice_batch=slice_batch,
+            hoist=hoist,
+            backend=plan.backend,
+        ):
+            out = fn(
+                list(arrays), list(self.hoisted()),
+                jnp.asarray(id_bits), jnp.asarray(w),
+            )
+            _trace.sync(out)
+        record_execution(plan, n_slices, total - n_slices, hoist)
+        return out
+
+    def _all_fn(self, slice_batch: int):
+        """The jitted scan of :meth:`run_all` (memoized on the plan per
+        slice batch and hoist mode).  It takes the slice ids' bits,
+        ``(n_batches, slice_batch, |S|)``, and the validity mask as
+        arguments, so the program's size does not grow with the slice
+        count."""
+        plan, hoist = self.plan, self.hoist
+        padded = self.n_slices % slice_batch != 0
         key = ("all", slice_batch, hoist)
         fn = plan._compiled.get(key)
         if fn is None:
-            ids = jnp.asarray(flat_ids).reshape(n_batches, slice_batch)
-            w = jnp.asarray(flat_valid).reshape(n_batches, slice_batch)
 
             @jax.jit
-            def run(arrs, hbufs):
+            def fn(arrs, hbufs, id_bits, w):
                 batched = jax.vmap(
-                    lambda sid: plan.contract_slice(
-                        arrs, sid, hbufs if hoist else None
+                    lambda sbits: plan.contract_slice(
+                        arrs, sbits, hbufs if hoist else None
                     )
                 )
 
@@ -317,25 +359,14 @@ class ContractionSession:
                     return acc + jnp.sum(contrib, axis=0), None
 
                 out_shape = jax.eval_shape(
-                    lambda: jnp.sum(batched(ids[0]), axis=0)
+                    lambda: jnp.sum(batched(id_bits[0]), axis=0)
                 )
                 acc0 = jnp.zeros(out_shape.shape, out_shape.dtype)
-                acc, _ = jax.lax.scan(body, acc0, (ids, w))
+                acc, _ = jax.lax.scan(body, acc0, (id_bits, w))
                 return acc
 
-            fn = plan._compiled.setdefault(key, run)
-        with _trace.span(
-            "exec.contract_all",
-            cat="exec",
-            slices=n_slices,
-            slice_batch=slice_batch,
-            hoist=hoist,
-            backend=plan.backend,
-        ):
-            out = fn(list(arrays), list(self.hoisted()))
-            _trace.sync(out)
-        record_execution(plan, n_slices, total - n_slices, hoist)
-        return out
+            fn = plan._compiled.setdefault(key, fn)
+        return fn
 
     # ------------------------------------------------------------------
     # strategy: slice ids sharded over a mesh (shard_map + one psum)
@@ -353,21 +384,23 @@ class ContractionSession:
         spec = P(axis_names)
 
         @jax.jit
-        def run(arrs, hbufs, ids_, valid_):
-            def worker(ids_local, valid_local):
+        def run(arrs, hbufs, bits_, valid_):
+            def worker(bits_local, valid_local):
                 # arrs/hbufs are closure captures: replicated devices
-                contract = lambda sid: plan.contract_slice(  # noqa: E731
-                    arrs, sid, hbufs if hoist else None
+                contract = lambda sbits: plan.contract_slice(  # noqa: E731
+                    arrs, sbits, hbufs if hoist else None
                 )
                 batched = jax.vmap(contract)
-                idb = ids_local.reshape(-1, slice_batch)
+                idb = bits_local.reshape(-1, slice_batch, plan.num_sliced)
                 vb = valid_local.reshape(-1, slice_batch)
 
-                out_shape = jax.eval_shape(lambda: contract(jnp.int32(0)))
+                out_shape = jax.eval_shape(
+                    lambda: contract(plan.slice_bits(0))
+                )
 
                 def body(acc, iv):
-                    sids, ok = iv
-                    contrib = batched(sids)
+                    sbits, ok = iv
+                    contrib = batched(sbits)
                     with jax.named_scope("batch_sum"):
                         contrib = mask_invalid(contrib, ok)
                         return acc + jnp.sum(contrib, axis=0), None
@@ -382,7 +415,7 @@ class ContractionSession:
                 in_specs=(spec, spec),
                 out_specs=P(),
                 check_vma=False,
-            )(ids_, valid_)
+            )(bits_, valid_)
 
         # setdefault so concurrent threads converge on one program
         return plan._compiled.setdefault(key, run)
@@ -405,7 +438,7 @@ class ContractionSession:
         for ax in axis_names:
             ndev *= mesh.shape[ax]
         subset = None if slice_ids is None else np.asarray(
-            slice_ids, dtype=np.int32
+            slice_ids, dtype=np.int64
         )
         n_slices = self.n_slices if subset is None else len(subset)
         slice_batch = max(1, min(slice_batch, n_slices))
@@ -426,10 +459,9 @@ class ContractionSession:
             "exec.sharded", cat="exec", slices=n_slices, devices=ndev,
             hoist=hoist, cached=cached,
         ):
-            with _trace.span("engine.ids_put", cat="engine"):
-                ids_d, valid_d = jnp.asarray(ids), jnp.asarray(valid)
+            bits_d, valid_d = self._put_ids(ids, valid)
             with _trace.span("engine.launch", cat="engine"):
-                out = fn(list(self.arrays), list(hoisted), ids_d, valid_d)
+                out = fn(list(self.arrays), list(hoisted), bits_d, valid_d)
             _trace.sync(out)
         record_execution(plan, n_slices, total - n_slices, hoist)
         return out

@@ -165,6 +165,9 @@ def test_plan_compiled_threaded_single_flight():
     c = random_1d_circuit(8, 6, seed=11)
     tn, arrays = circuit_to_network(c, bitstring="0" * 8)
     tn, arrays = simplify_network(tn, arrays)
+    # other tests plan 8-qubit, depth-6 chains of the same structure in
+    # this process, and the cache keys a family by structure alone
+    PLAN_CACHE.clear()
     h0, m0 = PLAN_CACHE.hits, PLAN_CACHE.misses
 
     results = _hammer(8, lambda i: plan_compiled(tn, 10))

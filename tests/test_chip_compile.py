@@ -127,7 +127,7 @@ def _slice_program(plan, arrays, sharding, batch):
     args = (
         [_sds(np.shape(a), np.asarray(a).dtype, sharding) for a in arrays],
         [_sds(h.shape, h.dtype, sharding) for h in hshapes],
-        _sds((batch,), jnp.int32, sharding),
+        _sds((batch, plan.num_sliced), jnp.int32, sharding),
         _sds((batch,), jnp.bool_, sharding),
     )
     return sess._batch_fn().lower(*args).compile()
@@ -216,7 +216,7 @@ def test_sharded_program_compiles_for_four_chips(topo, syc12):
     args = (
         [_sds(np.shape(a), np.asarray(a).dtype, rep) for a in arrays],
         [_sds(h.shape, h.dtype, rep) for h in hshapes],
-        _sds((8,), jnp.int32, shard),
+        _sds((8, plan.num_sliced), jnp.int32, shard),
         _sds((8,), jnp.bool_, shard),
     )
     compiled = sess._sharded_fn(mesh, ("data",), 1).lower(*args).compile()

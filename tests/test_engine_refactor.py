@@ -10,7 +10,9 @@ exactly — not approximately — on the same plans.
 Each legacy driver below is a frozen copy of the pre-refactor
 implementation (taken from the last pre-engine revision), with its jit
 memoization keys renamed ``legacy_*`` so it traces + compiles its OWN
-program rather than sharing the adapter's — the comparison is between
+program rather than sharing the adapter's (its slice ids reach
+``contract_slice`` as rows of ``plan.slice_bits``, the one encoding the
+plan takes) — the comparison is between
 two independently compiled executables, which is what makes equality
 meaningful.
 
@@ -83,7 +85,8 @@ def legacy_contract_all(plan, arrays, slice_batch=8, hoist=None):
     if plan.num_sliced == 0:
         key = ("legacy_dense",)
         fn = plan._compiled.get(key) or plan._compiled.setdefault(
-            key, jax.jit(lambda a: plan.contract_slice(a, 0))
+            key,
+            jax.jit(lambda a: plan.contract_slice(a, plan.slice_bits(0))),
         )
         return fn(list(arrays))
     hoist = default_hoist() if hoist is None else bool(hoist)
@@ -96,8 +99,8 @@ def legacy_contract_all(plan, arrays, slice_batch=8, hoist=None):
     fn = plan._compiled.get(key)
     if fn is None:
         ids = jnp.asarray(
-            np.arange(total, dtype=np.int32) % n_slices
-        ).reshape(n_batches, slice_batch)
+            plan.slice_bits(np.arange(total) % n_slices)
+        ).reshape(n_batches, slice_batch, plan.num_sliced)
         w = jnp.asarray(np.arange(total) < n_slices).reshape(
             n_batches, slice_batch
         )
@@ -147,7 +150,7 @@ def legacy_contract_sharded(
     slice_batch = max(1, min(slice_batch, n_slices))
     chunk = ndev * slice_batch
     total = -(-n_slices // chunk) * chunk
-    ids = np.arange(total, dtype=np.int32) % n_slices
+    ids = plan.slice_bits(np.arange(total) % n_slices)
     valid = np.arange(total) < n_slices
 
     hoist = default_hoist() if hoist is None else bool(hoist)
@@ -167,9 +170,11 @@ def legacy_contract_sharded(
                     arrs, sid, hbufs if hoist else None
                 )
                 batched = jax.vmap(contract)
-                idb = ids_local.reshape(-1, slice_batch)
+                idb = ids_local.reshape(-1, slice_batch, plan.num_sliced)
                 vb = valid_local.reshape(-1, slice_batch)
-                out_shape = jax.eval_shape(lambda: contract(jnp.int32(0)))
+                out_shape = jax.eval_shape(
+                    lambda: contract(plan.slice_bits(0))
+                )
                 wshape = (-1,) + (1,) * len(out_shape.shape)
 
                 def body(acc, iv):
@@ -208,7 +213,7 @@ def legacy_contract_resumable(plan, arrays, chunk=4, hoist=None):
     hoisted = plan.contract_prologue(arrays) if hoist else []
     n_slices = 1 << plan.num_sliced
     out_shape = jax.eval_shape(
-        lambda: plan.contract_slice(list(arrays), jnp.int32(0))
+        lambda: plan.contract_slice(list(arrays), plan.slice_bits(0))
     )
     state = SliceRangeCheckpoint(
         n_slices, set(), np.zeros(out_shape.shape, out_shape.dtype)
@@ -225,7 +230,9 @@ def legacy_contract_resumable(plan, arrays, chunk=4, hoist=None):
     for s, e in state.missing(chunk):
         acc = None
         for sid in range(s, e):
-            r = contract(list(arrays), list(hoisted), jnp.int32(sid))
+            r = contract(
+                list(arrays), list(hoisted), jnp.asarray(plan.slice_bits(sid))
+            )
             acc = r if acc is None else acc + r
         state.partial = state.partial + np.asarray(acc)
         state.add_range(s, e)
@@ -255,7 +262,8 @@ def legacy_mh_batch(plan, arrays, sb, hoist):
 
         fn = plan._compiled.setdefault(ck, fn)
     return lambda ids, valid: fn(
-        list(arrays), list(hoisted), jnp.asarray(ids), jnp.asarray(valid)
+        list(arrays), list(hoisted), jnp.asarray(plan.slice_bits(ids)),
+        jnp.asarray(valid),
     )
 
 

@@ -65,7 +65,7 @@ def test_resume_across_chunk_sizes():
     dense = np.asarray(ContractionPlan(tree, 0).contract_all(arrays))
     n_slices = 1 << plan.num_sliced
     out_shape = jax.eval_shape(
-        lambda: plan.contract_slice(list(arrays), 0)
+        lambda: plan.contract_slice(list(arrays), plan.slice_bits(0))
     )
     state = SliceRangeCheckpoint(
         n_slices, set(), np.zeros(out_shape.shape, out_shape.dtype)

@@ -65,7 +65,7 @@ def _lowered(sess):
     sess.plan._compiled.clear()
     return sess._batch_fn().lower(
         list(sess.arrays), list(sess.hoisted()),
-        jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.ShapeDtypeStruct((2, sess.plan.num_sliced), jnp.int32),
         jax.ShapeDtypeStruct((2,), jnp.bool_),
     )
 
