@@ -90,6 +90,13 @@ class PlanReport:
     fidelity_tol: float = 0.0  # the XEB budget the plan was certified at
     precision_counts: dict | None = None  # GEMM-step counts per precision
     predicted_amp_error: float = 0.0  # forward-model relative amp error
+    # lane-dense store orders (lowering/layout.store_layout): modeled
+    # HBM bytes of one pass through the tree's permutes, for the chosen
+    # orders and the greedy ones, and the steps whose output is stored
+    # in the order its consumer asked for
+    permute_bytes: int = 0
+    permute_bytes_greedy: int = 0
+    lookahead_orders: int = 0
 
     def row(self) -> str:
         row = (
@@ -112,6 +119,13 @@ class PlanReport:
             if self.peak_bytes_hoisted != self.peak_bytes:
                 row += f"->{_fmt_bytes(self.peak_bytes_hoisted)}"
             row += f" slots={self.buffer_slots}"
+        if self.permute_bytes_greedy:
+            row += f" permute={_fmt_bytes(self.permute_bytes)}"
+            if self.lookahead_orders:
+                row += (
+                    f"[greedy={_fmt_bytes(self.permute_bytes_greedy)}"
+                    f" lookahead={self.lookahead_orders}]"
+                )
         if self.cache_hit:
             row += " cache=hit"
         if self.lowered_backends:
@@ -502,6 +516,9 @@ def _plan_fresh(
     # itemsize — no recompute needed)
     report.invariant_fraction = plan.invariant_fraction
     report.measured_overhead = plan.executed_overhead(report.hoist)
+    report.permute_bytes = plan.permute_bytes
+    report.permute_bytes_greedy = plan.permute_bytes_greedy
+    report.lookahead_orders = plan.lookahead_orders
     if plan.schedule is not None:
         # refiner feedback: the modeled time now reflects the refined
         # schedule that will actually execute (per-slice × slice count)

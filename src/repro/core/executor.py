@@ -356,21 +356,27 @@ class ContractionPlan:
         # lane-dense layout: every buffer is stored flat in a static index
         # order, and each step's operand orders and GEMM orientation are
         # fixed here (see repro.lowering.layout)
-        from ..lowering.layout import dense_step  # lazy: avoid cycle
+        from ..lowering.layout import store_layout  # lazy: avoid cycle
 
-        self.store_order: dict[int, tuple] = {
-            i: node_inds[i] for i in range(tn.num_tensors)
-        }
-        self.dense_steps = []
-        for k, st in enumerate(self.steps):
-            spec = self.schedule.specs[k] if self.schedule else None
-            ds = dense_step(
-                self.store_order[st.lhs], self.store_order[st.rhs],
-                st.inds_out, tn.size_of,
-                canonical=spec is not None and spec.backend == "pallas",
-            )
-            self.dense_steps.append(ds)
-            self.store_order[st.out] = ds.out_order
+        specs = (
+            self.schedule.specs if self.schedule else [None] * len(self.steps)
+        )
+        layout = store_layout(
+            [
+                (st.lhs, st.rhs, st.out,
+                 spec is not None and spec.backend == "pallas")
+                for st, spec in zip(self.steps, specs)
+            ],
+            node_inds, self.out_inds, tn.size_of, self.dtype.itemsize,
+        )
+        self.store_order: dict[int, tuple] = layout.store_order
+        self.dense_steps = list(layout.dense_steps)
+        # modeled HBM bytes of the lane-dense routes, one pass through
+        # the tree, for the chosen orders and the greedy ones, and the
+        # steps whose output is stored for its consumer
+        self.permute_bytes = layout.permute_bytes
+        self.permute_bytes_greedy = layout.permute_bytes_greedy
+        self.lookahead_orders = layout.lookahead_orders
         # memoized jitted executables (plan-lifetime — a cached plan
         # served twice skips retracing, not just re-planning)
         self._compiled: dict = {}

@@ -136,6 +136,10 @@ class ContractionSession:
         self.hoist = bool(h and plan.can_hoist)
         self._hoisted: list | None = None
         _metrics.set_gauge("engine.sliced_bits", plan.num_sliced)
+        _metrics.set_gauge("plan.permute_bytes", plan.permute_bytes)
+        _metrics.set_gauge("plan.permute_bytes_greedy",
+                           plan.permute_bytes_greedy)
+        _metrics.set_gauge("plan.lookahead_orders", plan.lookahead_orders)
 
     # ------------------------------------------------------------------
     @property

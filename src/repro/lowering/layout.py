@@ -18,7 +18,14 @@ buffers all have a minor dimension of at least :data:`LANE` elements:
     the orientation of the 3-D ``dot_general`` so that each operand's
     and the output's minor dimension is its larger free group.  The
     output is stored in the order the GEMM produces it, which costs no
-    transpose at all.
+    transpose at all;
+  * :func:`store_layout` chooses that order for the step that consumes
+    the output: the indices the consumer contracts may be gathered into
+    one block where the output's M and N groups meet, so the
+    consumer's permute moves a block.  A step keeps such an order where
+    it lowers the route bytes of its own permutes plus its consumer's,
+    and the plan keeps these orders only if its total route bytes come
+    out lower than with every step choosing alone.
 
 A general permutation needs three minor blocks' worth of indices to
 route densely, so a tensor below ``LANE**3`` elements routes with a
@@ -31,6 +38,7 @@ take the direct transpose.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Callable, Hashable, Sequence
 
@@ -185,8 +193,12 @@ class DenseStep:
     out_order: tuple
     sizes: tuple  # (index, size) pairs of every index the step touches
 
+    @functools.cached_property
+    def _size(self) -> dict:
+        return dict(self.sizes)
+
     def size_of(self, ix) -> int:
-        return dict(self.sizes)[ix]
+        return self._size[ix]
 
 
 def dense_step(
@@ -196,6 +208,7 @@ def dense_step(
     size_of: Callable[[Hashable], int],
     *,
     canonical: bool = False,
+    consumer_k: Sequence[Hashable] | None = None,
 ) -> DenseStep:
     """Choose the operand orders and GEMM orientation of one step.
 
@@ -207,7 +220,12 @@ def dense_step(
     group, and the output's is the larger of M and N.  ``canonical``
     pins the plain ``(B, M, K) @ (B, K, N) → (B, M, N)`` orientation —
     the 2-D Pallas kernel's form, which the refiner only picks when
-    every dimension is MXU-sized."""
+    every dimension is MXU-sized.
+
+    ``consumer_k`` names the indices the step that consumes the output
+    contracts.  Those of M and N then move to where the output's two
+    free groups meet, in ``consumer_k``'s order, so they are one block
+    of the stored output; the other indices keep their order."""
     a_order, b_order = tuple(a_order), tuple(b_order)
     sa, sb, so = set(a_order), set(b_order), set(out_inds)
     batch = tuple(ix for ix in a_order if ix in sb and ix in so)
@@ -228,6 +246,20 @@ def dense_step(
         a_km = M > K  # a stored (B, K, M): M is its minor group
         b_nk = K > N  # b stored (B, N, K): K is its minor group
         swap = N > M  # output (B, N, M): M is its minor group
+    if consumer_k is not None:
+        rank = {ix: i for i, ix in enumerate(consumer_k)}
+
+        def hinted(group):
+            return tuple(sorted((ix for ix in group if ix in rank),
+                                key=rank.__getitem__))
+
+        def rest(group):
+            return tuple(ix for ix in group if ix not in rank)
+
+        if swap:  # output batch + n + m
+            n, m = rest(n) + hinted(n), hinted(m) + rest(m)
+        else:  # output batch + m + n
+            m, n = rest(m) + hinted(m), hinted(n) + rest(n)
     a_gemm = batch + (k + m if a_km else m + k)
     b_gemm = batch + (n + k if b_nk else k + n)
     a_shape = (B, K, M) if a_km else (B, M, K)
@@ -245,4 +277,117 @@ def dense_step(
         a_order=a_order, b_order=b_order, a_gemm=a_gemm, b_gemm=b_gemm,
         a_shape=a_shape, b_shape=b_shape, dims=dims, swap=swap,
         out_order=out_order, sizes=sizes,
+    )
+
+
+def route_bytes(src, dst, size_of, itemsize: int) -> int:
+    """HBM bytes of the route from ``src`` to ``dst`` order: each
+    transpose of :func:`transpose_orders` reads and writes the whole
+    tensor."""
+    n = math.prod(size_of(a) for a in src)
+    return 2 * n * itemsize * len(transpose_orders(src, dst, size_of))
+
+
+def step_route_bytes(ds: DenseStep, itemsize: int) -> int:
+    """Route bytes of one step's two operand permutes."""
+    return (
+        route_bytes(ds.a_order, ds.a_gemm, ds.size_of, itemsize)
+        + route_bytes(ds.b_order, ds.b_gemm, ds.size_of, itemsize)
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class StoreLayout:
+    """Every step's :class:`DenseStep` and every node's storage order,
+    with the modeled route bytes of one pass through the tree (every
+    step's operand permutes and the root's permute into the output
+    order) for the chosen orders and for the greedy ones, and how many
+    steps store their output in the order their consumer asked for."""
+
+    dense_steps: tuple[DenseStep, ...]
+    store_order: dict
+    permute_bytes: int
+    permute_bytes_greedy: int
+    lookahead_orders: int
+
+
+def _layout(steps, tree_order, out_inds, size_of, itemsize, lookahead):
+    """Storage orders step by step.  With ``lookahead`` each output is
+    also tried with its consumer's contracted indices as one block
+    (:func:`dense_step`'s ``consumer_k``), and kept so where that lowers
+    the route bytes of the step's permutes plus its consumer's (an
+    operand the consumer takes from a later step is costed in its tree
+    order)."""
+    made = {v for _, _, v, _ in steps}
+    order = {v: tuple(o) for v, o in tree_order.items() if v not in made}
+    consumer = {}
+    for k, (l, r, _, _) in enumerate(steps):
+        consumer[l] = consumer[r] = k
+    dense, hinted = [], 0
+    for l, r, v, canon in steps:
+        ds = dense_step(order[l], order[r], tree_order[v], size_of,
+                        canonical=canon)
+        c = consumer.get(v)
+        if lookahead and c is not None:
+            cl, cr, cv, c_canon = steps[c]
+            w = cr if cl == v else cl
+            kc = (set(tree_order[cl]) & set(tree_order[cr])) - set(
+                tree_order[cv]
+            )
+
+            def cost(d):
+                at = {v: d.out_order, w: order.get(w, tree_order[w])}
+                cds = dense_step(at[cl], at[cr], tree_order[cv], size_of,
+                                 canonical=c_canon)
+                return step_route_bytes(d, itemsize) + step_route_bytes(
+                    cds, itemsize
+                )
+
+            # the K order the consumer takes from its other operand, and
+            # the order this step's operands hold the indices in
+            hints = (
+                tuple(ix for ix in order.get(w, tree_order[w]) if ix in kc),
+                tuple(ix for ix in dict.fromkeys(order[l] + order[r])
+                      if ix in kc),
+            )
+            base, best = ds, cost(ds)
+            for h in hints:
+                d = dense_step(order[l], order[r], tree_order[v], size_of,
+                               canonical=canon, consumer_k=h)
+                if d.out_order != ds.out_order and (dc := cost(d)) < best:
+                    ds, best = d, dc
+            hinted += ds is not base
+        order[v] = ds.out_order
+        dense.append(ds)
+    total = sum(step_route_bytes(d, itemsize) for d in dense)
+    if steps:
+        total += route_bytes(order[steps[-1][2]], out_inds, size_of,
+                             itemsize)
+    return tuple(dense), order, total, hinted
+
+
+def store_layout(
+    steps: Sequence[tuple[Hashable, Hashable, Hashable, bool]],
+    tree_order: dict,
+    out_inds: Sequence[Hashable],
+    size_of: Callable[[Hashable], int],
+    itemsize: int,
+) -> StoreLayout:
+    """Storage orders of a whole plan.
+
+    ``steps`` are ``(lhs, rhs, out, canonical)`` node triples in
+    execution order (``canonical`` as in :func:`dense_step`);
+    ``tree_order`` maps every node to its indices (leaves in the order
+    they are stored); ``out_inds`` is the root's final order.  Each step
+    writes its output for the step that consumes it (one step of
+    look-ahead, see :func:`_layout`).  Choices cascade, since a step's M
+    and N orders are read from its operands, so the look-ahead orders
+    are kept only where the whole plan's route bytes come out lower than
+    with each step choosing for itself; else the greedy orders stand."""
+    greedy = _layout(steps, tree_order, out_inds, size_of, itemsize, False)
+    ahead = _layout(steps, tree_order, out_inds, size_of, itemsize, True)
+    dense, order, total, hinted = ahead if ahead[2] < greedy[2] else greedy
+    return StoreLayout(
+        dense_steps=dense, store_order=order, permute_bytes=total,
+        permute_bytes_greedy=greedy[2], lookahead_orders=hinted,
     )

@@ -16,6 +16,7 @@ imports this file.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import itertools
 import re
 
@@ -29,7 +30,8 @@ from repro.core.api import plan_compiled
 from repro.core.executor import simplify_network
 from repro.engine.session import ContractionSession
 from repro.kernels import ops, tiled_matmul
-from repro.lowering import lower_step, refine_step
+from repro.lowering import gemm_form, lower_step, refine_step
+from repro.lowering.layout import transpose_orders
 from repro.lowering.refiner import BLOCK_CANDIDATES, VMEM_BUDGET_BYTES
 from repro.quantum.circuits import circuit_to_network, sycamore_like
 
@@ -223,3 +225,60 @@ def test_sharded_program_compiles_for_four_chips(topo, syc12):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
+
+
+def _copies_in(text: str, scope: str) -> int:
+    """Transposes of the compiled program inside ``scope``'s ``permute``
+    (on a TPU each is a layout-changing ``copy``)."""
+    return sum(
+        1 for line in text.splitlines()
+        if re.match(r"\s*(ROOT )?%\S+ = \S+ copy\(", line)
+        and f"/{scope}/permute/transpose" in line
+    )
+
+
+def test_step_pair_stores_for_its_consumer(one_chip):
+    """The 30-qubit, 16-cycle Sycamore plan of the benchmark's
+    ``syc30.amp``: step 143 writes step 168's 2^27-element ``a``.  With
+    each step choosing its store order alone, step 168 permutes it in
+    five transposes; stored for step 168, in three.  Compiled at the
+    real shapes, step 168's permute of ``a`` has fewer transposes and
+    the pair fewer temporary bytes."""
+    from conftest import greedy_layout
+
+    circ = sycamore_like(5, 6, 16)
+    tn, arrays = circuit_to_network(circ, bitstring="0" * circ.num_qubits)
+    tn, arrays = simplify_network(tn, arrays)
+    plan, _ = plan_compiled(tn, 26, dtype=arrays[0].dtype, backend="gemm",
+                            slicing_mode="peak", use_cache=False)
+    p, c = 143, 168
+    assert plan.steps[c].lhs == plan.steps[p].out
+    greedy, _, _ = greedy_layout(plan)
+    copies, temps = [], []
+    for dense in (greedy, plan.dense_steps):
+        dp = dense[p]
+        # step 168's b arrives in its GEMM order: every transpose left
+        # in step 168 is a's
+        dc = dataclasses.replace(dense[c], b_order=dense[c].b_gemm)
+        n = dc.a_shape[0] * dc.a_shape[1] * dc.a_shape[2]
+        assert n == 2 ** 27
+        routes = len(transpose_orders(dc.a_order, dc.a_gemm, dc.size_of))
+        assert routes == (5 if dense is greedy else 3)
+
+        def pair(a, b, y, dp=dp, dc=dc):
+            with jax.named_scope(f"step{p}"):
+                x = gemm_form.contract_flat(plan.schedule.specs[p], dp, a, b)
+            with jax.named_scope(f"step{c}"):
+                return gemm_form.contract_flat(plan.schedule.specs[c], dc,
+                                               x, y)
+
+        args = [
+            _sds((int(np.prod([dp.size_of(i) for i in o])),),
+                 jnp.complex64, one_chip)
+            for o in (dp.a_order, dp.b_order)
+        ] + [_sds((int(np.prod(dc.b_shape)),), jnp.complex64, one_chip)]
+        compiled = jax.jit(pair).lower(*args).compile()
+        copies.append(_copies_in(compiled.as_text(), f"step{c}"))
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+    assert 0 < copies[1] < copies[0]
+    assert temps[1] <= temps[0]
