@@ -17,6 +17,7 @@ The paper's contribution lives here:
 """
 
 from .api import (  # noqa: F401
+    PlanOptions,
     PlanReport,
     SimulationResult,
     draw_from_batch,

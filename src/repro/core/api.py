@@ -2,12 +2,11 @@
 merging → lowering → sliced JAX contraction.  This is the public API the
 examples and benchmarks drive.
 
-``backend="gemm"`` compiles the planned tree through
-:mod:`repro.lowering` into an explicit kernel schedule (Pallas tiled
-GEMMs + refined fallbacks); the default ``"einsum"`` keeps the oracle
-path.  Planned artifacts are memoized in the compiled-plan cache
+Every planner and lowering parameter is a field of :class:`PlanOptions`.
+The entry points take those fields as keyword arguments and resolve them
+once.  Planned artifacts are memoized in the compiled-plan cache
 (:data:`repro.lowering.cache.PLAN_CACHE`) keyed by the canonical network
-fingerprint + planner parameters, so repeated requests for the same
+fingerprint + the resolved options, so repeated requests for the same
 circuit family skip planning and retracing — pass ``use_cache=False``
 to force a fresh plan.
 """
@@ -23,6 +22,7 @@ import numpy as np
 from ..obs import trace as _trace
 from .contraction_tree import ContractionTree
 from .executor import (
+    BACKENDS,
     ContractionPlan,
     auto_slice_batch,
     default_backend,
@@ -31,6 +31,119 @@ from .executor import (
 )
 from .merging import modeled_tree_time
 from .tensor_network import popcount
+
+_SLICING_MODES = ("width", "peak")
+_OPTIMIZERS = ("oneshot", "anytime")
+# the anytime search's budgets: they shape a plan only under
+# optimize="anytime", so only there do they join the cache key
+_SEARCH_FIELDS = ("search_evals", "search_workers", "search_wall_s")
+
+
+@dataclasses.dataclass(frozen=True)
+class PlanOptions:
+    """Every planner and lowering parameter, declared once.
+
+    :func:`plan_contraction`, :func:`plan_compiled`,
+    :func:`simulate_amplitude`, :func:`open_amplitude_batch`,
+    :func:`sample_bitstrings` and :func:`open_session` take these fields
+    as keyword arguments; an unknown name raises :class:`TypeError`.
+    :meth:`resolve` fills the environment defaults and checks the values
+    before any planning.
+
+    The plan-cache key (:meth:`key`) holds every resolved field but two
+    kinds, so that knobs a plan ignores cannot split the cache: the
+    search budgets count only under ``optimize="anytime"``, and
+    ``fidelity_tol`` only away from ``precision="fp32"``.
+    """
+
+    #: ``"einsum"`` runs every node as ``jnp.einsum`` (the oracle path);
+    #: ``"gemm"`` lowers the tree through :mod:`repro.lowering` into an
+    #: explicit kernel schedule (Pallas tiled GEMMs + refined fallbacks).
+    #: ``None`` follows ``REPRO_BACKEND``, default einsum.
+    backend: str | None = None
+    #: slicer: ``"lifetime"`` (Alg. 1) or another
+    #: :func:`repro.core.slicing.find_slices` method
+    method: str = "lifetime"
+    #: branch exchange + tuningSliceFinder (Alg. 2), lifetime slicer only
+    tune: bool = True
+    #: branch merging under the GEMM F(M,N,K) surface (Sec. V)
+    merge: bool = True
+    #: restarts of the randomized greedy path search
+    repeats: int = 8
+    #: seed of the path search and the randomized slicers
+    seed: int = 0
+    #: ``"width"`` slices until every tensor fits ``2^target_dim``;
+    #: ``"peak"`` then re-judges the mask against the lifetime memory
+    #: plan's live-set peak (:func:`repro.core.slicing.refine_slices_for_peak`)
+    #: and drops the indices the true peak never needed.
+    slicing_mode: str = "width"
+    #: ``"oneshot"`` runs the staged pipeline, each stage once
+    #: (:func:`repro.optimize.oneshot_plan`).  ``"anytime"`` runs the
+    #: path–slice–memory co-optimizer (:func:`repro.optimize.plan_search`):
+    #: the slicer re-runs after every accepted tree move, candidates are
+    #: scored by hoist-aware executed FLOPs under the certified peak
+    #: budget, and the report carries the improvement trace.
+    optimize: str = "oneshot"
+    #: the anytime budgets: candidate evaluations, annealing workers and
+    #: an optional wall clock.  Stopping early still yields a plan no
+    #: worse than the one-shot seed; a wall-clock budget makes the plan
+    #: depend on the machine.
+    search_evals: int = 64
+    search_workers: int = 4
+    search_wall_s: float | None = None
+    #: certified live-set byte budget of peak slicing and the search;
+    #: ``None`` derives it from ``target_dim``
+    budget_bytes: int | None = None
+    #: ``"fp32"``; ``"auto"`` demotes MXU GEMM steps to bf16-input /
+    #: fp32-accumulate while the forward error model keeps the predicted
+    #: Linear-XEB loss within ``fidelity_tol``; ``"bf16"`` demotes every
+    #: eligible step.  ``None`` follows ``REPRO_PRECISION``, default fp32.
+    precision: str | None = None
+    #: the relative Linear-XEB loss ``"auto"`` may spend; ``None`` is
+    #: :data:`repro.lowering.precision.DEFAULT_FIDELITY_TOL`
+    fidelity_tol: float | None = None
+
+    def resolve(self) -> PlanOptions:
+        """These options with the environment defaults filled in, after
+        checking every field that names a mode."""
+        from ..lowering.precision import (  # lazy: avoid cycle
+            DEFAULT_FIDELITY_TOL,
+            PRECISION_MODES,
+            default_precision,
+        )
+
+        backend, precision, tol = (
+            self.backend, self.precision, self.fidelity_tol
+        )
+        opts = dataclasses.replace(
+            self,
+            backend=default_backend() if backend is None else backend,
+            precision=default_precision() if precision is None else precision,
+            fidelity_tol=DEFAULT_FIDELITY_TOL if tol is None else float(tol),
+        )
+        for name, allowed in (
+            ("backend", BACKENDS),
+            ("precision", PRECISION_MODES),
+            ("slicing_mode", _SLICING_MODES),
+            ("optimize", _OPTIMIZERS),
+        ):
+            value = getattr(opts, name)
+            if value not in allowed:
+                raise ValueError(f"{name} {value!r} not in {allowed}")
+        return opts
+
+    def key(self) -> tuple:
+        """The resolved options as they join the plan-cache key."""
+        ignored = set()
+        if self.optimize != "anytime":
+            ignored.update(_SEARCH_FIELDS)
+        if self.precision == "fp32":
+            ignored.add("fidelity_tol")
+        return tuple(
+            (f.name, getattr(self, f.name))
+            for f in dataclasses.fields(self)
+            if f.name not in ignored
+        )
 
 
 def _fmt_bytes(b: float) -> str:
@@ -166,76 +279,53 @@ def _telemetry_snapshot() -> dict:
     return obs.telemetry_summary()
 
 
+def _with_hoist(report: PlanReport, plan, hoist: bool | None) -> PlanReport:
+    """``report`` with its hoist fields describing the mode that will run:
+    ``hoist``, or ``REPRO_HOIST`` when ``None``.  The mode is chosen at
+    execution time, so it is read on every call and never cached."""
+    on = default_hoist() if hoist is None else bool(hoist)
+    return dataclasses.replace(
+        report, hoist=on, measured_overhead=plan.executed_overhead(on)
+    )
+
+
+def plan_contraction(tn, target_dim: int, itemsize: int = 8, **options):
+    """Full planning pipeline on a tensor network: returns ``(tree,
+    smask, report)``.  ``options`` are :class:`PlanOptions` fields;
+    ``itemsize`` is the element width the memory plan counts."""
+    return _plan_tree(
+        tn, target_dim, itemsize, PlanOptions(**options).resolve()
+    )
+
+
 @_trace.traced("plan.build", cat="plan")
-def plan_contraction(
-    tn,
-    target_dim: int,
-    method: str = "lifetime",
-    tune: bool = True,
-    merge: bool = True,
-    repeats: int = 8,
-    seed: int = 0,
-    slicing_mode: str = "width",
-    itemsize: int = 8,
-    optimize: str = "oneshot",
-    search_evals: int = 64,
-    search_workers: int = 4,
-    search_wall_s: float | None = None,
-    budget_bytes: int | None = None,
-    precision: str | None = None,
-    fidelity_tol: float | None = None,
-):
-    """Full planning pipeline on a tensor network.
-
-    ``slicing_mode="peak"`` re-judges the final slicing mask against the
-    lifetime-based memory plan's live-set peak instead of the width
-    proxy (see :func:`repro.core.slicing.refine_slices_for_peak`):
-    indices the true peak never needed are dropped, shrinking the
-    ``2^|S|`` subtask count at the same byte budget.
-
-    ``optimize="anytime"`` replaces the staged pipeline with the
-    path–slice–memory co-optimizer (:func:`repro.optimize.plan_search`):
-    the slicer is re-invoked in place after every accepted tree move and
-    candidates are scored by hoist-aware executed FLOPs under the
-    certified peak budget.  ``search_evals`` / ``search_wall_s`` are the
-    anytime budgets (stopping early always yields a plan no worse than
-    the one-shot seed); the returned report carries the improvement
-    trace in ``PlanReport.search_trace``."""
+def _plan_tree(tn, target_dim: int, itemsize: int, opts: PlanOptions):
     from ..optimize import oneshot_plan, plan_search
 
     t0 = time.perf_counter()
     search_trace = None
-    if optimize == "anytime":
+    if opts.optimize == "anytime":
         sr = plan_search(
-            tn,
-            target_dim,
-            budget_bytes=budget_bytes,
-            itemsize=itemsize,
-            num_workers=search_workers,
-            max_evals=search_evals,
-            wall_clock_s=search_wall_s,
-            seed=seed,
-            method=method,
-            tune=tune,
-            merge=merge,
-            repeats=repeats,
-            slicing_mode=slicing_mode,
-            precision=precision,
-            fidelity_tol=fidelity_tol,
+            tn, target_dim, budget_bytes=opts.budget_bytes,
+            itemsize=itemsize, num_workers=opts.search_workers,
+            max_evals=opts.search_evals, wall_clock_s=opts.search_wall_s,
+            seed=opts.seed, method=opts.method, tune=opts.tune,
+            merge=opts.merge, repeats=opts.repeats,
+            slicing_mode=opts.slicing_mode, precision=opts.precision,
+            fidelity_tol=opts.fidelity_tol,
         )
         tree, smask = sr.tree, sr.smask
         width0 = sr.width_before  # raw greedy seed width, as in oneshot
         search_trace = [dataclasses.asdict(t) for t in sr.trace]
-    elif optimize == "oneshot":
+    else:
         shot = oneshot_plan(
-            tn, target_dim, method=method, tune=tune, merge=merge,
-            repeats=repeats, seed=seed, slicing_mode=slicing_mode,
-            itemsize=itemsize, budget_bytes=budget_bytes,
-            precision=precision, fidelity_tol=fidelity_tol,
+            tn, target_dim, method=opts.method, tune=opts.tune,
+            merge=opts.merge, repeats=opts.repeats, seed=opts.seed,
+            slicing_mode=opts.slicing_mode, itemsize=itemsize,
+            budget_bytes=opts.budget_bytes, precision=opts.precision,
+            fidelity_tol=opts.fidelity_tol,
         )
         tree, smask, width0 = shot.tree, shot.smask, shot.width_before
-    else:
-        raise ValueError(f"unknown optimize {optimize!r}")
     wall = time.perf_counter() - t0
     naive_overhead = tree.slicing_overhead(smask)
     hoist_on = default_hoist()
@@ -269,8 +359,8 @@ def plan_contraction(
         peak_bytes=mem.peak_bytes,
         peak_bytes_hoisted=mem.peak_bytes_hoisted,
         buffer_slots=mem.buffer_slots,
-        optimize=optimize,
-        search_evals=sr.evaluations if optimize == "anytime" else 0,
+        optimize=opts.optimize,
+        search_evals=sr.evaluations if opts.optimize == "anytime" else 0,
         search_trace=search_trace,
     )
     return tree, smask, report
@@ -280,34 +370,23 @@ def plan_compiled(
     tn,
     target_dim: int,
     dtype=None,
-    backend: str | None = None,
-    method: str = "lifetime",
-    tune: bool = True,
-    merge: bool = True,
-    repeats: int = 8,
-    seed: int = 0,
+    *,
     use_cache: bool = True,
-    slicing_mode: str = "width",
-    optimize: str = "oneshot",
-    search_evals: int = 64,
-    search_workers: int = 4,
-    search_wall_s: float | None = None,
-    budget_bytes: int | None = None,
-    precision: str | None = None,
-    fidelity_tol: float | None = None,
     telemetry: bool | None = None,
+    **options,
 ) -> tuple[ContractionPlan, PlanReport]:
     """Plan + lower a network into an executable :class:`ContractionPlan`,
-    consulting the compiled-plan cache.
+    consulting the compiled-plan cache.  ``options`` are
+    :class:`PlanOptions` fields.
 
-    ``precision`` (``None`` follows ``REPRO_PRECISION``, default
-    ``"fp32"``) selects mixed-precision lowering: ``"auto"`` demotes MXU
-    GEMM steps to bf16-input/fp32-accumulate while the forward error
-    model keeps the predicted Linear-XEB fidelity loss within
-    ``fidelity_tol`` (``None`` → the 0.05 default); ``"bf16"`` forces
-    every eligible step.  The resolved mode and (for non-fp32 modes) the
-    tolerance join the plan fingerprint, so plans at different budgets
-    never alias; fp32 plans ignore the tolerance and share one entry.
+    The cache key is the canonical network fingerprint (structure +
+    dtype + open indices, invariant under index relabeling), the target
+    width and :meth:`PlanOptions.key`, so a hit returns the *identical*
+    plan object — its lowered schedule and memoized jitted executables
+    ride along, which is what makes a hit skip retracing, not just
+    planning.  The slicing mask ``S`` is part of the cached artifact (it
+    is a deterministic function of the key), and so is a search result
+    under ``optimize="anytime"``.
 
     ``telemetry=True`` forces span tracing + metrics on for this call
     (``False`` forces off, ``None`` follows ``REPRO_TRACE``); when
@@ -316,34 +395,10 @@ def plan_compiled(
     snapshot taken after planning.  The toggle never joins the plan
     fingerprint: traced and untraced calls share cache entries and
     produce bitwise-identical plans.
-
-    The cache key is the canonical network fingerprint (structure +
-    dtype + open indices, invariant under index relabeling) plus every
-    planner/lowering parameter, so a hit returns the *identical* plan
-    object — its lowered schedule and memoized jitted executables ride
-    along, which is what makes a hit skip retracing, not just planning.
-    The slicing mask ``S`` is part of the cached artifact (it is a
-    deterministic function of the key).
-
-    ``optimize="anytime"`` plans through the co-optimizer
-    (:func:`repro.optimize.plan_search`); the search parameters join the
-    fingerprint, so a search *result* is cache-addressable — repeated
-    requests for the same circuit family at the same budgets reuse the
-    searched plan without re-running the search.  A wall-clock budget
-    (``search_wall_s``) makes the searched plan machine-dependent, so
-    such plans are still cached but only deterministic across processes
-    when ``search_wall_s=None``.
     """
+    opts = PlanOptions(**options).resolve()
     with _trace.enabled_scope(telemetry):
-        plan, report = _plan_compiled(
-            tn, target_dim, dtype=dtype, backend=backend, method=method,
-            tune=tune, merge=merge, repeats=repeats, seed=seed,
-            use_cache=use_cache, slicing_mode=slicing_mode,
-            optimize=optimize, search_evals=search_evals,
-            search_workers=search_workers, search_wall_s=search_wall_s,
-            budget_bytes=budget_bytes, precision=precision,
-            fidelity_tol=fidelity_tol,
-        )
+        plan, report = _plan_compiled(tn, target_dim, dtype, opts, use_cache)
         if _trace.enabled():
             report = dataclasses.replace(
                 report, telemetry=_telemetry_snapshot()
@@ -352,80 +407,21 @@ def plan_compiled(
 
 
 def _plan_compiled(
-    tn,
-    target_dim: int,
-    dtype=None,
-    backend: str | None = None,
-    method: str = "lifetime",
-    tune: bool = True,
-    merge: bool = True,
-    repeats: int = 8,
-    seed: int = 0,
-    use_cache: bool = True,
-    slicing_mode: str = "width",
-    optimize: str = "oneshot",
-    search_evals: int = 64,
-    search_workers: int = 4,
-    search_wall_s: float | None = None,
-    budget_bytes: int | None = None,
-    precision: str | None = None,
-    fidelity_tol: float | None = None,
+    tn, target_dim: int, dtype, opts: PlanOptions, use_cache: bool
 ) -> tuple[ContractionPlan, PlanReport]:
     from ..lowering.cache import PLAN_CACHE, PlanEntry, network_fingerprint
-    from ..lowering.precision import (
-        DEFAULT_FIDELITY_TOL,
-        PRECISION_MODES,
-        default_precision,
-    )
     import jax.numpy as jnp
 
-    backend = backend if backend is not None else default_backend()
     dtype = jnp.dtype(dtype if dtype is not None else jnp.complex64)
-    precision_mode = precision if precision is not None else default_precision()
-    if precision_mode not in PRECISION_MODES:
-        raise ValueError(
-            f"precision {precision_mode!r} not in {PRECISION_MODES}"
-        )
-    tol = DEFAULT_FIDELITY_TOL if fidelity_tol is None else float(fidelity_tol)
     t0 = time.perf_counter()
-
-    def _build() -> PlanEntry:
-        plan, report = _plan_fresh(
-            tn, target_dim, dtype=dtype, backend=backend, method=method,
-            tune=tune, merge=merge, repeats=repeats, seed=seed,
-            slicing_mode=slicing_mode, optimize=optimize,
-            search_evals=search_evals, search_workers=search_workers,
-            search_wall_s=search_wall_s, budget_bytes=budget_bytes,
-            precision_mode=precision_mode, tol=tol, t0=t0,
-        )
-        return PlanEntry(plan, report)
-
     if not use_cache:
-        ent = _build()
-        return ent.plan, ent.report
-    # search params only shape the plan under optimize="anytime" —
-    # keep them out of the oneshot key so ignored knobs cannot
-    # cause spurious cache misses
-    search_key = (
-        (search_evals, search_workers, search_wall_s)
-        if optimize == "anytime"
-        else ()
-    )
-    # the resolved precision mode always joins the key; the fidelity
-    # tolerance only matters off fp32, so fp32 plans at different
-    # tolerances share one entry instead of fragmenting the cache
-    key = network_fingerprint(
-        tn,
-        dtype,
-        extra=(backend, target_dim, method, tune, merge, repeats, seed,
-               slicing_mode, optimize, budget_bytes, search_key,
-               precision_mode,
-               tol if precision_mode != "fp32" else None),
-    )
+        plan, report = _plan_fresh(tn, target_dim, dtype, opts, t0)
+        return plan, _with_hoist(report, plan, None)
+    key = network_fingerprint(tn, dtype, extra=(target_dim, opts.key()))
     fresh: list[PlanEntry] = []
 
     def _factory() -> PlanEntry:
-        ent = _build()
+        ent = PlanEntry(*_plan_fresh(tn, target_dim, dtype, opts, t0))
         fresh.append(ent)
         return ent
 
@@ -435,31 +431,10 @@ def _plan_compiled(
     # plan and the loser overwrite the winner's jit-warmed plan is gone
     ent = PLAN_CACHE.single_flight(key, _factory)
     stats = PLAN_CACHE.stats()
-    if fresh:
-        # this thread planned: report the fresh-planning run
-        return ent.plan, dataclasses.replace(
-            ent.report,
-            cache_hits=stats["hits"],
-            cache_misses=stats["misses"],
-            search_trace=(
-                [dict(t) for t in ent.report.search_trace]
-                if ent.report.search_trace is not None
-                else None
-            ),
-        )
-    # cache hit (or waited on another thread's in-flight planning).
-    # hoist mode is an execution-time choice (REPRO_HOIST may have
-    # changed since the plan was cached): re-derive it so the
-    # report describes the mode that will actually run
-    hoist_on = default_hoist()
     report = dataclasses.replace(
         ent.report,
-        plan_wall_s=time.perf_counter() - t0,
-        cache_hit=True,
         cache_hits=stats["hits"],
         cache_misses=stats["misses"],
-        hoist=hoist_on,
-        measured_overhead=ent.plan.executed_overhead(hoist_on),
         # copy the one mutable field so a caller mutating its
         # report can never corrupt the cached template
         search_trace=(
@@ -468,43 +443,24 @@ def _plan_compiled(
             else None
         ),
     )
-    return ent.plan, report
+    if not fresh:
+        # cache hit (or waited on another thread's in-flight planning)
+        report = dataclasses.replace(
+            report, plan_wall_s=time.perf_counter() - t0, cache_hit=True
+        )
+    return ent.plan, _with_hoist(report, ent.plan, None)
 
 
 def _plan_fresh(
-    tn,
-    target_dim: int,
-    dtype,
-    backend: str,
-    method: str,
-    tune: bool,
-    merge: bool,
-    repeats: int,
-    seed: int,
-    slicing_mode: str,
-    optimize: str,
-    search_evals: int,
-    search_workers: int,
-    search_wall_s: float | None,
-    budget_bytes: int | None,
-    precision_mode: str,
-    tol: float,
-    t0: float,
+    tn, target_dim: int, dtype, opts: PlanOptions, t0: float
 ) -> tuple[ContractionPlan, PlanReport]:
     """One fresh planning + lowering run (no cache consultation) — the
     body a :meth:`PlanCache.single_flight` leader executes."""
-    tree, smask, report = plan_contraction(
-        tn, target_dim, method=method, tune=tune, merge=merge,
-        repeats=repeats, seed=seed, slicing_mode=slicing_mode,
-        itemsize=dtype.itemsize, optimize=optimize,
-        search_evals=search_evals, search_workers=search_workers,
-        search_wall_s=search_wall_s, budget_bytes=budget_bytes,
-        precision=precision_mode, fidelity_tol=tol,
-    )
-    with _trace.span("plan.lower", cat="plan", backend=backend):
+    tree, smask, report = _plan_tree(tn, target_dim, dtype.itemsize, opts)
+    with _trace.span("plan.lower", cat="plan", backend=opts.backend):
         plan = ContractionPlan(
-            tree, smask, backend=backend, dtype=dtype,
-            precision=precision_mode, fidelity_tol=tol,
+            tree, smask, backend=opts.backend, dtype=dtype,
+            precision=opts.precision, fidelity_tol=opts.fidelity_tol,
         )
     report.backend = plan.backend
     report.precision = plan.precision_mode
@@ -512,10 +468,9 @@ def _plan_fresh(
         report.fidelity_tol = plan.fidelity_tol
     # re-derive the two-phase metrics from the plan's own partition so the
     # report always describes the object that will execute (the memory
-    # fields were already computed by plan_contraction with this dtype's
+    # fields were already computed by _plan_tree with this dtype's
     # itemsize — no recompute needed)
     report.invariant_fraction = plan.invariant_fraction
-    report.measured_overhead = plan.executed_overhead(report.hoist)
     report.permute_bytes = plan.permute_bytes
     report.permute_bytes_greedy = plan.permute_bytes_greedy
     report.lookahead_orders = plan.lookahead_orders
@@ -552,35 +507,20 @@ def simulate_amplitude(
     circuit,
     bitstring: str,
     target_dim: int = 20,
-    method: str = "lifetime",
-    tune: bool = True,
-    merge: bool = True,
-    seed: int = 0,
+    *,
     slice_batch: int = 4,
-    backend: str | None = None,
-    use_cache: bool = True,
     hoist: bool | None = None,
-    slicing_mode: str = "width",
-    optimize: str = "oneshot",
-    search_evals: int = 64,
-    search_workers: int = 4,
-    search_wall_s: float | None = None,
-    budget_bytes: int | None = None,
-    precision: str | None = None,
-    fidelity_tol: float | None = None,
+    use_cache: bool = True,
     telemetry: bool | None = None,
+    **options,
 ) -> SimulationResult:
     """Amplitude <bitstring|C|0…0> via the full planner + executor stack.
 
-    ``backend="gemm"`` executes the lowered kernel schedule (Pallas
-    tiled GEMMs + refined fallbacks); the default follows
-    ``REPRO_BACKEND`` / ``"einsum"``.  ``hoist`` selects two-phase
-    (slice-invariant hoisted) execution, default ``REPRO_HOIST``.  Two
-    calls on the same circuit share one compiled plan via the plan cache
-    (different bitstrings change leaf *values*, never network structure).
-    ``optimize="anytime"`` plans via the path–slice co-optimizer
-    (:func:`repro.optimize.plan_search`) with ``search_evals``
-    evaluations over ``search_workers`` annealing workers.
+    ``options`` are :class:`PlanOptions` fields.  ``hoist`` selects
+    two-phase (slice-invariant hoisted) execution, default
+    ``REPRO_HOIST``.  Two calls on the same circuit share one compiled
+    plan via the plan cache (different bitstrings change leaf *values*,
+    never network structure).
     """
     from ..quantum.circuits import circuit_to_network  # avoid import cycle
 
@@ -588,32 +528,13 @@ def simulate_amplitude(
         tn, arrays = circuit_to_network(circuit, bitstring=bitstring)
         tn, arrays = simplify_network(tn, arrays)
         plan, report = plan_compiled(
-            tn,
-            target_dim,
+            tn, target_dim,
             dtype=arrays[0].dtype if arrays else None,
-            backend=backend,
-            method=method,
-            tune=tune,
-            merge=merge,
-            seed=seed,
-            use_cache=use_cache,
-            slicing_mode=slicing_mode,
-            optimize=optimize,
-            search_evals=search_evals,
-            search_workers=search_workers,
-            search_wall_s=search_wall_s,
-            budget_bytes=budget_bytes,
-            precision=precision,
-            fidelity_tol=fidelity_tol,
+            use_cache=use_cache, **options,
         )
         sb = auto_slice_batch(slice_batch, 1 << plan.num_sliced)
         value = plan.contract_all(arrays, slice_batch=sb, hoist=hoist)
-        if hoist is not None:
-            report = dataclasses.replace(
-                report,
-                hoist=bool(hoist),
-                measured_overhead=plan.executed_overhead(bool(hoist)),
-            )
+        report = _with_hoist(report, plan, hoist)
         if _trace.enabled():
             report = dataclasses.replace(
                 report, telemetry=_telemetry_snapshot()
@@ -629,26 +550,15 @@ def sample_bitstrings(
     open_qubits=None,
     base_bitstring: str | None = None,
     target_dim: int = 20,
-    method: str = "lifetime",
-    tune: bool = True,
-    merge: bool = True,
-    seed: int = 0,
+    *,
     slice_batch: int = 4,
     sampler: str = "frequency",
     mesh=None,
     axis_names: tuple[str, ...] = ("data",),
-    backend: str | None = None,
-    use_cache: bool = True,
     hoist: bool | None = None,
-    slicing_mode: str = "width",
-    optimize: str = "oneshot",
-    search_evals: int = 64,
-    search_workers: int = 4,
-    search_wall_s: float | None = None,
-    budget_bytes: int | None = None,
-    precision: str | None = None,
-    fidelity_tol: float | None = None,
+    use_cache: bool = True,
     telemetry: bool | None = None,
+    **options,
 ):
     """Draw correlated bitstring samples from one batched contraction —
     the paper's flagship workload (Sec. VI: 1M correlated Sycamore samples).
@@ -661,10 +571,10 @@ def sample_bitstrings(
     unbiased accept/reject, or 'topk' — heaviest outputs), and the sample
     set is scored with Linear XEB.
 
-    Pass a jax ``mesh`` to shard the slice ids over ``axis_names``
-    (shard_map + one psum); the open-batch axes are replicated so every
-    device returns the full batch.  ``backend="gemm"`` lowers the stem
-    to the refined kernel schedule (see :mod:`repro.lowering`) and the
+    ``options`` are :class:`PlanOptions` fields; ``seed`` seeds the
+    sampler as well as the planner.  Pass a jax ``mesh`` to shard the
+    slice ids over ``axis_names`` (shard_map + one psum); the open-batch
+    axes are replicated so every device returns the full batch.  The
     compiled plan is cached per circuit family like
     :func:`simulate_amplitude`.  Under two-phase execution (``hoist``,
     default ``REPRO_HOIST``) repeated sampler calls on the same batch
@@ -691,31 +601,13 @@ def sample_bitstrings(
 
     with _trace.enabled_scope(telemetry):
         batch, report = open_amplitude_batch(
-            circuit,
-            open_qubits=open_qubits,
-            base_bitstring=base_bitstring,
-            target_dim=target_dim,
-            method=method,
-            tune=tune,
-            merge=merge,
-            seed=seed,
-            slice_batch=slice_batch,
-            mesh=mesh,
-            axis_names=axis_names,
-            backend=backend,
-            use_cache=use_cache,
-            hoist=hoist,
-            slicing_mode=slicing_mode,
-            optimize=optimize,
-            search_evals=search_evals,
-            search_workers=search_workers,
-            search_wall_s=search_wall_s,
-            budget_bytes=budget_bytes,
-            precision=precision,
-            fidelity_tol=fidelity_tol,
+            circuit, open_qubits, base_bitstring, target_dim,
+            slice_batch=slice_batch, mesh=mesh, axis_names=axis_names,
+            hoist=hoist, use_cache=use_cache, **options,
         )
         res = draw_from_batch(
-            batch, num_samples, sampler=sampler, seed=seed
+            batch, num_samples, sampler=sampler,
+            seed=options.get("seed", PlanOptions.seed),
         )
         if _trace.enabled():
             report = dataclasses.replace(
@@ -730,24 +622,13 @@ def open_amplitude_batch(
     open_qubits=None,
     base_bitstring: str | None = None,
     target_dim: int = 20,
-    method: str = "lifetime",
-    tune: bool = True,
-    merge: bool = True,
-    seed: int = 0,
+    *,
     slice_batch: int = 4,
     mesh=None,
     axis_names: tuple[str, ...] = ("data",),
-    backend: str | None = None,
-    use_cache: bool = True,
     hoist: bool | None = None,
-    slicing_mode: str = "width",
-    optimize: str = "oneshot",
-    search_evals: int = 64,
-    search_workers: int = 4,
-    search_wall_s: float | None = None,
-    budget_bytes: int | None = None,
-    precision: str | None = None,
-    fidelity_tol: float | None = None,
+    use_cache: bool = True,
+    **options,
 ):
     """Contract one open-qubit batch: the planning + execution half of
     :func:`sample_bitstrings`, without drawing any samples.
@@ -759,7 +640,7 @@ def open_amplitude_batch(
     requests (read at their flat batch indices) or feeds any number of
     per-tenant :func:`draw_from_batch` calls.  Defaults mirror
     :func:`sample_bitstrings` (open the last ``min(6, n)`` qubits,
-    all-zeros base)."""
+    all-zeros base); ``options`` are :class:`PlanOptions` fields."""
     from ..sampling import AmplitudeBatch, batch as batch_mod
 
     n = circuit.num_qubits
@@ -781,34 +662,15 @@ def open_amplitude_batch(
     )
     # open indices cannot be sliced: the width floor is the batch rank
     plan, report = plan_compiled(
-        tn,
-        max(target_dim, len(open_qubits) + 1),
+        tn, max(target_dim, len(open_qubits) + 1),
         dtype=arrays[0].dtype if arrays else None,
-        backend=backend,
-        method=method,
-        tune=tune,
-        merge=merge,
-        seed=seed,
-        use_cache=use_cache,
-        slicing_mode=slicing_mode,
-        optimize=optimize,
-        search_evals=search_evals,
-        search_workers=search_workers,
-        search_wall_s=search_wall_s,
-        budget_bytes=budget_bytes,
-        precision=precision,
-        fidelity_tol=fidelity_tol,
+        use_cache=use_cache, **options,
     )
     amps = batch_mod.contract_amplitude_batch(
         plan, arrays, slice_batch=slice_batch, mesh=mesh,
         axis_names=axis_names, hoist=hoist,
     )
-    if hoist is not None:
-        report = dataclasses.replace(
-            report,
-            hoist=bool(hoist),
-            measured_overhead=plan.executed_overhead(bool(hoist)),
-        )
+    report = _with_hoist(report, plan, hoist)
     return AmplitudeBatch(amps, open_qubits, base_bitstring, n), report
 
 
@@ -852,10 +714,10 @@ def open_session(
     circuit,
     bitstring: str,
     target_dim: int = 20,
+    *,
     hoist: bool | None = None,
-    backend: str | None = None,
     use_cache: bool = True,
-    **plan_kwargs,
+    **options,
 ):
     """Plan a circuit amplitude and return a live
     :class:`~repro.engine.session.ContractionSession` plus its report.
@@ -865,18 +727,16 @@ def open_session(
     resolved, ready for ``run_slice`` / ``run_slices`` / ``run_all``.
     Callers that want to schedule slice execution themselves (custom
     drivers, the serving engine, incremental/resumable loops) start
-    here instead of :func:`simulate_amplitude`."""
+    here instead of :func:`simulate_amplitude`.  ``options`` are
+    :class:`PlanOptions` fields."""
     from ..engine.session import ContractionSession
     from ..quantum.circuits import circuit_to_network  # avoid import cycle
 
     tn, arrays = circuit_to_network(circuit, bitstring=bitstring)
     tn, arrays = simplify_network(tn, arrays)
     plan, report = plan_compiled(
-        tn,
-        target_dim,
+        tn, target_dim,
         dtype=arrays[0].dtype if arrays else None,
-        backend=backend,
-        use_cache=use_cache,
-        **plan_kwargs,
+        use_cache=use_cache, **options,
     )
     return ContractionSession(plan, arrays, hoist=hoist), report

@@ -94,9 +94,10 @@ class ServerOverloaded(RuntimeError):
 
 @dataclasses.dataclass
 class AmplitudeRequest:
-    """One amplitude <bitstring|C|0…0>.  ``plan_kwargs`` are forwarded to
-    the planner (backend/precision/optimize…) and join the family key —
-    requests planned differently never coalesce."""
+    """One amplitude <bitstring|C|0…0>.  ``plan_kwargs`` are
+    :class:`~repro.core.api.PlanOptions` fields, forwarded to the planner;
+    they join the family key — requests planned differently never
+    coalesce."""
 
     circuit: object
     bitstring: str

@@ -441,6 +441,24 @@ def test_plan_cache_hit_miss():
     r4 = simulate_amplitude(c, bs1, target_dim=4, backend="gemm",
                             use_cache=False)
     assert not r4.report.cache_hit
+    # search budgets only shape anytime plans: under oneshot they are
+    # left out of the key, so they cannot split the cache ...
+    r5 = simulate_amplitude(c, bs1, target_dim=4, backend="gemm",
+                            search_evals=3, search_workers=1)
+    assert r5.report.cache_hit and r5.plan is r1.plan
+    # ... while under anytime they separate plans
+    ra = simulate_amplitude(c, bs1, target_dim=4, backend="gemm",
+                            optimize="anytime", search_evals=3,
+                            search_workers=1)
+    rb = simulate_amplitude(c, bs1, target_dim=4, backend="gemm",
+                            optimize="anytime", search_evals=4,
+                            search_workers=1)
+    assert not ra.report.cache_hit and not rb.report.cache_hit
+    assert rb.plan is not ra.plan
+    # every other option joins the key
+    r6 = simulate_amplitude(c, bs1, target_dim=4, backend="gemm",
+                            repeats=3)
+    assert not r6.report.cache_hit
 
 
 def test_plan_cache_lru_eviction():

@@ -294,6 +294,10 @@ def test_plan_cache_separates_precision(monkeypatch):
     pf2, rf2 = plan_compiled(tn, 7, backend="gemm", precision="fp32",
                              fidelity_tol=0.1)
     assert pf2 is pf1 and rf2.cache_hit
+    # the unset mode resolves to fp32 (REPRO_PRECISION unset) and shares
+    # that entry at any tolerance
+    pd, rd = plan_compiled(tn, 7, backend="gemm", fidelity_tol=0.2)
+    assert pd is pf1 and rd.cache_hit
 
 
 # ----------------------------------------------------------------------

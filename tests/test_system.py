@@ -67,3 +67,93 @@ def test_planner_improves_over_greedy_on_stemmy_network():
     )
     assert rep_life.slicing_overhead <= rep_greedy.slicing_overhead * 1.5
     assert popcount(s_life) <= popcount(s_greedy) + 1
+
+
+# ---------------------------------------------------------------------
+# planning options: one PlanOptions value behind every entry point
+# ---------------------------------------------------------------------
+def _entry_calls(circ):
+    """Each public entry point, called on ``circ`` with extra options."""
+    from repro.core import api
+
+    n = circ.num_qubits
+    bs = "0" * n
+    tn, arrays = circuit_to_network(circ, bitstring=bs)
+    tn, _ = simplify_network(tn, arrays)
+    oq = (n - 2, n - 1)
+    return {
+        "plan_contraction": lambda **o: api.plan_contraction(tn, 4, **o),
+        "plan_compiled": lambda **o: api.plan_compiled(tn, 4, **o),
+        "simulate_amplitude": lambda **o: api.simulate_amplitude(
+            circ, bs, target_dim=4, **o
+        ),
+        "open_amplitude_batch": lambda **o: api.open_amplitude_batch(
+            circ, open_qubits=oq, target_dim=4, **o
+        ),
+        "sample_bitstrings": lambda **o: api.sample_bitstrings(
+            circ, num_samples=8, open_qubits=oq, target_dim=4, **o
+        ),
+        "open_session": lambda **o: api.open_session(
+            circ, bs, target_dim=4, **o
+        ),
+    }
+
+
+def _spy_planners(monkeypatch):
+    """Record the options each planner call receives."""
+    import repro.optimize as optimize
+
+    calls = []
+
+    def spy(fn):
+        def wrapper(*args, **kwargs):
+            calls.append(kwargs)
+            return fn(*args, **kwargs)
+
+        return wrapper
+
+    monkeypatch.setattr(optimize, "oneshot_plan", spy(optimize.oneshot_plan))
+    monkeypatch.setattr(optimize, "plan_search", spy(optimize.plan_search))
+    return calls
+
+
+ENTRY_POINTS = (
+    "plan_contraction", "plan_compiled", "simulate_amplitude",
+    "open_amplitude_batch", "sample_bitstrings", "open_session",
+)
+
+
+@pytest.mark.parametrize("entry", ENTRY_POINTS)
+def test_plan_options_reach_the_planner(entry, monkeypatch):
+    """Every entry point forwards every planning option: non-default
+    ``repeats`` and ``slicing_mode`` arrive at the one-shot planner."""
+    from repro.lowering.cache import PLAN_CACHE
+
+    PLAN_CACHE.clear()
+    calls = _spy_planners(monkeypatch)
+    _entry_calls(random_1d_circuit(8, 5, seed=3))[entry](
+        repeats=3, slicing_mode="peak"
+    )
+    assert len(calls) == 1
+    assert calls[0]["repeats"] == 3
+    assert calls[0]["slicing_mode"] == "peak"
+
+
+@pytest.mark.parametrize(
+    "bad, exc",
+    [
+        ({"repeat": 3}, TypeError),
+        ({"slicing_mode": "depth"}, ValueError),
+        ({"optimize": "greedy"}, ValueError),
+        ({"backend": "pallas"}, ValueError),
+    ],
+)
+def test_bad_plan_option_fails_before_planning(bad, exc, monkeypatch):
+    """An unknown option name, or a mode no planner has, raises naming
+    the offender at every entry point before any planner runs."""
+    calls = _spy_planners(monkeypatch)
+    (name,) = bad
+    for entry, call in _entry_calls(random_1d_circuit(6, 3, seed=1)).items():
+        with pytest.raises(exc, match=name):
+            call(**bad)
+        assert calls == [], entry
