@@ -583,7 +583,9 @@ class ContractionPlan:
             self._run_steps(env, self.prologue_idx, "prologue")
             return [env[v] for v in self.hoisted_nodes]
 
-    def contract_prologue(self, arrays, use_cache: bool = True):
+    def contract_prologue(
+        self, arrays, use_cache: bool = True, cache_key=None
+    ):
         """Materialize the slice-invariant prologue once.
 
         The result is memoized two ways: the jitted program on the plan
@@ -593,9 +595,12 @@ class ContractionPlan:
         buffer identity — no device→host transfer on the hot path; the
         key's keep-alive references ride with the cache entry so an id
         can never be recycled while its entry is live.  Host (numpy)
-        leaves fall back to value hashing.  Set
-        ``REPRO_HOIST_CACHE_SIZE=0`` or ``use_cache=False`` to skip both
-        the key and the cache.
+        leaves fall back to value hashing.  ``cache_key``, a
+        ``leaf_key(leaves, self.prologue_leaves)`` taken earlier from
+        leaves equal to ``arrays`` (a session takes it from its host
+        leaves when it opens), is used in place of keying ``arrays``
+        again.  Set ``REPRO_HOIST_CACHE_SIZE=0`` or ``use_cache=False``
+        to skip both the key and the cache.
         """
         if not self.can_hoist:
             return []
@@ -616,9 +621,9 @@ class ContractionPlan:
             return out
 
         if use_cache and self._hoist_cache.maxsize > 0:
-            from ..lowering.cache import leaf_key  # lazy: cycle
-
-            key, keepalive = leaf_key(arrays, self.prologue_leaves)
+            if cache_key is None:
+                cache_key = self._leaf_key(arrays)
+            key, keepalive = cache_key
             # single-flight: concurrent sessions over the same leaves
             # (serving tenants on one family) materialize the prologue
             # once — the waiters get the leader's buffers, and the
@@ -630,8 +635,13 @@ class ContractionPlan:
             )[0]
         return compute()
 
+    def _leaf_key(self, arrays) -> tuple[str, tuple]:
+        from ..lowering.cache import leaf_key  # lazy: cycle
+
+        return leaf_key(arrays, self.prologue_leaves)
+
     def contract_prologue_replicated(
-        self, arrays, mesh, use_cache: bool = True
+        self, arrays, mesh, use_cache: bool = True, cache_key=None
     ):
         """Prologue buffers device-put replicated over ``mesh`` — the
         form ``contract_sharded`` captures into its shard_map worker.
@@ -641,15 +651,19 @@ class ContractionPlan:
         sharded calls on a plan-cache hit reuse the already-broadcast
         buffers instead of re-issuing the device_put every invocation
         (``exec.hoist_replicated_reuse`` counts the skips,
-        ``exec.hoist_replicated_put`` the actual broadcasts)."""
+        ``exec.hoist_replicated_put`` the actual broadcasts).
+        ``cache_key`` is as in :meth:`contract_prologue`."""
         if not self.can_hoist:
             return []
-        out = self.contract_prologue(arrays, use_cache=use_cache)
+        cached = use_cache and self._hoist_cache.maxsize > 0
+        if cached and cache_key is None:
+            cache_key = self._leaf_key(arrays)
+        out = self.contract_prologue(
+            arrays, use_cache=use_cache, cache_key=cache_key
+        )
         entry = key = None
-        if use_cache and self._hoist_cache.maxsize > 0:
-            from ..lowering.cache import leaf_key  # lazy: cycle
-
-            key, _ = leaf_key(arrays, self.prologue_leaves)
+        if cached:
+            key = cache_key[0]
             entry = self._hoist_cache.get(key)
             if entry is not None and len(entry) > 2:
                 placed = entry[2].get(mesh)

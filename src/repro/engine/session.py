@@ -99,6 +99,20 @@ def record_execution(plan, executed: int, padded: int, hoist: bool) -> None:
         )
 
 
+def put_leaves(arrays: list, sharding=None) -> list:
+    """Leaf arrays on the device, as one ``jax.device_put`` of the list
+    (on the default device, uncommitted, when ``sharding`` is None).
+
+    Traced as ``engine.leaves_put``; counts the arrays in
+    ``engine.leaf_puts`` and their bytes in ``engine.leaf_put_bytes``."""
+    with _trace.span("engine.leaves_put", cat="engine", leaves=len(arrays)):
+        placed = jax.device_put(arrays, sharding)
+        _trace.sync(placed)
+    _metrics.inc("engine.leaf_puts", len(placed))
+    _metrics.inc("engine.leaf_put_bytes", sum(int(a.nbytes) for a in placed))
+    return placed
+
+
 class ContractionSession:
     """A compiled plan bound to leaf arrays, ready to execute slices.
 
@@ -108,6 +122,12 @@ class ContractionSession:
     prologue lazily on first use, through the plan's leaf-keyed
     HoistCache so repeated sessions over the same leaves (sampler calls,
     serving tenants) skip it entirely.
+
+    The leaves go to the device once, when the session opens
+    (:func:`put_leaves`; ``arrays`` holds the device copies), so a call
+    sends only its ids' bits and the validity mask.  The prologue's
+    HoistCache key is taken from the host leaves at the same time: by
+    value, as the device copies would key by identity only.
 
     Strategies:
 
@@ -131,10 +151,13 @@ class ContractionSession:
         from ..core.executor import default_hoist  # lazy: avoid cycle
 
         self.plan = plan
-        self.arrays = list(arrays)
+        host = list(arrays)
         h = default_hoist() if hoist is None else bool(hoist)
         self.hoist = bool(h and plan.can_hoist)
+        self._prologue_key = plan._leaf_key(host) if self.hoist else None
+        self.arrays = put_leaves(host)
         self._hoisted: list | None = None
+        self._replicated: dict = {}  # mesh -> leaves replicated over it
         _metrics.set_gauge("engine.sliced_bits", plan.num_sliced)
         _metrics.set_gauge("plan.permute_bytes", plan.permute_bytes)
         _metrics.set_gauge("plan.permute_bytes_greedy",
@@ -153,7 +176,9 @@ class ContractionSession:
         if not self.hoist:
             return []
         if self._hoisted is None:
-            self._hoisted = self.plan.contract_prologue(self.arrays)
+            self._hoisted = self.plan.contract_prologue(
+                self.arrays, cache_key=self._prologue_key
+            )
         return self._hoisted
 
     def hoisted_replicated(self, mesh) -> list:
@@ -162,7 +187,22 @@ class ContractionSession:
         in the same HoistCache entry as the host-side outputs."""
         if not self.hoist:
             return []
-        return self.plan.contract_prologue_replicated(self.arrays, mesh)
+        return self.plan.contract_prologue_replicated(
+            self.arrays, mesh, cache_key=self._prologue_key
+        )
+
+    def leaves_replicated(self, mesh) -> list:
+        """The leaves device-put replicated over ``mesh``, once per
+        (session, mesh)."""
+        placed = self._replicated.get(mesh)
+        if placed is None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            placed = self._replicated.setdefault(
+                mesh,
+                put_leaves(self.arrays, NamedSharding(mesh, PartitionSpec())),
+            )
+        return placed
 
     def out_struct(self):
         """``jax.ShapeDtypeStruct`` of one subtask's output (and of the
@@ -436,7 +476,9 @@ class ContractionSession:
         Open-batch axes are replicated — only the slice axis is sharded —
         so the one psum returns the complete amplitude batch on every
         device.  The hoisted prologue enters the worker as a replicated
-        capture, broadcast once per (leaves, mesh) via the HoistCache."""
+        capture, broadcast once per (leaves, mesh) via the HoistCache;
+        the leaves enter replicated too, put once per (session, mesh)
+        (:meth:`leaves_replicated`)."""
         plan, hoist = self.plan, self.hoist
         ndev = 1
         for ax in axis_names:
@@ -455,6 +497,7 @@ class ContractionSession:
 
         # invariant prologue: once per process, outside the slice loop
         hoisted = self.hoisted_replicated(mesh) if hoist else []
+        leaves = self.leaves_replicated(mesh)
 
         key = ("sharded", mesh, tuple(axis_names), slice_batch, hoist)
         cached = key in plan._compiled
@@ -465,7 +508,7 @@ class ContractionSession:
         ):
             bits_d, valid_d = self._put_ids(ids, valid)
             with _trace.span("engine.launch", cat="engine"):
-                out = fn(list(self.arrays), list(hoisted), bits_d, valid_d)
+                out = fn(list(leaves), list(hoisted), bits_d, valid_d)
             _trace.sync(out)
         record_execution(plan, n_slices, total - n_slices, hoist)
         return out
